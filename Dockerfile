@@ -54,4 +54,8 @@ RUN chmod +x /usr/local/bin/build /usr/local/bin/run_workflow_and_argo.sh
 
 USER gordo
 WORKDIR /home/gordo
+# the persistent XLA compile cache is placed from outside: an installed
+# package has no writable checkout to default into (util/xla_cache.py).
+# Mount a volume here to keep compiles across pod restarts.
+ENV JAX_COMPILATION_CACHE_DIR=/home/gordo/.cache/jax
 CMD ["gordo-tpu", "--help"]

@@ -18,9 +18,16 @@ push: image
 test:
 	python -m pytest tests/ -q
 
-# multichip sharding compile check (same entry the driver uses)
+# multichip sharding compile check on eight virtual CPU devices (the same
+# entry runs unchanged on real chips: it uses the devices present and fails
+# if there are fewer than asked for)
 dryrun:
-	python -c "import __graft_entry__ as g; g.dryrun_multichip(8)"
+	JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
+		python -c "import __graft_entry__ as g; g.dryrun_multichip(8)"
+
+# the quickest proof that the system starts on a TPU (fails without one)
+chip-smoke:
+	python chip_smoke.py
 
 # render the example config through the real CLI and schema-validate the
 # resulting Workflow docs — the no-cluster equivalent of `argo lint`
@@ -83,6 +90,6 @@ chaos-smoke:
 profile-smoke:
 	JAX_PLATFORMS=cpu python scripts/profile_smoke.py
 
-.PHONY: image push test dryrun smoke render-gate bench bench-hotpath \
+.PHONY: image push test dryrun chip-smoke smoke render-gate bench bench-hotpath \
 	bench-gate lint-bench-records lint-dashboards lint-chaos-scenarios \
 	chaos-smoke profile-smoke

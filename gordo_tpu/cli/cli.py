@@ -448,9 +448,11 @@ def batch_build(
                 coordinator_address, num_processes, process_id
             )
         native.prebuild(block=True)
+        from gordo_tpu.observability import device
         from gordo_tpu.util.xla_cache import setup_persistent_xla_cache
 
         setup_persistent_xla_cache()
+        device.log_placement(logger, "batch-build")
         with open(config_file) as f:
             config = yaml.safe_load(f)
         norm = NormalizedConfig(config, project_name=project_name)
@@ -573,10 +575,19 @@ def _flush_telemetry(trace_file: str, metrics_file: str) -> None:
 def _report_quarantine_and_exit(
     builder, n_built: int, quarantine_report_file: str
 ) -> None:
-    """The fleet-build exit report: one line per quarantined machine, an
-    optional JSON report file, and the documented exit-code contract
-    (0 all built / 81 partial / 82 none built; docs/robustness.md)."""
+    """The fleet-build exit report: one line per fleet program that failed
+    to compile and per quarantined machine, an optional JSON report file,
+    and the documented exit-code contract (0 all built / 81 partial / 82
+    none built; docs/robustness.md). A compile failure does not change the
+    code when the fault ladder built the machines another way, so the
+    report says it: such a build did not use the fleet path as planned."""
     records = builder.quarantine_records
+    for failure in builder.compile_failures:
+        click.echo(
+            f"fleet-compile-failure: bucket={failure['bucket']} "
+            f"machines={failure['machines']} error={failure['error']}",
+            err=True,
+        )
     for record in records:
         click.echo(
             f"quarantined: {record.machine} stage={record.stage} "
@@ -590,6 +601,7 @@ def _report_quarantine_and_exit(
                 {
                     "built": n_built,
                     "quarantined": [r.to_dict() for r in records],
+                    "fleet_compile_failures": builder.compile_failures,
                 },
                 f,
                 indent=2,
@@ -603,7 +615,15 @@ def _report_quarantine_and_exit(
     "--host", type=HostIP(), default="0.0.0.0", envvar="GORDO_SERVER_HOST"
 )
 @click.option("--port", type=click.IntRange(1, 65535), default=5555, envvar="GORDO_SERVER_PORT")
-@click.option("--workers", type=click.IntRange(1, 4), default=2, envvar="GORDO_SERVER_WORKERS")
+@click.option(
+    "--workers",
+    type=click.IntRange(min=1),
+    default=None,
+    envvar="GORDO_SERVER_WORKERS",
+    help="Worker processes. Default: one per TPU chip of this host (a chip "
+    "belongs to one process at a time, so more workers than chips is "
+    "refused), or 2 on a host without chips.",
+)
 @click.option(
     "--worker-connections",
     type=click.IntRange(1, 400),
@@ -634,15 +654,21 @@ def _report_quarantine_and_exit(
 def run_server_cli(host, port, workers, worker_connections, batch_predicts, warmup):
     """Run the gordo-tpu model server."""
     from gordo_tpu.server import run_server
+    from gordo_tpu.server.server import ChipLayoutError
 
     # the switch must be in env before workers fork; each worker process
     # then builds its own batcher on first use. "auto" = measured per-spec
     # self-A/B at first use (server/batcher.py), never a blind always-on
     os.environ["GORDO_TPU_SERVING_BATCH"] = "auto" if batch_predicts else "0"
-    run_server(
-        host, port, workers, worker_connections=worker_connections,
-        warmup=warmup,
-    )
+    try:
+        run_server(
+            host, port, workers, worker_connections=worker_connections,
+            warmup=warmup,
+        )
+    except ChipLayoutError as exc:
+        # more workers than chips (refused before anything is bound), or a
+        # worker found chips the launcher had not counted (pool stopped)
+        raise click.UsageError(str(exc))
 
 
 @click.command("run-gateway")

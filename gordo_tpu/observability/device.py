@@ -21,9 +21,16 @@ busy, and at what fraction of its peak? This module samples, on demand
 - **online MFU** (``gordo_server_device_mfu``): the batcher also
   accumulates achieved forward FLOPs per fused call
   (:func:`~gordo_tpu.ops.flops.forward_flops_per_sample` × windows ×
-  lanes); differentiated against the chip peak from
-  :func:`~gordo_tpu.ops.flops.peak_flops_with_source` — which now has a
-  measured-GEMM fallback, so MFU is non-null on CPU too.
+  lanes); differentiated against the chip peak that
+  :func:`log_placement` resolved at boot. A CPU has no peak on record, so
+  there the gauge stays unset ("not measured").
+
+:func:`describe` names the device the process runs on (platform,
+``device_kind``, device count); :func:`snapshot` carries it, so a reader
+of ``/debug/vars`` learns where the numbers came from, from the process
+that held the chip. The chip's peak FLOP/s is looked up once, at boot
+(:func:`log_placement`), where an accelerator with no peak on record fails
+the boot loudly; the samplers only read what boot stored.
 
 Everything is peek-only (never creates a batcher) and best-effort: a
 sampling failure must never fail a scrape or a request.
@@ -40,6 +47,9 @@ _MEMORY_STATS = ("bytes_in_use", "peak_bytes_in_use", "bytes_limit")
 _lock = threading.Lock()
 # previous (monotonic, busy_seconds, flops) sample for rate derivation
 _last_sample: Optional[Dict[str, float]] = None
+# bf16 peak FLOP/s of this process's chip, stored by log_placement() at boot;
+# None on a CPU and in a process that never booted through it
+_peak_flops: Optional[float] = None
 
 
 def _sample_memory() -> None:
@@ -112,12 +122,10 @@ def _sample_rates(inflight_s: float) -> None:
         return  # scrape storm: keep the previous ratio rather than divide
     ratio = max(0.0, busy - last["busy"]) / dt
     metric_catalog.DEVICE_BUSY_RATIO.set(min(ratio, 1.0))
-    from gordo_tpu.ops import flops as flops_mod
-
-    peak, _source = flops_mod.serving_peak_flops()
-    if peak:
+    # the serving batcher dispatches to one device
+    if _peak_flops:
         metric_catalog.DEVICE_MFU.set(
-            max(0.0, flops - last["flops"]) / dt / peak
+            max(0.0, flops - last["flops"]) / dt / _peak_flops
         )
 
 
@@ -138,20 +146,52 @@ def sample() -> None:
         pass
 
 
+def describe() -> Dict[str, Any]:
+    """Where this process runs, as JAX reports it: ``platform``,
+    ``device_kind`` and device count. Initialises the backend if nothing
+    has yet, so call it only from a process that is meant to hold the
+    device."""
+    import jax
+
+    devices = jax.devices()
+    return {
+        "platform": devices[0].platform,
+        "device_kind": devices[0].device_kind,
+        "device_count": len(devices),
+    }
+
+
+def log_placement(logger, command: str) -> Dict[str, Any]:
+    """The one INFO line ``batch-build`` and ``run-server`` workers print at
+    start, so logs say which device the numbers that follow came from. Also
+    looks up the chip's peak FLOP/s for the samplers, here and only here: an
+    accelerator with no peak on record (ops/flops.py raises) stops the boot,
+    instead of failing every later scrape or being swallowed by one."""
+    global _peak_flops
+    from gordo_tpu.ops import flops as flops_mod
+
+    placement = describe()
+    _peak_flops = flops_mod.chip_peak_flops(placement["device_kind"])
+    logger.info(
+        "%s running on platform=%s device_kind=%r device_count=%d",
+        command, placement["platform"], placement["device_kind"],
+        placement["device_count"],
+    )
+    return placement
+
+
 def snapshot() -> Dict[str, Any]:
     """Device-telemetry dict for /debug/vars (gauges refreshed first)."""
     sample()
     from gordo_tpu.observability import metrics as metric_catalog
-    from gordo_tpu.ops import flops as flops_mod
 
-    peak, source = flops_mod.serving_peak_flops()
     return {
+        **describe(),
         "busy_ratio": metric_catalog.DEVICE_BUSY_RATIO.value(),
         "busy_seconds_total": metric_catalog.DEVICE_BUSY_SECONDS.value(),
         "achieved_flops_total": metric_catalog.DEVICE_FLOPS.value(),
         "online_mfu": metric_catalog.DEVICE_MFU.value(),
-        "peak_flops": peak,
-        "peak_source": source,
+        "peak_flops": _peak_flops,
         "param_bank_bytes": metric_catalog.PARAM_BANK_BYTES.value(),
         "param_bank_occupancy": metric_catalog.PARAM_BANK_OCCUPANCY.value(),
         "program_cache_entries": metric_catalog.PROGRAM_CACHE_ENTRIES.value(),
@@ -167,6 +207,7 @@ def install_shard_hooks() -> None:
 
 
 def reset_for_tests() -> None:
-    global _last_sample
+    global _last_sample, _peak_flops
     with _lock:
         _last_sample = None
+        _peak_flops = None

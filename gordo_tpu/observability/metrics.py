@@ -85,6 +85,31 @@ XLA_CACHE_ENTRIES_ADDED = telemetry.counter(
     "Entries the persistent XLA cache gained while this process ran "
     "(cold compiles that future builds will skip)",
 )
+XLA_COMPILES = telemetry.counter(
+    "gordo_build_xla_compiles_total",
+    "Programs this process asked XLA for, by source: compiled (the backend "
+    "compiled it) or persistent_cache (loaded from the persistent cache); "
+    "counted from jax's own monitoring events in build and serving "
+    "processes alike",
+    ("source",),
+)
+XLA_COMPILE_SECONDS = telemetry.counter(
+    "gordo_build_xla_compile_seconds_total",
+    "Wall seconds this process spent waiting for XLA to compile programs or "
+    "load them from the persistent cache",
+)
+FLEET_COMPILE_FAILURES = telemetry.counter(
+    "gordo_build_fleet_compile_failures_total",
+    "Fleet programs that failed to compile (the first dispatch of a bucket "
+    "program raised): whatever the fault ladder did next, the bucket did "
+    "not train on the fleet path as planned",
+)
+FLEET_SHARD_DEVICES = telemetry.gauge(
+    "gordo_build_fleet_shard_devices",
+    "Devices holding the most recent chunk's stacked data and trained "
+    "params (the smaller of the two) — the mesh size when the fleet "
+    "program really spreads machines over every device",
+)
 
 # --------------------------------------- elastic fleet scheduler (ISSUE 10)
 # wired by parallel/scheduler.py + parallel/batch_trainer.py; a "steal" is
@@ -251,6 +276,13 @@ AOT_PROGRAMS = telemetry.counter(
     "jit path serves instead)",
     ("source",),
 )
+WARMUP_FAILURES = telemetry.counter(
+    "gordo_server_warmup_failures_total",
+    "Serving warmup failures, by scope: model (one artifact failed to load "
+    "or compile; named in the warmup report) or collection (the warmup "
+    "pass itself raised). The worker still serves, compiling lazily",
+    ("scope",),
+)
 PRELOWER_FAILURES = telemetry.counter(
     "gordo_server_prelower_failures_total",
     "AOT pre-lower attempts that failed and fell back to the lazy jit "
@@ -326,8 +358,8 @@ DEVICE_FLOPS = telemetry.counter(
 DEVICE_MFU = telemetry.gauge(
     "gordo_server_device_mfu",
     "Online serving MFU: achieved FLOP/s over the last sampling interval "
-    "divided by the chip peak (table, env override, or measured GEMM "
-    "fallback — ops/flops.py peak_flops_with_source)",
+    "divided by the chip's bf16 peak (ops/flops.py chip_peak_flops); unset "
+    "on CPU, which has no peak on record",
 )
 DEVICE_MEMORY = telemetry.gauge(
     "gordo_server_device_memory_bytes",

@@ -9,9 +9,10 @@ TPU-first:
 - ``impl="xla"``: plain jnp einsum formulation — XLA fuses softmax into the
   two MXU matmuls; this is the reference implementation and CPU/test path.
 - ``impl="flash"``: Pallas TPU kernel (blockwise online-softmax, O(T) memory;
-  see :mod:`gordo_tpu.ops.pallas_kernels.flash_attention`).
-- ``impl="auto"``: flash on TPU when shapes satisfy the kernel's tiling
-  constraints, else xla.
+  see :mod:`gordo_tpu.ops.pallas_kernels.flash_attention`). Compiled by
+  Mosaic: on a backend without it the call raises.
+- ``impl="auto"``: flash on a TPU for the shapes ``_flash_ok`` admits (those
+  proven to compile on the chip), else xla.
 
 Sequence-parallel exact attention for windows too long for one chip (ring
 attention over a mesh axis via shard_map + ppermute) lives in
@@ -145,26 +146,34 @@ def spec_may_use_ring(spec) -> bool:
 
 def _flash_ok(q: jnp.ndarray, k: jnp.ndarray) -> bool:
     """
-    Whether the Pallas flash kernel supports these shapes on this backend.
-    The kernel needs self-attention (equal Q/K lengths), T divisible by its
-    128-row blocks, and a FULL-lane head dim: dh >= 64 — Mosaic lowering of
-    sub-64 head dims was measured to hang (a dh=8 TPU export ran >300 s
-    without completing), and small heads waste most of the 128-lane vector
-    unit anyway, so they stay on the XLA path. Below ~256 rows the O(T²)
-    XLA path is already VMEM-resident and the kernel buys nothing; above
-    ~4096 rows the kernel's full-length K/V (and lane-replicated lse)
-    VMEM staging approaches the ~16 MB budget — longer sequences belong to
-    ring attention (parallel/ring_attention.py), the designed long-T path.
+    Whether ``auto`` sends these shapes to the Pallas flash kernel: on a TPU,
+    self-attention (equal Q/K lengths), T a multiple of the kernel's 128-row
+    blocks between 256 and 4096, and a head dim of 64 or 128.
+
+    Those are the (T, head dim) corners ``chip_smoke.py`` compiles and
+    compares on the chip, forward and backward, f32 and bf16. The head dim is
+    the kernel's lane dimension and decides how Mosaic tiles every block, so
+    only the two widths that were compiled are admitted (72..120 were never
+    tried). T only sets the grid size, the loop trip counts and the dK/dV
+    kernel's whole-sequence staging, which grows with T and was compiled at
+    both ends. What a v5e showed (chip run, PR 21): head dims 16, 32, 64, 128
+    and 256 and T 128, 256, 512, 1024, 4096 and 8192 compile, except
+    T 8192 x dh 128 in f32, where the staging of q, dO, O and the
+    lane-replicated lse runs out of the 16 MiB scoped VMEM by 128 KiB — so the
+    upper bounds stay a factor of two inside what fits. The lower bounds are
+    not compile limits: whether the kernel beats XLA below 256 rows or with
+    heads narrower than 64 lanes is not measured, and until a benchmark cell
+    decides it those shapes stay on the XLA path. Longer sequences belong to
+    ring attention (parallel/ring_attention.py).
     """
     if jax.default_backend() != "tpu":
         return False
     t, dh = q.shape[-2], q.shape[-1]
     return (
         k.shape[-2] == t
-        and 256 <= t <= 4096
         and t % 128 == 0
-        and dh % 8 == 0
-        and dh >= 64
+        and 256 <= t <= 4096
+        and dh in (64, 128)
     )
 
 

@@ -17,7 +17,7 @@ Conventions (standard accounting, matmul-dominated):
 
 from __future__ import annotations
 
-from typing import Optional, Tuple
+from typing import Optional
 
 from gordo_tpu.models.spec import (
     DenseLayer,
@@ -114,16 +114,18 @@ def cv_build_flops(
     return total
 
 
-# bf16 peak matmul FLOP/s per chip, by jax device_kind substring. Public
-# figures (cloud.google.com/tpu docs); fp32 compute on TPU routes through the
-# same MXU via bf16x3 passes at roughly 1/2 throughput — MFU here is always
-# reported against the bf16 peak, the honest (hardest) denominator.
+# bf16 peak matmul FLOP/s per chip, by jax device_kind substring. Source:
+# Google Cloud TPU documentation, the "TPU v5e" / "TPU v5p" / "TPU v6e" /
+# "TPU v4" system-architecture pages (v5e: 197 TFLOP/s bf16 — 394 is its
+# int8 figure). fp32 compute on TPU routes through the same MXU in several
+# bf16 passes — MFU here is always reported against the bf16 peak, the
+# hardest denominator.
 _PEAK_BF16 = {
     "v6e": 918e12,
     "v6 lite": 918e12,
     "v5p": 459e12,
-    "v5e": 394e12,
-    "v5 lite": 394e12,
+    "v5e": 197e12,
+    "v5 lite": 197e12,
     "v4": 275e12,
     "v3": 123e12,
     "v2": 45e12,
@@ -131,126 +133,22 @@ _PEAK_BF16 = {
 
 
 def chip_peak_flops(device_kind: str) -> Optional[float]:
-    """Peak bf16 FLOP/s for a ``jax.devices()[0].device_kind`` string, or
-    None when unknown (override with env ``GORDO_TPU_PEAK_FLOPS``)."""
-    import os
+    """Peak bf16 FLOP/s for a ``jax.devices()[0].device_kind`` string.
 
-    env = os.environ.get("GORDO_TPU_PEAK_FLOPS")
-    if env:
-        return float(env)
+    ``None`` for a CPU device: a host has no datasheet peak, so its MFU is
+    "not measured". Any other kind missing from the table raises — an
+    accelerator whose peak is unknown must be added with its source, never
+    defaulted."""
     kind = (device_kind or "").lower()
     for key, peak in _PEAK_BF16.items():
         if key in kind:
             return peak
-    return None
-
-
-# ------------------------------------------------- measured-peak fallback
-# Before ISSUE 9 every CPU bench record carried ``mfu: null`` — the peak
-# table only knows TPU chips. The fallback times a large f32 GEMM through
-# jit (the same XLA backend the models run on) and uses its best-of-N
-# throughput as the host's achievable peak. Cached per (backend, host
-# fingerprint) under the tempdir so the ~second of measurement is paid
-# once per host, not once per process.
-_GEMM_N = 1024
-
-# in-process memo: None = not measured yet, 0.0 = measurement failed
-_measured_peak: Optional[float] = None
-
-
-def measured_peak_flops() -> Optional[float]:
-    """Best-of-3 f32 GEMM throughput of the current default backend, or
-    None when measurement fails. Disk-cached per host fingerprint."""
-    global _measured_peak
-    if _measured_peak is not None:
-        return _measured_peak or None
-    import json
-    import os
-    import tempfile
-    import time
-
-    try:
-        import jax
-        import jax.numpy as jnp
-
-        from gordo_tpu.util.xla_cache import host_fingerprint
-
-        backend = jax.default_backend()
-        path = os.path.join(
-            tempfile.gettempdir(),
-            f"gordo_tpu_peak-{backend}-{host_fingerprint()}.json",
-        )
-        try:
-            with open(path) as fh:
-                peak = float(json.load(fh)["peak_flops"])
-            if peak > 0:
-                _measured_peak = peak
-                return peak
-        except (OSError, ValueError, KeyError, TypeError):
-            pass
-        a = jnp.ones((_GEMM_N, _GEMM_N), jnp.float32)
-        b = jnp.ones((_GEMM_N, _GEMM_N), jnp.float32)
-        matmul = jax.jit(lambda x, y: x @ y)
-        matmul(a, b).block_until_ready()  # compile outside the timing
-        best = float("inf")
-        for _ in range(3):
-            t0 = time.perf_counter()
-            matmul(a, b).block_until_ready()
-            best = min(best, time.perf_counter() - t0)
-        peak = 2.0 * float(_GEMM_N) ** 3 / max(best, 1e-9)
-        tmp = f"{path}.tmp.{os.getpid()}"
-        try:
-            with open(tmp, "w") as fh:
-                json.dump(
-                    {"peak_flops": peak, "backend": backend,
-                     "gemm_n": _GEMM_N},
-                    fh,
-                )
-            os.replace(tmp, path)
-        except OSError:
-            pass
-        _measured_peak = peak
-        return peak
-    except Exception:  # noqa: BLE001 — a peak estimate is never worth a crash
-        _measured_peak = 0.0
+    if kind == "cpu":
         return None
-
-
-def peak_flops_with_source(
-    device_kind: str,
-) -> Tuple[Optional[float], Optional[str]]:
-    """``(peak FLOP/s, source)`` where source tags how the denominator was
-    obtained: ``env`` (GORDO_TPU_PEAK_FLOPS override), ``table`` (known
-    chip), or ``measured`` (GEMM fallback — the reason CPU MFU is no
-    longer null). ``(None, None)`` only when even measurement failed."""
-    import os
-
-    env = os.environ.get("GORDO_TPU_PEAK_FLOPS")
-    if env:
-        try:
-            return float(env), "env"
-        except ValueError:
-            pass
-    kind = (device_kind or "").lower()
-    for key, peak in _PEAK_BF16.items():
-        if key in kind:
-            return peak, "table"
-    peak = measured_peak_flops()
-    if peak:
-        return peak, "measured"
-    return None, None
-
-
-def serving_peak_flops() -> Tuple[Optional[float], Optional[str]]:
-    """``peak_flops_with_source`` for the process's default jax device
-    (the serving batcher dispatches to one device)."""
-    try:
-        import jax
-
-        kind = jax.devices()[0].device_kind
-    except Exception:  # noqa: BLE001 — no backend, no peak
-        return None, None
-    return peak_flops_with_source(kind)
+    raise ValueError(
+        f"no peak FLOP/s on record for device_kind {device_kind!r}; add it "
+        f"to gordo_tpu.ops.flops._PEAK_BF16 with its source"
+    )
 
 
 def mfu(
@@ -258,23 +156,11 @@ def mfu(
 ) -> Optional[float]:
     """Model FLOPs utilization in [0, 1] against the HOST's aggregate peak
     (chip peak x device count — a fleet build spreads machines over every
-    chip). Falls back to the measured GEMM peak on unknown chips (CPU), so
-    None only when even measurement failed."""
-    value, _source = mfu_with_source(
-        total_flops, wall_sec, device_kind, n_devices
-    )
-    return value
-
-
-def mfu_with_source(
-    total_flops: float, wall_sec: float, device_kind: str, n_devices: int = 1
-) -> Tuple[Optional[float], Optional[str]]:
-    """``(mfu, peak_source)`` — the bench records both so an MFU against a
-    measured host peak is never mistaken for one against a chip datasheet."""
-    peak, source = peak_flops_with_source(device_kind)
+    chip). ``None`` on CPU (no peak to divide by) and for a zero wall."""
+    peak = chip_peak_flops(device_kind)
     if not peak or wall_sec <= 0:
-        return None, source
-    return total_flops / wall_sec / (peak * max(n_devices, 1)), source
+        return None
+    return total_flops / wall_sec / (peak * max(n_devices, 1))
 
 
 def spec_param_count(spec: ModelSpec) -> int:

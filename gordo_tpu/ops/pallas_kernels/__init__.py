@@ -1,10 +1,9 @@
 """
 Pallas TPU kernels for the framework's hot ops.
 
-Kernels are optional accelerations: every one has a numerically-matching
-jnp/XLA reference implementation that is used on CPU and for backward passes,
-and tests run the kernels in interpret mode so CI (CPU-only) still exercises
-the kernel code paths.
+Every kernel has a numerically-matching jnp/XLA reference implementation
+that tests compare it against. The kernels compile for TPU only; the CPU
+tests pass ``interpret=True`` explicitly to exercise the kernel code paths.
 """
 
 from .flash_attention import flash_attention

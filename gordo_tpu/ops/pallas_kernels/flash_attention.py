@@ -18,8 +18,9 @@ Design (see /opt/skills/guides/pallas_guide.md):
   dS = P ∘ (dP − D) with D = rowsum(dO ∘ O).
 - Accumulation in float32 regardless of input dtype (bfloat16-safe).
 
-The kernels run under ``interpret=True`` on CPU so tests exercise the real
-kernel logic without TPU hardware.
+The kernels compile through Mosaic and therefore need a TPU backend. A
+caller that wants the Pallas interpreter (the CPU tests) asks for it with
+``interpret=True``; nothing selects it on the caller's behalf.
 """
 
 import functools
@@ -285,15 +286,13 @@ def _flash_bwd(causal, interpret, residuals, g):
 _flash_attention.defvjp(_flash_fwd, _flash_bwd)
 
 
-def flash_attention(q, k, v, causal: bool = False, interpret: bool = None):
+def flash_attention(q, k, v, causal: bool = False, interpret: bool = False):
     """
     Blockwise flash attention. q, k, v: (..., T, Dh); any leading batch dims.
 
-    ``interpret=None`` auto-selects interpreter mode off-TPU so the kernel is
-    testable on CPU.
+    Compiled unless the caller passes ``interpret=True``: on a backend
+    without Mosaic the call raises instead of running interpreted.
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     lead = q.shape[:-2]
     t, dh = q.shape[-2:]
     qf = q.reshape((-1, t, dh))

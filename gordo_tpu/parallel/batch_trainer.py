@@ -544,6 +544,16 @@ def _bucket_program_unrolled(
 
 
 # ------------------------------------------------- vectorized fold metrics
+def _note_shard_devices(stacked_data, params_stack) -> None:
+    """Record over how many devices a chunk's stacked data and trained
+    params are laid out: with a mesh of n devices both must say n, or the
+    fleet program is not spreading machines over the mesh."""
+    leaves = jax.tree_util.tree_leaves(params_stack) + [stacked_data]
+    metric_catalog.FLEET_SHARD_DEVICES.set(
+        min(len(leaf.sharding.device_set) for leaf in leaves)
+    )
+
+
 def _metric_per_column(name: str, yt: np.ndarray, yp: np.ndarray) -> np.ndarray:
     """Per-column metric over stacked machines. yt/yp: (M, n, D) → (M, D).
     Formulas match sklearn's defaults (uniform_average over outputs)."""
@@ -683,6 +693,11 @@ class BatchedModelBuilder:
         self.quarantined: List[Machine] = []
         self.quarantine_records: List[QuarantineRecord] = []
         self._quarantined_names: set = set()
+        # fleet programs that failed to compile during the last build():
+        # {"bucket", "machines", "error"} each. The fault ladder may still
+        # have built those machines some other way, so the exit report
+        # names them: such a build did not use the fleet path as planned
+        self.compile_failures: List[Dict[str, Any]] = []
 
     # -------------------------------------------------------------- data
     def _load_data(self, plan: _Plan):
@@ -788,6 +803,7 @@ class BatchedModelBuilder:
         self.quarantined = []
         self.quarantine_records = []
         self._quarantined_names = set()
+        self.compile_failures = []
         with maybe_profile("batched-build"):
             with telemetry.span("batched_build", machines=len(self.machines)):
                 return self._build_all(distributed)
@@ -1434,6 +1450,23 @@ class BatchedModelBuilder:
             )
             return self._bucket_serial_last_resort(bucket, global_idxs)
 
+    def _note_compile_failure(
+        self, bucket_name: str, n_machines: int, exc: BaseException
+    ) -> None:
+        """A fleet program failed in its first dispatch, which is where jit
+        compiles: make it loud — counted, logged at ERROR with the
+        compiler's message, and kept for the exit report — before the fault
+        ladder (or ``fail_fast``) decides what happens to the machines."""
+        metric_catalog.FLEET_COMPILE_FAILURES.inc()
+        error = f"{type(exc).__name__}: {exc}"
+        logger.error(
+            "Fleet program for bucket %s (%d machines) FAILED TO COMPILE: %s",
+            bucket_name, n_machines, error,
+        )
+        self.compile_failures.append(
+            {"bucket": bucket_name, "machines": n_machines, "error": error}
+        )
+
     def _bucket_serial_last_resort(
         self, bucket: List[_Plan], global_idxs: List[int]
     ) -> List[Tuple[int, Tuple[Any, Machine]]]:
@@ -1602,7 +1635,9 @@ class BatchedModelBuilder:
                     stacked,
                 )
                 args = args + (warm_d,)
-            return group, program(*args)
+            outputs = program(*args)
+            _note_shard_devices(X_d, outputs[0])
+            return group, outputs
 
         def fetch(group, outputs):
             params_stack, losses, fold_preds = outputs
@@ -1697,7 +1732,12 @@ class BatchedModelBuilder:
                 machines=M, cached=program_cached,
             ):
                 t_compile = time.time()
-                in_flight, in_flight_start = dispatch(starts[0]), starts[0]
+                try:
+                    in_flight, in_flight_start = dispatch(starts[0]), starts[0]
+                except Exception as exc:
+                    if not program_cached:
+                        self._note_compile_failure(bucket_name, M, exc)
+                    raise
                 if not program_cached:
                     _first_compile_walls[program_key] = time.time() - t_compile
             with telemetry.span(

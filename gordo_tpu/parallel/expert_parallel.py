@@ -123,7 +123,7 @@ def shard_params_ep(spec: ModelSpec, params, strict: bool = True):
 def _ep_ffn_fn(layer: MoEBlock, n_shards: int):
     """shard_map'd routed FFN: expert weights sharded, tokens replicated,
     one psum combines the per-shard contributions."""
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     from gordo_tpu.ops.nn import moe_dispatch_ffn
 
@@ -140,7 +140,7 @@ def _ep_ffn_fn(layer: MoEBlock, n_shards: int):
         mesh=mesh,
         in_specs=(P(AXIS), P(), P()),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
 
 

@@ -135,7 +135,7 @@ def make_pipeline_blocks_fn(
     Returns (B, T, D), replicated, numerically equal to applying the
     blocks sequentially (up to reduction order).
     """
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     from gordo_tpu.ops.nn import _apply_transformer_block
 
@@ -205,7 +205,7 @@ def make_pipeline_blocks_fn(
         mesh=mesh,
         in_specs=(P(AXIS), P()),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
 
 

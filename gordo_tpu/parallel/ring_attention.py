@@ -24,10 +24,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
-try:
-    from jax import shard_map
-except ImportError:  # pragma: no cover - jax < 0.5 keeps it in experimental
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 NEG_INF = -1e30

@@ -34,6 +34,7 @@ adding ~2-800ms p50 at low concurrency). ``GORDO_TPU_BATCH_WINDOW_MS``
 re-enables a timed collection window if ever wanted.
 """
 
+import contextlib
 import functools
 import logging
 import os
@@ -1432,6 +1433,23 @@ def get_batcher() -> Optional[CrossModelBatcher]:
     return _batcher
 
 
+_direct = threading.local()
+
+
+@contextlib.contextmanager
+def direct_path():
+    """This thread's predicts go direct inside the block, whatever the
+    batcher decided. Warmup compiles the per-request program with it: which
+    of the two paths serves an architecture is a measured decision that can
+    fall either way between two boots, and the program set a worker
+    compiles — and leaves in the persistent cache — must not depend on it."""
+    _direct.on = True
+    try:
+        yield
+    finally:
+        _direct.on = False
+
+
 def maybe_submit(spec, params, X) -> Optional[np.ndarray]:
     """Route through the batcher when enabled; None means 'go direct'.
 
@@ -1442,7 +1460,10 @@ def maybe_submit(spec, params, X) -> Optional[np.ndarray]:
     batcher = get_batcher()
     if batcher is None:
         return None
-    if threading.current_thread().name == "gordo-batcher":
+    if (
+        threading.current_thread().name == "gordo-batcher"
+        or getattr(_direct, "on", False)
+    ):
         return None
     from gordo_tpu.ops.attention import spec_may_use_ring
 

@@ -236,7 +236,14 @@ def vars_view(config: Dict[str, Any]) -> Response:
                 "draining": resilience.is_draining(),
                 "project": config.get("PROJECT"),
             },
-            "batcher": None if batcher is None else dict(batcher.stats),
+            # dispatch counters plus the auto mode's measured per-architecture
+            # decisions: how many batch, how many stood down
+            "batcher": None if batcher is None else {
+                **batcher.stats,
+                "self_ab": dict(
+                    zip(("batching", "stood_down"), batcher.decision_counts())
+                ),
+            },
             # last warmup report (boot / hot-swap pre-warm / /debug/prewarm):
             # AOT program counts incl. shipped-vs-compiled and the compile
             # seconds shipped programs saved — the node's warmth at a glance

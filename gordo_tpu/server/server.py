@@ -683,10 +683,38 @@ def build_app(
     return app
 
 
+class ChipLayoutError(ValueError):
+    """The pool that was asked for cannot have one process per TPU chip."""
+
+
+def resolve_workers(workers: Optional[int]) -> "tuple[int, int]":
+    """``(workers, chips)`` for a serving pool on this host.
+
+    A TPU chip belongs to one process at a time and a serving worker
+    dispatches to one device, so on a host with chips the pool is one worker
+    per chip: that is the default, fewer is allowed, more is refused here —
+    a surplus worker could never start its backend and the arbiter would
+    respawn it forever. Without chips (CPU) the default stays two workers.
+    Counted without touching jax (util/chips.py)."""
+    from gordo_tpu.util import chips as chips_mod
+
+    chips = chips_mod.attached_tpu_chips()
+    if workers is None:
+        workers = chips or 2
+    workers = max(1, workers)
+    if chips and workers > chips:
+        raise ChipLayoutError(
+            f"{workers} workers asked for, but this host has {chips} TPU "
+            f"chip(s) and a chip belongs to one process at a time: run at "
+            f"most {chips} worker(s) here"
+        )
+    return workers, chips
+
+
 def run_server(
     host: str = "0.0.0.0",
     port: int = 5555,
-    workers: int = 2,
+    workers: Optional[int] = None,
     worker_connections: int = 50,
     warmup: bool = False,
     **kwargs,
@@ -698,10 +726,17 @@ def run_server(
     The listening socket is bound once and inherited by ``workers`` forked
     processes that all accept on it; each worker serves threaded (device
     compute releases the GIL, so threads provide request concurrency on one
-    warm model cache per worker). With prometheus enabled and workers > 1,
-    PROMETHEUS_MULTIPROC_DIR is set before the per-worker app build so
-    /metrics aggregates across the pool. ``worker_connections`` is accepted
-    for reference-CLI parity; the werkzeug server has no connection cap.
+    warm model cache per worker). ``workers=None`` means one per TPU chip
+    of the host, or two without chips; each forked worker is pinned to its
+    own chip before it initialises jax, and the arbiter parent never
+    touches jax (:func:`resolve_workers`). A pool that cannot have one
+    process per chip raises :class:`ChipLayoutError`: at once when more
+    workers than chips were asked for, or when the first unpinned worker
+    finds a TPU the arbiter had not counted. With prometheus enabled and
+    workers > 1, PROMETHEUS_MULTIPROC_DIR is set before the per-worker app
+    build so /metrics aggregates across the pool. ``worker_connections`` is
+    accepted for reference-CLI parity; the werkzeug server has no
+    connection cap.
     """
     import signal
     import socket
@@ -727,7 +762,7 @@ def run_server(
             host, port, app, threaded=True, fd=listen_sock.fileno()
         )
 
-    workers = max(1, workers)
+    workers, chips = resolve_workers(workers)
     if workers > 1 and os.environ.get("GORDO_TPU_UDS_PATH"):
         # forked workers would fight over one socket path (each bind
         # unlinks its predecessor's), so the Unix-domain lane is a
@@ -758,30 +793,56 @@ def run_server(
 
         use_multiprocess_values()
 
-    def _maybe_warmup():
-        # per process, AFTER any fork (jax/XLA state must not cross fork).
+    def _boot_worker():
+        # per process, AFTER any fork (jax/XLA state must not cross fork):
+        # place the persistent compile cache — with or without warmup, so
+        # lazy compiles are kept too — and say which device this worker
+        # took. Either failing fails the worker's boot.
+        from gordo_tpu.observability import device
+        from gordo_tpu.util.xla_cache import setup_persistent_xla_cache
+
+        setup_persistent_xla_cache()
+        placement = device.log_placement(logger, "run-server worker")
+        if workers > 1 and not chips and placement["platform"] == "tpu":
+            # the arbiter counted no chip (util/chips.py reads sysfs and
+            # /dev, which a container may hide), so it pinned nobody — yet
+            # jax found a TPU. This worker now holds every chip and its
+            # siblings can never start: say so and stop the pool, before
+            # any warmup
+            raise ChipLayoutError(
+                f"this worker found {placement['device_count']} TPU chip(s) "
+                f"({placement['device_kind']}) but the launcher counted 0 on "
+                f"this host and started {workers} unpinned workers; a chip "
+                f"belongs to one process at a time: run with --workers 1"
+            )
+        if warmup:
+            _warm_worker()
+        # publish what boot counted (warmup compiles, failures) to the
+        # pool's merged view now, not at this worker's first request
+        shared.flush(force=True)
+
+    def _warm_worker():
         # On a fresh boot every worker warms itself — workers fork together
         # and the XLA cache has no in-flight dedupe — but the persistent
-        # cache established below makes restarts (and later workers'
-        # stragglers) near-free.
-        if not warmup:
+        # cache makes restarts (and later workers' stragglers) near-free.
+        collection_dir = default_config()["MODEL_COLLECTION_DIR"]
+        if not collection_dir:
+            logger.warning("warmup requested but MODEL_COLLECTION_DIR unset")
             return
         try:
-            collection_dir = default_config()["MODEL_COLLECTION_DIR"]
-            if not collection_dir:
-                logger.warning("warmup requested but MODEL_COLLECTION_DIR unset")
-                return
-            from gordo_tpu.util.xla_cache import setup_persistent_xla_cache
-
-            setup_persistent_xla_cache()
             from gordo_tpu.server.warmup import warmup_collection
 
             warmup_collection(collection_dir)
-        except Exception:  # noqa: BLE001 — warmup must NEVER stop the
-            # server: an unreadable collection dir or malformed knob would
-            # otherwise crash every respawned worker until the fast-death
-            # throttle kills the whole pool; the lazy path still serves
-            logger.exception("serving warmup failed; serving lazily")
+        except Exception:  # noqa: BLE001 — the worker still serves: an
+            # unreadable collection dir would otherwise crash every
+            # respawned worker until the fast-death throttle kills the
+            # whole pool. But a warmup that failed is an ERROR and is
+            # counted, because every program now compiles inside a request
+            metric_catalog.WARMUP_FAILURES.labels(scope="collection").inc()
+            logger.exception(
+                "serving warmup FAILED; programs will compile in the "
+                "request path"
+            )
 
     def _install_drain_handler(server):
         """Graceful drain: the first SIGTERM stops the accept loop (from a
@@ -859,12 +920,15 @@ def run_server(
 
     registration = _register_node(sock)
     logger.info(
-        "Starting server on %s:%s with %d worker(s)", host, port, workers
+        "Starting server on %s:%s with %d worker(s) (%s)", host, port,
+        workers,
+        f"{chips} TPU chip(s) on this host, one worker per chip"
+        if chips else "no TPU chip on this host",
     )
     if workers == 1:
         # single worker: serve inline, no arbiter
         app = build_app()
-        _maybe_warmup()
+        _boot_worker()
         server = _make_http_server(app, sock)
         _install_drain_handler(server)
         try:
@@ -886,6 +950,9 @@ def run_server(
     from gordo_tpu.server.prometheus.server import mark_worker_dead
 
     worker_pids: set = set()
+    # pid -> pool slot: with several chips a worker is pinned to the chip of
+    # its slot, and its replacement inherits slot and chip
+    slots: dict = {}
     spawn_times: dict = {}
     ready_fds: dict = {}
     shutting_down = False
@@ -901,11 +968,16 @@ def run_server(
     MAX_FAST_DEATHS = 5
     fast_deaths = 0
 
-    def _serve_child(ready_w: int) -> "None":  # never returns
+    def _serve_child(ready_w: int, slot: int) -> "None":  # never returns
         # any escape path must os._exit: an exception unwinding out of the
         # forked child would execute the arbiter's inherited finally block
         # (SIGTERM-ing healthy siblings) in the child
         try:
+            if chips > 1:
+                # before anything initialises jax in this process
+                from gordo_tpu.util import chips as chips_mod
+
+                chips_mod.pin_process_to_chip(slot)
             signal.signal(signal.SIGCHLD, signal.SIG_DFL)
             # default TERM until the server exists (a TERM during boot just
             # kills the booting worker; there is nothing to drain yet)
@@ -913,7 +985,7 @@ def run_server(
             # app built per worker process: model cache and metric values are
             # process-local (metrics aggregate via the multiprocess dir)
             app = build_app()
-            _maybe_warmup()
+            _boot_worker()
             server = _make_http_server(app, sock)
             # from here on SIGTERM drains: stop accepting, finish in-flight
             # within the budget, exit — revision rollover no longer cuts
@@ -926,12 +998,18 @@ def run_server(
                 pass
             server.serve_forever()
             _finish_drain(server)
+        except ChipLayoutError as exc:
+            # not a boot failure to retry: hand the arbiter the reason over
+            # the readiness pipe, so it stops the whole pool with it
+            logger.error("%s", exc)
+            os.write(ready_w, b"L" + str(exc).encode())
+            os._exit(1)
         except BaseException:
             logger.exception("worker failed to boot/serve")
             os._exit(1)
         os._exit(0)
 
-    def _spawn() -> None:
+    def _spawn(slot: int) -> None:
         start = _time.monotonic()
         # the write end is held ONLY by this child (the parent closes its
         # copy right after fork, and earlier siblings predate the pipe), so
@@ -949,12 +1027,13 @@ def run_server(
                     os.close(fd)
                 except OSError:
                     pass
-            _serve_child(ready_w)
+            _serve_child(ready_w, slot)
         os.close(ready_w)
         # spawn time recorded before the pid becomes reapable via
         # worker_pids, so _reap never sees a missing entry
         spawn_times[pid] = start
         ready_fds[pid] = ready_r
+        slots[pid] = slot
         worker_pids.add(pid)
 
     def _reap():
@@ -976,6 +1055,7 @@ def run_server(
                 continue
             if reaped == pid:
                 worker_pids.discard(pid)
+                slot = slots.pop(pid)
                 mark_worker_dead(pid)
                 # retire the dead worker's telemetry shard too, or its last
                 # counters would stay in the fleet sum forever
@@ -987,10 +1067,14 @@ def run_server(
                 became_ready = False
                 if ready_r is not None:
                     try:
-                        became_ready = os.read(ready_r, 1) == b"R"
+                        said = os.read(ready_r, 1024)
                     except OSError:
-                        became_ready = False
+                        said = b""
                     os.close(ready_r)
+                    if said.startswith(b"L"):
+                        # a worker found a chip layout no respawn can fix
+                        raise ChipLayoutError(said[1:].decode())
+                    became_ready = said == b"R"
                 if lifetime < FAST_DEATH_S or not became_ready:
                     fast_deaths += 1
                 else:
@@ -1003,7 +1087,7 @@ def run_server(
                     )
                     continue
                 logger.warning("worker %d died; spawning replacement", pid)
-                _spawn()
+                _spawn(slot)
 
     # SIGTERM must run the cleanup below (the default action would kill the
     # arbiter outright, orphaning the pool), so convert it to SystemExit
@@ -1018,8 +1102,8 @@ def run_server(
         # statuses and break waitpid): keeps children reapable while all
         # actual reaping happens in the poll loop below
         signal.signal(signal.SIGCHLD, lambda signum, frame: None)
-        for _ in range(workers):
-            _spawn()
+        for slot in range(workers):
+            _spawn(slot)
         _reap()
         RETRY_S = 10.0
         last_retry = _time.monotonic()
@@ -1047,7 +1131,7 @@ def run_server(
                     "pool at %d/%d workers; retrying one respawn",
                     len(worker_pids), workers,
                 )
-                _spawn()
+                _spawn(min(set(range(workers)) - set(slots.values())))
             _time.sleep(1)
     except (KeyboardInterrupt, SystemExit):
         pass

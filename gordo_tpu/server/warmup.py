@@ -8,9 +8,10 @@ per request, server/utils.py:323-343). Serving shapes here are padded to
 power-of-two buckets (ops/train.pad_for_predict), so the program set is
 finite: warming compiles the programs for the configured row buckets
 (``GORDO_TPU_WARMUP_ROWS``, default 128 and 1024 — a request padding to a
-bucket outside that list still pays its first compile), and a persistent
-XLA cache (``JAX_COMPILATION_CACHE_DIR``, which run-server establishes
-when warmup is on) carries compiles across worker processes and restarts.
+bucket outside that list still pays its first compile), and the
+persistent XLA cache (``$JAX_COMPILATION_CACHE_DIR`` or the in-checkout
+default, set up by every run-server worker — util/xla_cache.py) carries
+compiles across worker processes and restarts.
 
 ``run-server --warmup`` (or ``GORDO_TPU_SERVING_WARMUP=1``) runs this in
 each worker after fork, before the worker starts accepting; models sharing
@@ -168,6 +169,25 @@ def _prelower_programs(model, bucket_rows, offset, n_features) -> int:
     return compiled
 
 
+def _warm_direct_program(model, X, bucket, done: set) -> None:
+    """With the batcher on, compile the per-request (unfused) program of
+    this artifact's architecture for one row bucket too, once per
+    architecture: the self-A/B is a measurement that can fall either way
+    from one boot to the next, and the warmup predict above only compiled
+    the path it chose. Warming both makes the compiled-program set — and so
+    what the persistent cache holds after one boot — the same whichever way
+    it fell."""
+    from gordo_tpu.server import batcher as batcher_mod
+
+    if batcher_mod.get_batcher() is None:
+        return  # every predict is already direct
+    key = (tuple(e.spec_ for e in _jax_estimators(model)), int(bucket))
+    if key not in done:
+        done.add(key)
+        with batcher_mod.direct_path():
+            model.predict(X)
+
+
 def _register_params(model) -> int:
     """Commit-once pre-registration: push the artifact's params into the
     cross-model batcher's device-resident bank (when batching is enabled)
@@ -235,8 +255,9 @@ def warmup_collection(
     bucket, compiling the serving programs traffic will hit.
 
     Returns ``{"models": N, "programs": M, "seconds": S, "failed": [...]}``.
-    A model that fails to warm is logged and skipped — warmup must never
-    prevent the server from starting (the lazy path still works).
+    A model that fails to warm is skipped — the lazy path still serves it —
+    but loudly: logged at ERROR with its traceback, counted in
+    ``gordo_server_warmup_failures_total`` and listed under ``failed``.
     """
     from gordo_tpu.server.utils import load_metadata, load_model
 
@@ -269,6 +290,8 @@ def warmup_collection(
         return dict(batcher.aot_stats)
 
     aot_before = _aot_stats()
+    # (architectures, row bucket) whose per-request program is compiled
+    direct_warm: set = set()
     for name in names:
         try:
             metadata = load_metadata(collection_dir, name)
@@ -300,6 +323,7 @@ def warmup_collection(
                 X = np.zeros((int(bucket) + int(offset), n_features), np.float32)
                 model.predict(X)
                 programs += 1
+                _warm_direct_program(model, X, bucket, direct_warm)
             # commit-once: AFTER the first predict (which device-commits
             # params_, fixing the object identity the bank keys on), pin
             # this artifact's params into the batcher's device-resident
@@ -314,8 +338,13 @@ def warmup_collection(
                 model, bucket_rows, offset, n_features
             )
             warmed += 1
-        except Exception as exc:  # noqa: BLE001 — warmup is best-effort
-            logger.warning("warmup failed for model %r: %s", name, exc)
+        except Exception:  # noqa: BLE001 — the other models still warm,
+            # and this one compiles in its first request instead: an ERROR
+            # with the traceback, counted, and named in the report
+            from gordo_tpu.observability import metrics as metric_catalog
+
+            metric_catalog.WARMUP_FAILURES.labels(scope="model").inc()
+            logger.exception("warmup FAILED for model %r", name)
             failed.append(name)
     seconds = time.monotonic() - t0
     aot_after = _aot_stats()

@@ -1,6 +1,12 @@
-"""Persistent XLA compilation cache setup, shared by bench.py and serving
-warmup — one copy of the directory scheme so their compiles land in (and
-re-use) the same cache.
+"""Persistent XLA compilation cache setup — the one place that decides where
+compiled programs are kept, called by every entry point that compiles
+(``build``, ``batch-build``, ``run-server``, ``drift-rebuilder``, the bench
+section children, ``chip_smoke.py``).
+
+Placement: ``$JAX_COMPILATION_CACHE_DIR`` when set (that directory and no
+other), otherwise :data:`DEFAULT_CACHE_DIR`, one fixed git-ignored path
+inside the checkout. A cache that moves never hits, so the path carries
+nothing that varies between runs or hosts.
 
 Effectiveness is observable: :func:`setup_persistent_xla_cache` records the
 cache's entry count and byte size at startup into the telemetry registry
@@ -11,6 +17,7 @@ future builds will skip."""
 import logging
 import os
 import re
+import threading
 from typing import Optional, Tuple
 
 # entry count at setup, so record_cache_growth can report the delta
@@ -104,22 +111,20 @@ class CosmeticAotMismatchFilter(logging.Filter):
 
 _AOT_FILTER = CosmeticAotMismatchFilter()
 
-# loggers the XLA:CPU AOT loader warning can surface through (direct jax
-# loggers plus warnings-module capture); filters don't propagate, so the
-# filter is attached to each
-_AOT_LOGGER_NAMES = (
-    "jax",
-    "jax._src.compiler",
-    "jax._src.compilation_cache",
-    "jax._src.cache_key",
-    "py.warnings",
-)
-
 
 def install_aot_warning_filter() -> None:
-    """Attach the cosmetic-mismatch filter to the jax loggers (idempotent:
-    logging.Logger.addFilter is a no-op for an already-attached filter)."""
-    for name in _AOT_LOGGER_NAMES:
+    """Attach the cosmetic-mismatch filter to every jax logger that exists
+    (whichever module the XLA:CPU AOT loader warning surfaces through) and
+    to the warnings-module capture. Filters don't propagate to parent
+    loggers, so each gets its own; idempotent, because
+    logging.Logger.addFilter is a no-op for an already-attached filter."""
+    import jax  # noqa: F401 — importing creates the per-module jax loggers
+
+    names = ["jax", "py.warnings"] + [
+        name for name in logging.root.manager.loggerDict
+        if name.startswith("jax.")
+    ]
+    for name in names:
         logging.getLogger(name).addFilter(_AOT_FILTER)
     for handler in logging.getLogger().handlers:
         handler.addFilter(_AOT_FILTER)
@@ -167,12 +172,12 @@ def host_fingerprint() -> str:
     """Short stable hash of everything that makes an XLA:CPU AOT artifact
     host-specific: machine arch, CPU feature flags, and the jaxlib version.
 
-    Partitioning the persistent cache by platform tag alone is not enough:
-    XLA:CPU AOT executables bake in the compile host's CPU features, and
-    loading one on a host with different features warns ("could lead to
-    execution errors such as SIGILL") and can crash. TPU executables don't
-    depend on host CPU features, but including the fingerprint there too
-    only costs a cold cache after a host change — never a bad artifact.
+    Stamped into the manifest of programs shipped with an artifact
+    (serializer/programs.py): XLA:CPU AOT executables bake in the compile
+    host's CPU features, and loading one on a host with different features
+    warns ("could lead to execution errors such as SIGILL") and can crash.
+    The persistent compile cache is NOT keyed on it — jax's own cache key
+    covers what the compiler depends on.
     """
     import hashlib
     import platform
@@ -197,16 +202,67 @@ def host_fingerprint() -> str:
     return hashlib.sha1("|".join(parts).encode()).hexdigest()[:12]
 
 
-def setup_persistent_xla_cache(min_compile_secs: float = 1.0) -> str:
-    """Point jax at the platform+host-partitioned persistent compile cache.
+# <checkout>/.jax_cache — fixed, inside the checkout, listed in .gitignore
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    ),
+    ".jax_cache",
+)
 
-    Via ``jax.config``, not env: jax reads ``JAX_COMPILATION_CACHE_DIR`` at
-    import, long before callers run. Partitioned by platform tag AND a host
-    fingerprint (arch + CPU flags + jaxlib version): a remote-compiled
-    artifact must never be offered to a process on a host with different
-    machine features (the round-4 bench drowned in XLA:CPU AOT
-    feature-mismatch warnings from exactly that). Failures are swallowed
-    (the cache is an optimization only). Returns the dir used.
+
+_listening = False
+
+
+def _count_compiles() -> None:
+    """Feed jax's own compile events into the telemetry registry (once per
+    process), so every process that compiles can say how many programs it
+    compiled, how many it loaded from the persistent cache, and how long it
+    waited for both — read by ``chip_smoke.py`` from the metrics file of a
+    build and from ``/debug/vars`` of a server."""
+    global _listening
+    if _listening:
+        return
+    _listening = True
+    import jax.monitoring
+
+    from gordo_tpu.observability import metrics as metric_catalog
+
+    # a cache hit is announced inside the compile-or-load call whose
+    # duration event follows on the same thread
+    hit = threading.local()
+
+    def on_event(event: str, **_kwargs) -> None:
+        if event == "/jax/compilation_cache/cache_hits":
+            hit.pending = True
+
+    def on_duration(event: str, seconds: float, **_kwargs) -> None:
+        if event != "/jax/core/compile/backend_compile_duration":
+            return
+        source = "persistent_cache" if getattr(hit, "pending", False) else "compiled"
+        hit.pending = False
+        metric_catalog.XLA_COMPILES.labels(source=source).inc()
+        metric_catalog.XLA_COMPILE_SECONDS.inc(seconds)
+
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
+    jax.monitoring.register_event_listener(on_event)
+
+
+def cache_dir() -> str:
+    """Where the persistent cache lives. Touches neither jax nor the disk,
+    so a parent that must stay off the device can ask too."""
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR") or DEFAULT_CACHE_DIR
+
+
+def setup_persistent_xla_cache() -> str:
+    """Turn on jax's persistent compile cache and return its directory.
+
+    ``$JAX_COMPILATION_CACHE_DIR`` wins and no other directory is set in
+    code; without it the cache lives at :data:`DEFAULT_CACHE_DIR`. Takes the
+    device (it asks jax for the backend), so call it from the process that
+    computes. Raises when the directory cannot be created or jax refuses
+    the setting: a process that silently compiles everything again is a
+    performance bug nobody sees.
     """
     global _entries_at_setup, _cache_dir
     import jax
@@ -215,30 +271,28 @@ def setup_persistent_xla_cache(min_compile_secs: float = 1.0) -> str:
     # the cosmetic feature-mismatch warning is silenced here (genuine ISA
     # mismatches still pass the filter and stay loud)
     install_aot_warning_filter()
-    cache_dir = os.environ.get(
-        "JAX_COMPILATION_CACHE_DIR",
-        "/tmp/gordo_tpu_xla_cache-"
-        + (os.environ.get("JAX_PLATFORMS") or "default")
-        + "-" + host_fingerprint(),
-    )
-    try:
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        jax.config.update(
-            "jax_persistent_cache_min_compile_time_secs", min_compile_secs
-        )
-    except Exception:  # noqa: BLE001
-        pass
+    path = cache_dir()
+    if jax.config.jax_compilation_cache_dir != path:
+        # either the default, or a variable exported after jax read its
+        # environment at import
+        jax.config.update("jax_compilation_cache_dir", path)
+    os.makedirs(path, exist_ok=True)
+    if jax.default_backend() != "cpu":
+        # keep every accelerator compile whatever it cost, so that a second
+        # run of the same programs compiles nothing and adds no entries. On
+        # CPU jax's own threshold stays: XLA:CPU prints two multi-kilobyte
+        # feature-list lines from C++ for every program it loads back, which
+        # no Python log filter can drop
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     # startup snapshot of cache effectiveness (warm entries available to
     # this process); export-time record_cache_growth() reports what was
     # added. Gauges are cheap and the scan is one directory listing.
-    try:
-        from gordo_tpu.observability import metrics as metric_catalog
+    from gordo_tpu.observability import metrics as metric_catalog
 
-        _cache_dir = cache_dir
-        entries, size = cache_stats(cache_dir)
-        _entries_at_setup = entries
-        metric_catalog.XLA_CACHE_ENTRIES.set(entries)
-        metric_catalog.XLA_CACHE_BYTES.set(size)
-    except Exception:  # noqa: BLE001 — observability must not break setup
-        pass
-    return cache_dir
+    _count_compiles()
+    _cache_dir = path
+    entries, size = cache_stats(path)
+    _entries_at_setup = entries
+    metric_catalog.XLA_CACHE_ENTRIES.set(entries)
+    metric_catalog.XLA_CACHE_BYTES.set(size)
+    return path

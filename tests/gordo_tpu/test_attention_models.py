@@ -57,6 +57,22 @@ def test_flash_attention_grad_matches_reference():
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4)
 
 
+def test_flash_attention_never_picks_the_interpreter_itself():
+    """Off the TPU the kernel cannot compile. Without ``interpret=True`` the
+    call must fail there — never fall into the interpreter on its own — and
+    so must ``attention: flash`` reached through the dispatcher."""
+    from gordo_tpu.ops.attention import dot_product_attention
+
+    q = jnp.zeros((1, 2, 128, 8), jnp.float32)
+    assert jax.default_backend() == "cpu"
+    with pytest.raises(ValueError, match="interpret mode"):
+        flash_attention(q, q, q)
+    with pytest.raises(ValueError, match="interpret mode"):
+        dot_product_attention(q, q, q, impl="flash")
+    # the caller who asks for the interpreter gets it
+    assert flash_attention(q, q, q, interpret=True).shape == q.shape
+
+
 def test_multihead_attention_shapes_and_heads():
     rng = np.random.RandomState(2)
     x = jnp.asarray(rng.randn(3, 16, 32).astype(np.float32))
@@ -431,27 +447,34 @@ def test_flash_attention_lowers_through_mosaic_for_tpu():
     assert bf16_mlir.count("tpu_custom_call") == 3
 
 
-def test_flash_dispatch_gate_matches_lowering_support(monkeypatch):
-    """_flash_ok must only admit shapes the Mosaic lowering handles: dh<64
-    was measured to hang TPU lowering, and t>4096 approaches the VMEM
-    budget (long sequences are ring attention's job)."""
+def test_flash_dispatch_gate_is_the_rectangle_proven_on_the_chip(monkeypatch):
+    """``auto`` admits exactly the (T, head dim) rectangle whose corners
+    ``chip_smoke.py`` compiles on the chip — and nothing off the TPU."""
     import jax.numpy as jnp
 
+    import chip_smoke
     from gordo_tpu.ops import attention
 
-    monkeypatch.setattr(
-        attention.jax, "default_backend", lambda: "tpu"
-    )
+    def ok(t, dh, t_k=None):
+        q = jnp.zeros((1, 2, t, dh), jnp.float32)
+        k = jnp.zeros((1, 2, t_k or t, dh), jnp.float32)
+        return attention._flash_ok(q, k)
 
-    def ok(t, dh):
-        x = jnp.zeros((1, 2, t, dh), jnp.float32)
-        return attention._flash_ok(x, x)
-
-    assert ok(512, 64) and ok(4096, 128)
-    assert not ok(512, 8)      # sub-64 head dim: lowering hang
-    assert not ok(512, 16)
-    assert not ok(8192, 64)    # past the VMEM-budget cap
-    assert not ok(128, 64)     # below the win threshold
+    assert not ok(512, 64)  # the tests' backend is the CPU
+    monkeypatch.setattr(attention.jax, "default_backend", lambda: "tpu")
+    assert all(ok(t, dh) for t, dh in chip_smoke.FLASH_CORNERS)
+    assert set(chip_smoke.FLASH_CORNERS) == {
+        (256, 64), (256, 128), (4096, 64), (4096, 128)
+    }
+    assert ok(512, 64) and ok(1024, 128)
+    # outside the rectangle in each direction
+    assert not ok(128, 64) and not ok(8192, 64)
+    assert not ok(512, 16) and not ok(512, 32) and not ok(512, 256)
+    # the head dim is the kernel's lane dimension: only the widths the chip
+    # compiled, nothing in between
+    assert not ok(2048, 96) and not ok(512, 72) and not ok(512, 120)
+    assert not ok(300, 64)           # not a multiple of the 128-row blocks
+    assert not ok(512, 64, t_k=256)  # cross-length attention
 
 
 def test_flash_attention_bfloat16_matches_reference():

@@ -91,56 +91,31 @@ def test_cv_build_flops_composition():
 
 def test_mfu_and_peak_lookup():
     assert flops_mod.chip_peak_flops("TPU v4") == 275e12
-    assert flops_mod.chip_peak_flops("TPU v5 lite") == 394e12
-    assert flops_mod.chip_peak_flops("cpu-whatever") is None
+    # the bf16 figure; 394e12 is the v5e's int8 peak
+    assert flops_mod.chip_peak_flops("TPU v5 lite") == 197e12
+    assert flops_mod.chip_peak_flops("TPU v5e") == 197e12
     assert flops_mod.mfu(1e12, 1.0, "TPU v4") == pytest.approx(1e12 / 275e12)
     # aggregate peak scales with device count
     assert flops_mod.mfu(1e12, 1.0, "TPU v4", n_devices=4) == pytest.approx(
         1e12 / (4 * 275e12)
     )
-    # ISSUE 9: unknown chips fall back to the measured GEMM peak instead
-    # of returning None — CPU bench records now carry a real MFU
-    assert flops_mod.mfu(1e12, 1.0, "unknown") is not None
+    assert flops_mod.mfu(1e9, 0.0, "TPU v4") is None
 
 
-def test_peak_env_override(monkeypatch):
+def test_cpu_has_no_peak_and_no_mfu():
+    # a host has no datasheet peak: its MFU is "not measured", never a
+    # number derived from a timed GEMM
+    assert flops_mod.chip_peak_flops("cpu") is None
+    assert flops_mod.mfu(1e12, 1.0, "cpu") is None
+
+
+def test_unknown_device_kind_raises(monkeypatch):
+    # no environment override rescues an unknown accelerator either
     monkeypatch.setenv("GORDO_TPU_PEAK_FLOPS", "1e15")
-    assert flops_mod.chip_peak_flops("anything") == 1e15
-
-
-def test_peak_source_tags(monkeypatch):
-    monkeypatch.delenv("GORDO_TPU_PEAK_FLOPS", raising=False)
-    assert flops_mod.peak_flops_with_source("TPU v4") == (275e12, "table")
-    peak, source = flops_mod.peak_flops_with_source("cpu-whatever")
-    assert source == "measured" and peak > 0
-    monkeypatch.setenv("GORDO_TPU_PEAK_FLOPS", "1e15")
-    assert flops_mod.peak_flops_with_source("anything") == (1e15, "env")
-
-
-def test_mfu_with_source_threads_the_tag(monkeypatch):
-    monkeypatch.delenv("GORDO_TPU_PEAK_FLOPS", raising=False)
-    value, source = flops_mod.mfu_with_source(1e12, 1.0, "TPU v4")
-    assert value == pytest.approx(1e12 / 275e12)
-    assert source == "table"
-    value, source = flops_mod.mfu_with_source(1e9, 1.0, "cpu-whatever")
-    assert source == "measured" and value is not None and value > 0
-    # degenerate wall: no MFU, but the source tag still says which peak
-    # would have been used
-    value, source = flops_mod.mfu_with_source(1e9, 0.0, "TPU v4")
-    assert value is None and source == "table"
-
-
-def test_measured_peak_cached_and_positive():
-    first = flops_mod.measured_peak_flops()
-    assert first is not None and first > 0
-    # in-process memo: the second call must not re-time the GEMM
-    assert flops_mod.measured_peak_flops() == first
-
-
-def test_serving_peak_flops_reports_a_peak():
-    peak, source = flops_mod.serving_peak_flops()
-    assert peak is not None and peak > 0
-    assert source in ("env", "table", "measured")
+    with pytest.raises(ValueError, match="no peak FLOP/s on record"):
+        flops_mod.chip_peak_flops("TPU v9 imaginary")
+    with pytest.raises(ValueError, match="no peak FLOP/s on record"):
+        flops_mod.mfu(1e12, 1.0, "unknown")
 
 
 # --------------------------------------------------------- MoE aux loss

@@ -450,6 +450,39 @@ def test_warmup_triggers_batcher_calibration(
     assert on + off >= 1  # calibration ran and recorded a decision
 
 
+@pytest.mark.parametrize("batching_wins", [True, False])
+def test_warmup_compiles_both_paths_whatever_the_self_ab_decided(
+    model_collection_directory, trained_model_directories, monkeypatch,
+    batching_wins,
+):
+    """The program set a worker compiles must not depend on which way the
+    measured self-A/B fell: with the batcher on, warmup runs the per-request
+    program of every (architecture, row bucket) too."""
+    from gordo_tpu.ops import train as train_ops
+    from gordo_tpu.server import batcher as batcher_mod
+    from gordo_tpu.server import warmup
+
+    monkeypatch.setenv("GORDO_TPU_SERVING_BATCH", "auto")
+    monkeypatch.setattr(batcher_mod, "_batcher", None)
+    monkeypatch.setattr(
+        batcher_mod.CrossModelBatcher, "_calibrate",
+        lambda self, spec, params, X: self._spec_on.setdefault(
+            spec, batching_wins
+        ),
+    )
+    direct_rows = []
+    real_predict_fn = train_ops.predict_fn
+
+    def recording_predict_fn(spec):
+        fn = real_predict_fn(spec)
+        return lambda params, X: direct_rows.append(len(X)) or fn(params, X)
+
+    monkeypatch.setattr(train_ops, "predict_fn", recording_predict_fn)
+    result = warmup.warmup_collection(model_collection_directory)
+    assert result["failed"] == []
+    assert set(direct_rows) == set(warmup.DEFAULT_BUCKET_ROWS)
+
+
 def test_warmup_rows_env_parsing(monkeypatch):
     """A malformed GORDO_TPU_WARMUP_ROWS falls back to the defaults with a
     warning instead of aborting warmup (best-effort contract)."""

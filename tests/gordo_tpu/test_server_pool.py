@@ -472,3 +472,115 @@ def test_arbiter_drain_on_sigterm_finishes_inflight(
             os.killpg(proc.pid, signal.SIGKILL)
         except ProcessLookupError:
             pass
+
+
+# ------------------------------------------------- one worker per TPU chip
+def test_chip_count_needs_both_the_bus_and_a_device_file(monkeypatch):
+    """A sealed one-chip machine lists four chips on PCI but hands out one
+    device file (seen on the chip tool's machine); a VFIO group without a
+    TPU on the bus is not a chip; and a process held off the TPU counts
+    none, because no worker will take one."""
+    from gordo_tpu.util import chips
+
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    monkeypatch.setattr(chips, "_chips_on_pci", lambda: 4)
+    monkeypatch.setattr(chips, "_chip_device_files", lambda: 1)
+    assert chips.attached_tpu_chips() == 1
+    monkeypatch.setattr(chips, "_chip_device_files", lambda: 4)
+    assert chips.attached_tpu_chips() == 4
+    monkeypatch.setattr(chips, "_chips_on_pci", lambda: 0)
+    assert chips.attached_tpu_chips() == 0
+    monkeypatch.setattr(chips, "_chips_on_pci", lambda: 4)
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu,cpu")
+    assert chips.attached_tpu_chips() == 4
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    assert chips.attached_tpu_chips() == 0
+
+
+def test_pool_is_one_worker_per_chip_or_refused(monkeypatch):
+    from gordo_tpu.server.server import resolve_workers
+    from gordo_tpu.util import chips
+
+    monkeypatch.setattr(chips, "attached_tpu_chips", lambda: 4)
+    assert resolve_workers(None) == (4, 4)   # the default: one per chip
+    assert resolve_workers(2) == (2, 4)      # fewer is allowed
+    monkeypatch.setattr(chips, "attached_tpu_chips", lambda: 1)
+    assert resolve_workers(None) == (1, 1)
+    # more workers than chips could never start: refused, naming the count
+    with pytest.raises(ValueError, match="1 TPU chip"):
+        resolve_workers(2)
+    monkeypatch.setattr(chips, "attached_tpu_chips", lambda: 0)
+    assert resolve_workers(None) == (2, 0)   # no chips: the old default
+    assert resolve_workers(3) == (3, 0)
+
+
+def test_run_server_cli_refuses_more_workers_than_chips(monkeypatch):
+    from click.testing import CliRunner
+
+    from gordo_tpu.cli.cli import gordo
+    from gordo_tpu.util import chips
+
+    monkeypatch.setattr(chips, "attached_tpu_chips", lambda: 1)
+    result = CliRunner().invoke(gordo, ["run-server", "--workers", "2"])
+    assert result.exit_code == 2
+    assert "this host has 1 TPU chip" in result.output
+
+
+def test_pinning_describes_a_one_chip_topology(monkeypatch):
+    from gordo_tpu.util import chips
+
+    for name in ("TPU_VISIBLE_CHIPS", "TPU_PROCESS_BOUNDS", "TPU_PROCESS_PORT"):
+        monkeypatch.delenv(name, raising=False)
+    monkeypatch.setattr(os, "environ", dict(os.environ))
+    chips.pin_process_to_chip(2)
+    assert os.environ["TPU_VISIBLE_CHIPS"] == "2"
+    assert os.environ["TPU_CHIPS_PER_PROCESS_BOUNDS"] == "1,1,1"
+    assert os.environ["TPU_PROCESS_BOUNDS"] == "1,1,1"
+    # distinct ports, so four pinned workers never meet
+    assert os.environ["TPU_PROCESS_PORT"] == "8478"
+
+
+_UNCOUNTED_CHIP_SCRIPT = """
+import logging, os, sys
+logging.basicConfig(level=logging.INFO, stream=sys.stderr)
+sys.path.insert(0, {repo!r})
+import jax
+jax.config.update("jax_platforms", "cpu")
+from gordo_tpu.observability import device
+from gordo_tpu.server.server import ChipLayoutError, run_server
+from gordo_tpu.util import chips
+# the launcher counts no chip, yet every worker's jax reports a TPU
+chips.attached_tpu_chips = lambda: 0
+device.describe = lambda: dict(
+    platform="tpu", device_kind="TPU v5 lite", device_count=1
+)
+try:
+    run_server(host="127.0.0.1", port={port}, workers=2, warmup=False)
+except ChipLayoutError as exc:
+    print("REFUSED:", exc, flush=True)
+    sys.exit(3)
+"""
+
+
+def test_pool_stops_when_a_worker_finds_chips_the_launcher_did_not_count(
+    tmp_path,
+):
+    """The arbiter stays off jax and counts chips from sysfs; where that
+    reads 0 on a real TPU host it starts unpinned workers and only one can
+    hold the chips. The worker that does says so and the whole pool exits
+    with the chip count — no respawn loop behind a pool that reports up."""
+    script = tmp_path / "serve.py"
+    script.write_text(
+        _UNCOUNTED_CHIP_SCRIPT.format(repo=REPO, port=_free_port())
+    )
+    proc = subprocess.run(
+        [sys.executable, str(script)], capture_output=True, text=True,
+        timeout=120, env={**os.environ, "MODEL_COLLECTION_DIR": str(tmp_path)},
+    )
+    assert proc.returncode == 3, proc.stderr[-2000:]
+    assert "REFUSED: this worker found 1 TPU chip(s) (TPU v5 lite)" in proc.stdout
+    assert "launcher counted 0" in proc.stdout
+    assert "run with --workers 1" in proc.stdout
+    # stopped at the first report: nothing was respawned
+    assert "spawning replacement" not in proc.stderr
+    assert "retrying one respawn" not in proc.stderr
