@@ -431,11 +431,15 @@ def device_trace(seconds: float) -> Dict[str, Any]:
             import tempfile
 
             out_dir = tempfile.mkdtemp(prefix="gordo-device-trace-")
-        import jax
+        from gordo_tpu.util import profiling
 
-        jax.profiler.start_trace(out_dir)
-        time.sleep(seconds)
-        jax.profiler.stop_trace()
+        # spans are on while the session is open: it holds the serving
+        # path's stages as gordo.<name> marks beside the device's events
+        with profiling.session(out_dir) as opened:
+            if not opened:
+                return {"error": "a profiler session is already open",
+                        "dir": out_dir}
+            time.sleep(seconds)
     except Exception as exc:  # noqa: BLE001 — capture is advisory
         return {"error": str(exc), "dir": out_dir}
     files = 0

@@ -7,10 +7,11 @@ substrate the fleet paths plug into instead:
 
 - :func:`span` — a thread-safe context manager over monotonic clocks.
   Spans are recorded as Chrome trace events (openable in Perfetto or
-  ``chrome://tracing``) when a trace is active, mirrored into JAX device
-  traces via :func:`gordo_tpu.util.profiling.annotate` when
-  ``$GORDO_TPU_PROFILE_DIR`` profiling is on, and optionally observed into
-  a duration histogram. When neither a trace nor profiling nor span timing
+  ``chrome://tracing``) when a trace is active, written into an open
+  ``jax.profiler`` session as the host mark ``gordo.<name>`` (on the clock
+  of the device's own events; whoever opens a session turns spans on for
+  its duration, :func:`spans_on`), and optionally observed into
+  a duration histogram. When neither a trace nor span timing
   is enabled, ``span()`` returns one shared no-op singleton — the disabled
   path allocates nothing and times nothing (asserted by
   tests/gordo_tpu/test_telemetry.py), so instrumented hot paths cost a
@@ -38,10 +39,12 @@ every metric name carries a ``gordo_`` prefix and non-empty help text.
 True
 """
 
+import contextlib
 import json
 import math
 import os
 import re
+import sys
 import threading
 import time
 from typing import Any, Dict, List, Optional, Sequence, Tuple
@@ -58,6 +61,7 @@ __all__ = [
     "gauge",
     "histogram",
     "span",
+    "spans_on",
     "add_trace_event",
     "spans_enabled",
     "enable_spans",
@@ -615,6 +619,10 @@ class _NullSpan:
 
 _NULL_SPAN = _NullSpan()
 
+# every live span is the host mark "gordo.<name>" in an open jax.profiler
+# session; a trace reducer tells the program's marks by this prefix
+PROFILER_MARK = "gordo."
+
 
 class _Span:
     __slots__ = (
@@ -634,12 +642,17 @@ class _Span:
         self.attrs.update(attrs)
 
     def __enter__(self):
-        from gordo_tpu.util.profiling import annotate
-
-        # the JAX TraceAnnotation shares the span's name, so device-op
-        # timelines (GORDO_TPU_PROFILE_DIR) and telemetry spans line up
-        self._annotation = annotate(self.name)
-        self._annotation.__enter__()
+        # the span's mark on the profiler's clock: inside an open
+        # jax.profiler session it lands beside the runtime's and the
+        # device's events, outside one a TraceMe is a branch. A process
+        # that never imported jax has no session to write into
+        jax = sys.modules.get("jax")
+        self._annotation = None
+        if jax is not None:
+            self._annotation = jax.profiler.TraceAnnotation(
+                PROFILER_MARK + self.name
+            )
+            self._annotation.__enter__()
         # request-scoped tracing: under an active trace context this span
         # becomes the ambient parent for anything opened inside it
         self._ctx = _request_tracing.current()
@@ -652,7 +665,8 @@ class _Span:
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         duration = time.monotonic() - self._t0
-        self._annotation.__exit__(exc_type, exc, tb)
+        if self._annotation is not None:
+            self._annotation.__exit__(exc_type, exc, tb)
         ctx = self._ctx
         if self._token is not None:
             _request_tracing.pop(self._token)
@@ -686,20 +700,16 @@ def span(name: str, hist: Optional[Histogram] = None, links=(), **attrs):
     """A named timing span.
 
     Active when a trace was started (:func:`start_trace`), span timing was
-    enabled (:func:`enable_spans`, the ``--metrics-file``-only mode), a
-    request trace context is attached (:mod:`..tracing` — the span joins
-    the request's tree), or JAX profiling is on
-    (``$GORDO_TPU_PROFILE_DIR``). Otherwise returns the shared no-op
-    singleton. ``hist``: a :class:`Histogram` to observe the span's
-    duration into on exit (phase-duration metrics without a second timer
-    at the call site). ``links``: (trace_id, span_id) pairs of correlated
-    spans in other traces (the batcher's co-fused riders).
+    enabled (:func:`enable_spans`, the ``--metrics-file``-only mode;
+    :func:`spans_on` for a profiler session's duration), or a request trace
+    context is attached (:mod:`..tracing` — the span joins the request's
+    tree). Otherwise returns the shared no-op singleton. ``hist``: a
+    :class:`Histogram` to observe the span's duration into on exit
+    (phase-duration metrics without a second timer at the call site).
+    ``links``: (trace_id, span_id) pairs of correlated spans in other
+    traces (the batcher's co-fused riders).
     """
-    if (
-        not _spans_enabled
-        and _request_tracing.current() is None
-        and not os.environ.get("GORDO_TPU_PROFILE_DIR")
-    ):
+    if not _spans_enabled and _request_tracing.current() is None:
         return _NULL_SPAN
     return _Span(name, hist, attrs, links)
 
@@ -725,6 +735,21 @@ def enable_spans() -> None:
     global _spans_enabled
     with _state_lock:
         _spans_enabled = True
+
+
+@contextlib.contextmanager
+def spans_on():
+    """Span timing on for the enclosed block, then back to what it was:
+    for whoever opens a ``jax.profiler`` session, so that the session holds
+    the program's stages (every live span is a ``gordo.<name>`` mark)."""
+    global _spans_enabled
+    with _state_lock:
+        was, _spans_enabled = _spans_enabled, True
+    try:
+        yield
+    finally:
+        with _state_lock:
+            _spans_enabled = was or _trace is not None
 
 
 def start_trace() -> None:

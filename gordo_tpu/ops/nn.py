@@ -197,6 +197,13 @@ def init_model_params(rng: jax.Array, spec: ModelSpec) -> Params:
     return params
 
 
+# The named scopes here, in ops/train.py and in parallel/batch_trainer.py
+# (dense, lstm_cell, attention, window_gather, optimizer_update, fold_predict)
+# are what a device trace is reduced by (scripts/trace_by_scope.py; the table
+# is in docs/observability.md): metadata only, and stable names, so rename
+# none. JAX writes jvp(...) / transpose(jvp(...)) into the op_name for the
+# forward and backward pass under differentiation.
+@jax.named_scope("dense")
 def _apply_dense(layer: DenseLayer, p, x):
     out = x @ p["kernel"] + p["bias"]
     return _activation(layer.activation)(out)
@@ -216,6 +223,7 @@ def _apply_lstm(layer: LSTMLayer, p, x):
 
     W = jnp.concatenate([p["kernel"], p["recurrent_kernel"]], axis=0)
 
+    @jax.named_scope("lstm_cell")
     def step(carry, xt):
         h, c = carry
         # one fused (B, in+units) @ (in+units, 4*units) gate matmul; runs at
@@ -266,6 +274,7 @@ def _apply_positional_encoding(layer: PositionalEncoding, x):
     return x + pe[None, :, :]
 
 
+@jax.named_scope("attention")
 def _attention_sublayer(layer, p, x, fuse_qkv=None):
     """Pre-LN MHA + residual, shared by TransformerBlock and MoEBlock
     (same param keys, same dispatch). ``fuse_qkv=None`` defers to the

@@ -82,6 +82,7 @@ def _loss_terms(spec: ModelSpec, params, xb, yb, wb):
     return jnp.sum(per_sample * wb) / w_sum + penalty
 
 
+@jax.named_scope("window_gather")
 def _gather_batch(spec: ModelSpec, X, y, idx):
     """Gather a minibatch by sample (or window-start) indices."""
     if spec.lookback_window <= 1 and spec.lookahead == 0:
@@ -141,8 +142,9 @@ def make_epoch_fn(
             loss, grads = jax.value_and_grad(_loss_terms, argnums=1)(
                 spec, params, xb, yb, wb
             )
-            updates, opt_state = opt.update(grads, opt_state, params)
-            params = optax.apply_updates(params, updates)
+            with jax.named_scope("optimizer_update"):
+                updates, opt_state = opt.update(grads, opt_state, params)
+                params = optax.apply_updates(params, updates)
             bw = jnp.sum(wb)
             return (params, opt_state, loss_sum + loss * bw, w_sum + bw), None
 
@@ -269,13 +271,16 @@ def make_masked_epoch_fn(
             )
             bw = jnp.sum(wb)
             live = bw > 0
-            updates, new_opt_state = opt.update(grads, opt_state, params)
-            new_params = optax.apply_updates(params, updates)
-            pick = functools.partial(
-                jax.tree_util.tree_map, lambda a, b: jnp.where(live, a, b)
-            )
-            params = pick(new_params, params)
-            opt_state = pick(new_opt_state, opt_state)
+            # the scope holds the live-step select too: XLA fuses the update
+            # into it and names the fusion after its root
+            with jax.named_scope("optimizer_update"):
+                updates, new_opt_state = opt.update(grads, opt_state, params)
+                new_params = optax.apply_updates(params, updates)
+                pick = functools.partial(
+                    jax.tree_util.tree_map, lambda a, b: jnp.where(live, a, b)
+                )
+                params = pick(new_params, params)
+                opt_state = pick(new_opt_state, opt_state)
             loss = jnp.where(live, loss, 0.0)
             return (i + 1, params, opt_state, loss_sum + loss * bw, w_sum + bw)
 

@@ -84,6 +84,24 @@ _PHASE_COMPILE = metric_catalog.BUILD_PHASE_SECONDS.labels(phase="compile")
 _PHASE_TRAIN = metric_catalog.BUILD_PHASE_SECONDS.labels(phase="train")
 _PHASE_SERIALIZE = metric_catalog.BUILD_PHASE_SECONDS.labels(phase="serialize")
 _PHASE_ASSEMBLE = metric_catalog.BUILD_PHASE_SECONDS.labels(phase="assemble")
+# the stages of the thread that runs build(): contiguous, non-overlapping
+# walls that tile it (compile, train and tail are parents), where the six
+# above are per machine on pool threads or bucket walls. Each is a span and
+# a phase label of its own name (docs/observability.md has the table)
+_STAGE_SECONDS = {
+    name: metric_catalog.BUILD_PHASE_SECONDS.labels(phase=name)
+    for name in (
+        "plan", "fetch_stage", "validate_stage", "bucket_prep", "stack_h2d",
+        "launch", "wait", "d2h", "slice", "tail", "drain", "finalize",
+    )
+}
+
+
+def _stage(name: str, **attrs):
+    """One stage of the build thread as a span that observes its wall into
+    ``gordo_build_phase_seconds{phase=<name>}``."""
+    return telemetry.span(name, _STAGE_SECONDS[name], **attrs)
+
 
 # first-compile wall per bucket-program cache key: a later cache hit credits
 # this wall to the compile-seconds-saved counter (the measured wall includes
@@ -345,6 +363,7 @@ def _minmax(x_train, x_apply):
     return (x_apply - mn) * scale
 
 
+@jax.named_scope("fold_predict")
 def _predict_windows(spec: ModelSpec, params, X):
     """Model output over a contiguous slice (windowed for recurrent specs)."""
     if spec.lookback_window <= 1 and spec.lookahead == 0:
@@ -353,7 +372,8 @@ def _predict_windows(spec: ModelSpec, params, X):
     n_out = X.shape[0] - spec.lookback_window + 1 - spec.lookahead
     idx = jnp.arange(n_out)
     window = jnp.arange(spec.lookback_window)
-    xb = X[idx[:, None] + window[None, :]]
+    with jax.named_scope("window_gather"):
+        xb = X[idx[:, None] + window[None, :]]
     out, _ = apply_model(spec, params, xb)
     return out
 
@@ -965,140 +985,104 @@ class BatchedModelBuilder:
         plans: Dict[int, _Plan] = {}
         serial: List[int] = []
 
-        # resume prefilter. Registry lookups (cheap) run threaded for the
-        # whole fleet, and each hit is OWNED by exactly one process — keyed
-        # by the machine's GLOBAL index, not its position in the locally
-        # observed hit list: registries can drift between processes
-        # (overlapping builds registering keys mid-prefilter), and
-        # position-keyed ownership would then double- or zero-own a machine.
-        # The owner unpickles and returns it, the others skip it entirely.
-        cached_results: Dict[int, Tuple[Any, Machine]] = {}
-        foreign_cached: set = set()
-        if self.model_register_dir and self.machines:
-            idxs = list(range(len(self.machines)))
-            with ThreadPoolExecutor(max_workers=min(16, len(idxs))) as pool:
-                paths = list(
-                    pool.map(lambda i: self._cached_path(self.machines[i]), idxs)
-                )
-            owned_hits = []
-            for i, path in zip(idxs, paths):
-                if not path:
+        with _stage("plan", machines=len(self.machines)):
+            # resume prefilter. Registry lookups (cheap) run threaded for the
+            # whole fleet, and each hit is OWNED by exactly one process — keyed
+            # by the machine's GLOBAL index, not its position in the locally
+            # observed hit list: registries can drift between processes
+            # (overlapping builds registering keys mid-prefilter), and
+            # position-keyed ownership would then double- or zero-own a machine.
+            # The owner unpickles and returns it, the others skip it entirely.
+            cached_results: Dict[int, Tuple[Any, Machine]] = {}
+            foreign_cached: set = set()
+            if self.model_register_dir and self.machines:
+                idxs = list(range(len(self.machines)))
+                with ThreadPoolExecutor(max_workers=min(16, len(idxs))) as pool:
+                    paths = list(
+                        pool.map(lambda i: self._cached_path(self.machines[i]), idxs)
+                    )
+                owned_hits = []
+                for i, path in zip(idxs, paths):
+                    if not path:
+                        continue
+                    if distributed.owns_serial_machine(
+                        _machine_seed(self.machines[i])
+                    ):
+                        owned_hits.append((i, path))
+                    else:
+                        foreign_cached.add(i)
+                if owned_hits:
+                    with ThreadPoolExecutor(
+                        max_workers=min(16, len(owned_hits))
+                    ) as pool:
+                        loaded = pool.map(
+                            lambda ip: self._load_cached_guarded(*ip), owned_hits
+                        )
+                        cached_results = {
+                            i: c
+                            for (i, _), c in zip(owned_hits, loaded)
+                            if c is not None
+                        }
+
+            for i, machine in enumerate(self.machines):
+                if i in foreign_cached:
+                    continue  # cached; another process owns and returns it
+                if i in cached_results:
+                    cached = cached_results[i]
+                    logger.info("Machine %s: loaded from cache", machine.name)
+                    metric_catalog.BUILD_MACHINES.labels(outcome="cached").inc()
+                    results[i] = cached
+                    model_dir = self._machine_output_dir(machine.name)
+                    if model_dir and not os.path.exists(
+                        os.path.join(model_dir, "model.pkl")
+                    ):
+                        # cache hit from a previous run's output_dir; materialize
+                        # the artifact in this run's tree too
+                        self._persist(machine, *cached)
                     continue
-                if distributed.owns_serial_machine(
+                plan = _plan_machine(machine)
+                if plan is None:
+                    serial.append(i)
+                else:
+                    plans[i] = plan
+
+            # ownership keyed by a stable hash of the machine name (same rule as
+            # the cached-hit loop above): the serial list's composition depends
+            # on local cache state, so list-POSITION ownership could diverge
+            # between processes, while raw global indices could concentrate load
+            # on one process when unbatchable machines land on a stride
+            for i in serial:
+                if not self.serial_fallback:
+                    raise ValueError(
+                        f"Machine {self.machines[i].name} is not batchable and "
+                        f"serial_fallback=False"
+                    )
+                if not distributed.owns_serial_machine(
                     _machine_seed(self.machines[i])
                 ):
-                    owned_hits.append((i, path))
-                else:
-                    foreign_cached.add(i)
-            if owned_hits:
-                with ThreadPoolExecutor(
-                    max_workers=min(16, len(owned_hits))
-                ) as pool:
-                    loaded = pool.map(
-                        lambda ip: self._load_cached_guarded(*ip), owned_hits
+                    continue
+                logger.info("Machine %s: serial fallback", self.machines[i].name)
+                metric_catalog.SERIAL_FALLBACKS.labels(reason="unbatchable").inc()
+                try:
+                    results[i] = ModelBuilder(self.machines[i]).build(
+                        output_dir=self._machine_output_dir(self.machines[i].name),
+                        model_register_dir=self.model_register_dir,
                     )
-                    cached_results = {
-                        i: c
-                        for (i, _), c in zip(owned_hits, loaded)
-                        if c is not None
-                    }
-
-        for i, machine in enumerate(self.machines):
-            if i in foreign_cached:
-                continue  # cached; another process owns and returns it
-            if i in cached_results:
-                cached = cached_results[i]
-                logger.info("Machine %s: loaded from cache", machine.name)
-                metric_catalog.BUILD_MACHINES.labels(outcome="cached").inc()
-                results[i] = cached
-                model_dir = self._machine_output_dir(machine.name)
-                if model_dir and not os.path.exists(
-                    os.path.join(model_dir, "model.pkl")
-                ):
-                    # cache hit from a previous run's output_dir; materialize
-                    # the artifact in this run's tree too
-                    self._persist(machine, *cached)
-                continue
-            plan = _plan_machine(machine)
-            if plan is None:
-                serial.append(i)
-            else:
-                plans[i] = plan
-
-        # ownership keyed by a stable hash of the machine name (same rule as
-        # the cached-hit loop above): the serial list's composition depends
-        # on local cache state, so list-POSITION ownership could diverge
-        # between processes, while raw global indices could concentrate load
-        # on one process when unbatchable machines land on a stride
-        for i in serial:
-            if not self.serial_fallback:
-                raise ValueError(
-                    f"Machine {self.machines[i].name} is not batchable and "
-                    f"serial_fallback=False"
-                )
-            if not distributed.owns_serial_machine(
-                _machine_seed(self.machines[i])
-            ):
-                continue
-            logger.info("Machine %s: serial fallback", self.machines[i].name)
-            metric_catalog.SERIAL_FALLBACKS.labels(reason="unbatchable").inc()
-            try:
-                results[i] = ModelBuilder(self.machines[i]).build(
-                    output_dir=self._machine_output_dir(self.machines[i].name),
-                    model_register_dir=self.model_register_dir,
-                )
-            except Exception as exc:
-                if self.fail_fast:
-                    raise
-                self._quarantine(
-                    self.machines[i],
-                    stage=faults.STAGE_SERIAL_BUILD,
-                    reason=type(exc).__name__,
-                    error=str(exc),
-                )
-
-        # fetch data concurrently (provider I/O is the per-machine serial cost
-        # the reference paid per pod), then bucket by (spec, shapes, config).
-        # Each fetch retries transient faults with backoff and quarantines
-        # the machine on exhaustion — one dead sensor feed degrades one
-        # machine, not the fleet (the blast radius the reference got from
-        # one-pod-per-machine)
-        if plans:
-            max_workers = min(16, len(plans))
-            with ThreadPoolExecutor(max_workers=max_workers) as pool:
-                records = list(pool.map(self._load_data_guarded, plans.values()))
-            for (i, plan), record in zip(list(plans.items()), records):
-                if record is not None:
-                    self._quarantine(plan.machine, record=record)
-                    del plans[i]
-
-        # pre-flight validation: a NaN column would train to NaN params and
-        # poison nothing but its own vmap lane — but its thresholds/scores
-        # would be garbage and, pre-bucketing, it is trivially isolable
-        for i in list(plans):
-            plan = plans[i]
-            with _machine_trace(plan.machine.name), telemetry.span(
-                "validate", _PHASE_VALIDATE, machine=plan.machine.name
-            ):
-                bad = faults.non_finite_report(plan.X, plan.y)
-            if bad is not None:
-                if self.fail_fast:
-                    raise faults.NonFiniteDataError(
-                        f"machine {plan.machine.name}: {bad}"
+                except Exception as exc:
+                    if self.fail_fast:
+                        raise
+                    self._quarantine(
+                        self.machines[i],
+                        stage=faults.STAGE_SERIAL_BUILD,
+                        reason=type(exc).__name__,
+                        error=str(exc),
                     )
-                self._quarantine(
-                    plan.machine,
-                    stage=faults.STAGE_DATA_VALIDATION,
-                    reason="non_finite_data",
-                    error=bad,
-                )
-                del plans[i]
 
-        self._attach_warm_params(plans)
+        def quarantine(machine, record):
+            self._quarantine(machine, record=record)
 
-        buckets: Dict[Tuple, List[int]] = {}
-        for i, plan in plans.items():
-            buckets.setdefault(plan.bucket_key(), []).append(i)
+        self._fetch_stage(plans, quarantine)
+        buckets = self._validate_stage(plans, quarantine)
 
         for key, idxs in buckets.items():
             bucket_plans = [plans[i] for i in idxs]
@@ -1106,6 +1090,61 @@ class BatchedModelBuilder:
                 results[i] = built
 
         return [results[i] for i in sorted(results)]
+
+    def _fetch_stage(self, plans: Dict[int, _Plan], quarantine) -> None:
+        """Fetch every planned machine's data concurrently (provider I/O is
+        the per-machine serial cost the reference paid per pod). Each fetch
+        retries transient faults with backoff and, on exhaustion, the
+        machine is handed to ``quarantine(machine, record)`` and dropped
+        from ``plans`` — one dead sensor feed degrades one machine, not the
+        fleet (the blast radius the reference got from one-pod-per-machine).
+        """
+        with _stage("fetch_stage", machines=len(plans)):
+            if not plans:
+                return
+            with ThreadPoolExecutor(max_workers=min(16, len(plans))) as pool:
+                records = list(pool.map(self._load_data_guarded, plans.values()))
+            for (i, plan), record in zip(list(plans.items()), records):
+                if record is not None:
+                    quarantine(plan.machine, record)
+                    del plans[i]
+
+    def _validate_stage(
+        self, plans: Dict[int, _Plan], quarantine
+    ) -> Dict[Tuple, List[int]]:
+        """Pre-flight validation, warm-start params, then the buckets by
+        (spec, shapes, config). A NaN column would train to NaN params and
+        poison nothing but its own vmap lane — but its thresholds/scores
+        would be garbage and, pre-bucketing, it is trivially isolable."""
+        with _stage("validate_stage", machines=len(plans)):
+            for i in list(plans):
+                plan = plans[i]
+                with _machine_trace(plan.machine.name), telemetry.span(
+                    "validate", _PHASE_VALIDATE, machine=plan.machine.name
+                ):
+                    bad = faults.non_finite_report(plan.X, plan.y)
+                if bad is not None:
+                    if self.fail_fast:
+                        raise faults.NonFiniteDataError(
+                            f"machine {plan.machine.name}: {bad}"
+                        )
+                    quarantine(
+                        plan.machine,
+                        QuarantineRecord(
+                            machine=plan.machine.name,
+                            stage=faults.STAGE_DATA_VALIDATION,
+                            reason="non_finite_data",
+                            error=bad,
+                        ),
+                    )
+                    del plans[i]
+
+            self._attach_warm_params(plans)
+
+            buckets: Dict[Tuple, List[int]] = {}
+            for i, plan in plans.items():
+                buckets.setdefault(plan.bucket_key(), []).append(i)
+            return buckets
 
     def _build_all_elastic(self, distributed) -> List[Tuple[Any, Machine]]:
         """The work-stealing fleet build (parallel/scheduler.py): every
@@ -1173,99 +1212,64 @@ class BatchedModelBuilder:
                 base_dir, n_done,
             )
         try:
-            # resume prefilter, elastic form: full-key registry hits are
-            # claimed exactly once fleet-wide by a done marker instead of
-            # the hash partition — whoever claims first loads and returns
-            # the machine; everyone else drops it entirely
-            cached_paths: Dict[int, str] = {}
-            if self.model_register_dir and self.machines:
-                idxs = list(range(len(self.machines)))
-                with ThreadPoolExecutor(max_workers=min(16, len(idxs))) as pool:
-                    paths = list(
-                        pool.map(
-                            lambda i: self._cached_path(self.machines[i]), idxs
+            with _stage("plan", machines=len(self.machines)):
+                # resume prefilter, elastic form: full-key registry hits are
+                # claimed exactly once fleet-wide by a done marker instead of
+                # the hash partition — whoever claims first loads and returns
+                # the machine; everyone else drops it entirely
+                cached_paths: Dict[int, str] = {}
+                if self.model_register_dir and self.machines:
+                    idxs = list(range(len(self.machines)))
+                    with ThreadPoolExecutor(max_workers=min(16, len(idxs))) as pool:
+                        paths = list(
+                            pool.map(
+                                lambda i: self._cached_path(self.machines[i]), idxs
+                            )
                         )
-                    )
-                cached_paths = {i: p for i, p in zip(idxs, paths) if p}
+                    cached_paths = {i: p for i, p in zip(idxs, paths) if p}
 
-            for i, machine in enumerate(self.machines):
-                if i in cached_paths:
-                    if not sched.try_claim(
-                        unit_id_for([machine.name], "cached"),
-                        {"machine": machine.name},
-                    ):
-                        continue  # a peer claimed and returns this hit
-                    cached = self._load_cached_guarded(i, cached_paths[i])
-                    if cached is not None:
-                        logger.info(
-                            "Machine %s: loaded from cache", machine.name
-                        )
-                        metric_catalog.BUILD_MACHINES.labels(
-                            outcome="cached"
-                        ).inc()
-                        results[i] = cached
-                        model_dir = self._machine_output_dir(machine.name)
-                        if model_dir and not os.path.exists(
-                            os.path.join(model_dir, "model.pkl")
+                for i, machine in enumerate(self.machines):
+                    if i in cached_paths:
+                        if not sched.try_claim(
+                            unit_id_for([machine.name], "cached"),
+                            {"machine": machine.name},
                         ):
-                            self._persist(machine, *cached)
-                        continue
-                    # corrupt artifact: we hold the claim; rebuild below
-                plan = _plan_machine(machine)
-                if plan is None:
-                    serial.append(i)
-                else:
-                    plans[i] = plan
+                            continue  # a peer claimed and returns this hit
+                        cached = self._load_cached_guarded(i, cached_paths[i])
+                        if cached is not None:
+                            logger.info(
+                                "Machine %s: loaded from cache", machine.name
+                            )
+                            metric_catalog.BUILD_MACHINES.labels(
+                                outcome="cached"
+                            ).inc()
+                            results[i] = cached
+                            model_dir = self._machine_output_dir(machine.name)
+                            if model_dir and not os.path.exists(
+                                os.path.join(model_dir, "model.pkl")
+                            ):
+                                self._persist(machine, *cached)
+                            continue
+                        # corrupt artifact: we hold the claim; rebuild below
+                    plan = _plan_machine(machine)
+                    if plan is None:
+                        serial.append(i)
+                    else:
+                        plans[i] = plan
 
-            for i in serial:
-                if not self.serial_fallback:
-                    raise ValueError(
-                        f"Machine {self.machines[i].name} is not batchable "
-                        f"and serial_fallback=False"
-                    )
-
-            # data fetch + validation: same guarded paths as the static
-            # build, except quarantines are claim-gated — every host
-            # observes the same bad feed, exactly one records it
-            if plans:
-                max_workers = min(16, len(plans))
-                with ThreadPoolExecutor(max_workers=max_workers) as pool:
-                    records = list(
-                        pool.map(self._load_data_guarded, plans.values())
-                    )
-                for (i, plan), record in zip(list(plans.items()), records):
-                    if record is not None:
-                        self._quarantine_claimed(sched, plan.machine, record)
-                        del plans[i]
-
-            for i in list(plans):
-                plan = plans[i]
-                with _machine_trace(plan.machine.name), telemetry.span(
-                    "validate", _PHASE_VALIDATE, machine=plan.machine.name
-                ):
-                    bad = faults.non_finite_report(plan.X, plan.y)
-                if bad is not None:
-                    if self.fail_fast:
-                        raise faults.NonFiniteDataError(
-                            f"machine {plan.machine.name}: {bad}"
+                for i in serial:
+                    if not self.serial_fallback:
+                        raise ValueError(
+                            f"Machine {self.machines[i].name} is not batchable "
+                            f"and serial_fallback=False"
                         )
-                    self._quarantine_claimed(
-                        sched,
-                        plan.machine,
-                        QuarantineRecord(
-                            machine=plan.machine.name,
-                            stage=faults.STAGE_DATA_VALIDATION,
-                            reason="non_finite_data",
-                            error=bad,
-                        ),
-                    )
-                    del plans[i]
 
-            self._attach_warm_params(plans)
-
-            buckets: Dict[Tuple, List[int]] = {}
-            for i, plan in plans.items():
-                buckets.setdefault(plan.bucket_key(), []).append(i)
+            # data fetch + validation: the static build's stages, except that
+            # quarantines are claim-gated — every host observes the same bad
+            # feed, exactly one records it
+            quarantine = functools.partial(self._quarantine_claimed, sched)
+            self._fetch_stage(plans, quarantine)
+            buckets = self._validate_stage(plans, quarantine)
 
             units: Dict[str, WorkUnit] = {}
             members: Dict[str, Tuple[str, List[int]]] = {}
@@ -1496,170 +1500,181 @@ class BatchedModelBuilder:
     def _build_bucket(
         self, bucket: List[_Plan], global_idxs: List[int]
     ) -> List[Tuple[int, Tuple[Any, Machine]]]:
-        faults.fault_point(
-            "bucket_compile", machines=[p.machine.name for p in bucket]
-        )
-        plan0 = bucket[0]
-        spec = plan0.spec
-        n_rows = len(plan0.X)
-        kfold_folds: Optional[List[Tuple[np.ndarray, np.ndarray]]] = None
-        perms: Optional[np.ndarray] = None
-        if plan0.cv[0] == "kfold":
-            # seeded shuffled-KFold geometry (KFCV plans): exact sklearn fold
-            # assignment computed on host — identical to the serial
-            # detector's — expressed as per-stage row permutations
-            # [train..., test...] so the program keeps static train-prefix /
-            # test-tail shapes. Bounds pad every fold's test slice to the
-            # largest fold; assembly discards the padded leading rows.
-            _, n_sp, shuffle_cv, seed_cv = plan0.cv
-            splitter = KFold(
-                n_splits=n_sp, shuffle=shuffle_cv,
-                random_state=seed_cv if shuffle_cv else None,
+        with _stage("bucket_prep", machines=len(bucket)):
+            faults.fault_point(
+                "bucket_compile", machines=[p.machine.name for p in bucket]
             )
-            kfold_folds = [
-                (tr, te) for tr, te in splitter.split(np.zeros((n_rows, 1)))
-            ]
-            te_max = max(len(te) for _, te in kfold_folds)
-            fold_bounds = tuple(
-                (len(tr), n_rows - te_max, n_rows) for tr, _ in kfold_folds
-            )
-            perms = np.stack(
-                [np.concatenate([tr, te]) for tr, te in kfold_folds]
-                + [np.arange(n_rows)]
-            ).astype(np.int32)
-        else:
-            fold_bounds = self._fold_bounds(n_rows, plan0.n_splits)
-        n_dev = int(np.prod(list(self.mesh.shape.values())))
-
-        # every CV fold must yield at least one training sample, mirroring the
-        # serial path's explicit error (ops/train.py fit_arrays)
-        for tr_end, _, _ in fold_bounds:
-            if n_train_samples(spec, tr_end) <= 0:
-                raise ValueError(
-                    f"CV fold with {tr_end} rows yields no training samples for "
-                    f"lookback_window={spec.lookback_window} "
-                    f"lookahead={spec.lookahead} "
-                    f"(machines: {[p.machine.name for p in bucket]})"
+            plan0 = bucket[0]
+            spec = plan0.spec
+            n_rows = len(plan0.X)
+            kfold_folds: Optional[List[Tuple[np.ndarray, np.ndarray]]] = None
+            perms: Optional[np.ndarray] = None
+            if plan0.cv[0] == "kfold":
+                # seeded shuffled-KFold geometry (KFCV plans): exact sklearn fold
+                # assignment computed on host — identical to the serial
+                # detector's — expressed as per-stage row permutations
+                # [train..., test...] so the program keeps static train-prefix /
+                # test-tail shapes. Bounds pad every fold's test slice to the
+                # largest fold; assembly discards the padded leading rows.
+                _, n_sp, shuffle_cv, seed_cv = plan0.cv
+                splitter = KFold(
+                    n_splits=n_sp, shuffle=shuffle_cv,
+                    random_state=seed_cv if shuffle_cv else None,
                 )
+                kfold_folds = [
+                    (tr, te) for tr, te in splitter.split(np.zeros((n_rows, 1)))
+                ]
+                te_max = max(len(te) for _, te in kfold_folds)
+                fold_bounds = tuple(
+                    (len(tr), n_rows - te_max, n_rows) for tr, _ in kfold_folds
+                )
+                perms = np.stack(
+                    [np.concatenate([tr, te]) for tr, te in kfold_folds]
+                    + [np.arange(n_rows)]
+                ).astype(np.int32)
+            else:
+                fold_bounds = self._fold_bounds(n_rows, plan0.n_splits)
+            n_dev = int(np.prod(list(self.mesh.shape.values())))
 
-        M = len(bucket)
-        # fixed chunk size (multiple of mesh size): one compiled program is
-        # reused for every chunk, so compile cost doesn't scale with M
-        chunk = ((min(self.chunk_size, M) + n_dev - 1) // n_dev) * n_dev
+            # every CV fold must yield at least one training sample, mirroring the
+            # serial path's explicit error (ops/train.py fit_arrays)
+            for tr_end, _, _ in fold_bounds:
+                if n_train_samples(spec, tr_end) <= 0:
+                    raise ValueError(
+                        f"CV fold with {tr_end} rows yields no training samples for "
+                        f"lookback_window={spec.lookback_window} "
+                        f"lookahead={spec.lookahead} "
+                        f"(machines: {[p.machine.name for p in bucket]})"
+                    )
 
-        from gordo_tpu.parallel import distributed
+            M = len(bucket)
+            # fixed chunk size (multiple of mesh size): one compiled program is
+            # reused for every chunk, so compile cost doesn't scale with M
+            chunk = ((min(self.chunk_size, M) + n_dev - 1) // n_dev) * n_dev
 
-        multiprocess = distributed.is_multiprocess()
-        warm = plan0.warm_params is not None
-        sharding = machines_sharding(self.mesh)
-        program_key = (
-            spec,
-            n_rows,
-            fold_bounds,
-            plan0.epochs,
-            plan0.batch_size,
-            plan0.shuffle,
-            plan0.scale_x,
-            sharding if multiprocess else None,
-            perms is not None,
-            warm,
-        )
-        cache_before = _bucket_program.cache_info()
-        program = _bucket_program(
-            spec,
-            n_rows,
-            fold_bounds,
-            plan0.epochs,
-            plan0.batch_size,
-            plan0.shuffle,
-            plan0.scale_x,
-            out_sharding=sharding if multiprocess else None,
-            use_perms=perms is not None,
-            warm_start=warm,
-        )
-        # program-cache effectiveness: a hit reuses an already-compiled
-        # program; credit its remembered first-compile wall as time saved
-        program_cached = _bucket_program.cache_info().hits > cache_before.hits
-        metric_catalog.PROGRAM_CACHE.labels(
-            result="hit" if program_cached else "miss"
-        ).inc()
-        if program_cached:
-            saved = _first_compile_walls.get(program_key)
-            if saved:
-                metric_catalog.COMPILE_SECONDS_SAVED.inc(saved)
-        perms_d = None
-        if perms is not None:
-            from jax.sharding import NamedSharding, PartitionSpec
+            from gordo_tpu.parallel import distributed
 
-            # fold permutations are identical for every machine (same seed,
-            # same row count): one replicated array, not a vmapped axis.
-            # make_global_stacked handles the multi-process world, where a
-            # plain device_put cannot address other hosts' devices
-            perms_d = distributed.make_global_stacked(
-                NamedSharding(self.mesh, PartitionSpec()), perms
+            multiprocess = distributed.is_multiprocess()
+            warm = plan0.warm_params is not None
+            sharding = machines_sharding(self.mesh)
+            program_key = (
+                spec,
+                n_rows,
+                fold_bounds,
+                plan0.epochs,
+                plan0.batch_size,
+                plan0.shuffle,
+                plan0.scale_x,
+                sharding if multiprocess else None,
+                perms is not None,
+                warm,
             )
+            cache_before = _bucket_program.cache_info()
+            program = _bucket_program(
+                spec,
+                n_rows,
+                fold_bounds,
+                plan0.epochs,
+                plan0.batch_size,
+                plan0.shuffle,
+                plan0.scale_x,
+                out_sharding=sharding if multiprocess else None,
+                use_perms=perms is not None,
+                warm_start=warm,
+            )
+            # program-cache effectiveness: a hit reuses an already-compiled
+            # program; credit its remembered first-compile wall as time saved
+            program_cached = _bucket_program.cache_info().hits > cache_before.hits
+            metric_catalog.PROGRAM_CACHE.labels(
+                result="hit" if program_cached else "miss"
+            ).inc()
+            if program_cached:
+                saved = _first_compile_walls.get(program_key)
+                if saved:
+                    metric_catalog.COMPILE_SECONDS_SAVED.inc(saved)
+            perms_d = None
+            if perms is not None:
+                from jax.sharding import NamedSharding, PartitionSpec
+
+                # fold permutations are identical for every machine (same seed,
+                # same row count): one replicated array, not a vmapped axis.
+                # make_global_stacked handles the multi-process world, where a
+                # plain device_put cannot address other hosts' devices
+                perms_d = distributed.make_global_stacked(
+                    NamedSharding(self.mesh, PartitionSpec()), perms
+                )
 
         t0 = time.time()
 
         def dispatch(start: int):
-            group = bucket[start : start + chunk]
-            pad = chunk - len(group)
-            X = np.stack([p.X for p in group] + [group[0].X] * pad)
-            y = np.stack([p.y for p in group] + [group[0].y] * pad)
-            # per-machine RNG stream derived from (evaluation.seed, machine
-            # name): independent of bucket composition/ordering, so a
-            # machine's weights are reproducible no matter which other
-            # machines train alongside it
-            seeds = np.array(
-                [_machine_seed(p.machine) for p in group] + [0] * pad,
-                dtype=np.uint32,
-            )
-            X_d = distributed.make_global_stacked(sharding, X)
-            y_d = distributed.make_global_stacked(sharding, y)
-            seeds_d = distributed.make_global_stacked(sharding, seeds)
-            args = (X_d, y_d, seeds_d)
-            if perms_d is not None:
-                args = args + (perms_d,)
-            if warm:
-                # stack each machine's prior params on the machine axis
-                # (padding lanes replicate group[0], like X/y above) and
-                # shard the stacked tree exactly like the other inputs
-                trees = [p.warm_params for p in group] + [
-                    group[0].warm_params
-                ] * pad
-                stacked = jax.tree_util.tree_map(
-                    lambda *leaves: np.stack(leaves), *trees
+            with _stage("stack_h2d", chunk_start=start):
+                group = bucket[start : start + chunk]
+                pad = chunk - len(group)
+                X = np.stack([p.X for p in group] + [group[0].X] * pad)
+                y = np.stack([p.y for p in group] + [group[0].y] * pad)
+                # per-machine RNG stream derived from (evaluation.seed, machine
+                # name): independent of bucket composition/ordering, so a
+                # machine's weights are reproducible no matter which other
+                # machines train alongside it
+                seeds = np.array(
+                    [_machine_seed(p.machine) for p in group] + [0] * pad,
+                    dtype=np.uint32,
                 )
-                warm_d = jax.tree_util.tree_map(
-                    lambda a: distributed.make_global_stacked(sharding, a),
-                    stacked,
-                )
-                args = args + (warm_d,)
-            outputs = program(*args)
-            _note_shard_devices(X_d, outputs[0])
+                X_d = distributed.make_global_stacked(sharding, X)
+                y_d = distributed.make_global_stacked(sharding, y)
+                seeds_d = distributed.make_global_stacked(sharding, seeds)
+                args = (X_d, y_d, seeds_d)
+                if perms_d is not None:
+                    args = args + (perms_d,)
+                if warm:
+                    # stack each machine's prior params on the machine axis
+                    # (padding lanes replicate group[0], like X/y above) and
+                    # shard the stacked tree exactly like the other inputs
+                    trees = [p.warm_params for p in group] + [
+                        group[0].warm_params
+                    ] * pad
+                    stacked = jax.tree_util.tree_map(
+                        lambda *leaves: np.stack(leaves), *trees
+                    )
+                    warm_d = jax.tree_util.tree_map(
+                        lambda a: distributed.make_global_stacked(sharding, a),
+                        stacked,
+                    )
+                    args = args + (warm_d,)
+            with _stage("launch", chunk_start=start):
+                # returns once the execution is queued (the first call
+                # compiles or loads the program before that)
+                outputs = program(*args)
+                _note_shard_devices(X_d, outputs[0])
+            return group, outputs
+
+        def wait(group, outputs):
+            with _stage("wait"):
+                jax.block_until_ready(outputs)
             return group, outputs
 
         def fetch(group, outputs):
-            params_stack, losses, fold_preds = outputs
-            if not multiprocess:
-                # one batched host transfer for the whole tree
-                losses_np = np.asarray(jax.device_get(losses))
-                return (
-                    group,
-                    np.arange(losses_np.shape[0]),
-                    jax.device_get(params_stack),
-                    losses_np,
-                    [np.asarray(jax.device_get(fp)) for fp in fold_preds],
+            with _stage("d2h"):
+                params_stack, losses, fold_preds = outputs
+                if not multiprocess:
+                    # one batched host transfer for the whole tree
+                    losses_np = np.asarray(jax.device_get(losses))
+                    return (
+                        group,
+                        np.arange(losses_np.shape[0]),
+                        jax.device_get(params_stack),
+                        losses_np,
+                        [np.asarray(jax.device_get(fp)) for fp in fold_preds],
+                    )
+                # multi-process: only this host's rows are addressable; every
+                # output shares the machines sharding, so the rows from `losses`
+                # apply to all leaves
+                rows, losses_np = distributed.local_rows(losses)
+                params_np = jax.tree_util.tree_map(
+                    lambda a: distributed.local_rows(a)[1], params_stack
                 )
-            # multi-process: only this host's rows are addressable; every
-            # output shares the machines sharding, so the rows from `losses`
-            # apply to all leaves
-            rows, losses_np = distributed.local_rows(losses)
-            params_np = jax.tree_util.tree_map(
-                lambda a: distributed.local_rows(a)[1], params_stack
-            )
-            fold_preds_np = [distributed.local_rows(fp)[1] for fp in fold_preds]
-            return group, rows, params_np, losses_np, fold_preds_np
+                fold_preds_np = [distributed.local_rows(fp)[1] for fp in fold_preds]
+                return group, rows, params_np, losses_np, fold_preds_np
 
         # host-side assembly per machine (~10ms each: threshold stats,
         # scores, metadata) runs on a thread pool, enqueued per chunk AS SOON
@@ -1668,55 +1683,56 @@ class BatchedModelBuilder:
         futures = []
 
         def enqueue_assembly(pool, fetched, chunk_start):
-            group, rows, params_stack, losses, fold_preds = fetched
-            # provisional per-machine duration for checkpointed metadata: the
-            # wall so far over the machines so far (the bucket-level
-            # apportionment below refreshes it once the bucket completes,
-            # but a mid-bucket kill must not leave zeros behind)
-            n_done = chunk_start + len(group)
-            per_machine_est = (time.time() - t0) / max(n_done, 1)
-            for j, row in enumerate(int(r) for r in rows):
-                if row >= len(group):
-                    continue  # padding rows replicate group[0]; skip
-                params_i = jax.tree_util.tree_map(lambda a: a[j], params_stack)
-                fold_preds_i = [fp[j] for fp in fold_preds]
-                # post-build divergence detection: a lane that trained to
-                # NaN/Inf params (bad lr, degenerate data) is quarantined —
-                # its garbage must not be persisted as a servable artifact
-                bad = faults.params_non_finite(params_i, losses[j])
-                if bad is None and faults.should_fire(
-                    "diverge", group[row].machine.name
-                ):
-                    bad = "injected divergence"
-                if bad is not None:
-                    plan = group[row]
-                    if self.fail_fast:
-                        raise faults.DivergedModelError(
-                            f"machine {plan.machine.name}: {bad}"
+            with _stage("slice", chunk_start=chunk_start):
+                group, rows, params_stack, losses, fold_preds = fetched
+                # provisional per-machine duration for checkpointed metadata: the
+                # wall so far over the machines so far (the bucket-level
+                # apportionment below refreshes it once the bucket completes,
+                # but a mid-bucket kill must not leave zeros behind)
+                n_done = chunk_start + len(group)
+                per_machine_est = (time.time() - t0) / max(n_done, 1)
+                for j, row in enumerate(int(r) for r in rows):
+                    if row >= len(group):
+                        continue  # padding rows replicate group[0]; skip
+                    params_i = jax.tree_util.tree_map(lambda a: a[j], params_stack)
+                    fold_preds_i = [fp[j] for fp in fold_preds]
+                    # post-build divergence detection: a lane that trained to
+                    # NaN/Inf params (bad lr, degenerate data) is quarantined —
+                    # its garbage must not be persisted as a servable artifact
+                    bad = faults.params_non_finite(params_i, losses[j])
+                    if bad is None and faults.should_fire(
+                        "diverge", group[row].machine.name
+                    ):
+                        bad = "injected divergence"
+                    if bad is not None:
+                        plan = group[row]
+                        if self.fail_fast:
+                            raise faults.DivergedModelError(
+                                f"machine {plan.machine.name}: {bad}"
+                            )
+                        self._quarantine(
+                            plan.machine,
+                            stage=faults.STAGE_TRAINING,
+                            reason="diverged",
+                            error=bad,
                         )
-                    self._quarantine(
-                        plan.machine,
-                        stage=faults.STAGE_TRAINING,
-                        reason="diverged",
-                        error=bad,
-                    )
-                    continue
-                futures.append(
-                    pool.submit(
-                        lambda idx, plan, p, l, fp: (
-                            idx,
-                            self._assemble_and_persist(
-                                plan, p, l, fp, fold_bounds, per_machine_est,
-                                kfold_folds,
+                        continue
+                    futures.append(
+                        pool.submit(
+                            lambda idx, plan, p, l, fp: (
+                                idx,
+                                self._assemble_and_persist(
+                                    plan, p, l, fp, fold_bounds, per_machine_est,
+                                    kfold_folds,
+                                ),
                             ),
-                        ),
-                        global_idxs[chunk_start + row],
-                        group[row],
-                        params_i,
-                        losses[j],
-                        fold_preds_i,
+                            global_idxs[chunk_start + row],
+                            group[row],
+                            params_i,
+                            losses[j],
+                            fold_preds_i,
+                        )
                     )
-                )
 
         # keep at most 2 chunks in flight: dispatch chunk k+1 (async) before
         # fetching chunk k, so transfers overlap compute while peak HBM stays
@@ -1726,7 +1742,9 @@ class BatchedModelBuilder:
             starts = list(range(0, M, chunk))
             # jit compiles synchronously during the first call (execution is
             # dispatched async), so the first-dispatch span is the compile
-            # span — on a warm program cache it collapses to device_put time
+            # span — on a warm program cache it collapses to device_put time.
+            # compile and train are parents: the stages inside dispatch(),
+            # wait(), fetch() and enqueue_assembly() tile them
             with telemetry.span(
                 "compile", _PHASE_COMPILE, bucket=bucket_name,
                 machines=M, cached=program_cached,
@@ -1746,24 +1764,36 @@ class BatchedModelBuilder:
             ):
                 for start in starts[1:]:
                     next_in_flight = dispatch(start)
-                    enqueue_assembly(pool, fetch(*in_flight), in_flight_start)
+                    enqueue_assembly(
+                        pool, fetch(*wait(*in_flight)), in_flight_start
+                    )
                     in_flight, in_flight_start = next_in_flight, start
-                enqueue_assembly(pool, fetch(*in_flight), in_flight_start)
+                # the device's work on this bucket ends with this wait
+                ready = wait(*in_flight)
+            # ... and everything after it is the tail: the last chunk's pull
+            # and slicing, the assembly pool's drain, the metadata rewrite
+            with _stage("tail", bucket=bucket_name, machines=M):
+                enqueue_assembly(pool, fetch(*ready), in_flight_start)
                 train_duration = time.time() - t0
-            out = [f.result() for f in futures]
-        logger.info(
-            "Batched bucket: %d machines (chunk %d) trained in %.2fs",
-            M, chunk, train_duration,
-        )
+                with _stage("drain"):
+                    out = [f.result() for f in futures]
+                logger.info(
+                    "Batched bucket: %d machines (chunk %d) trained in %.2fs",
+                    M, chunk, train_duration,
+                )
+                with _stage("finalize"):
+                    self._finalize_durations(
+                        out, train_duration / M, len(fold_bounds)
+                    )
+                return out
 
-        # duration metadata: the fused program interleaves CV-fold training
-        # with the final fit, and compile time belongs to no one machine —
-        # apportion the bucket wall uniformly (by fold count for the
-        # cv-vs-fit split), exactly as a whole-fleet observer would
-        n_stages = len(fold_bounds) + 1
-        per_machine = train_duration / M
-        cv_share = per_machine * len(fold_bounds) / n_stages
-        fit_share = per_machine / n_stages
+    def _finalize_durations(self, out, per_machine: float, n_folds: int) -> None:
+        """Duration metadata: the fused program interleaves CV-fold training
+        with the final fit, and compile time belongs to no one machine —
+        apportion the bucket wall uniformly (by fold count for the
+        cv-vs-fit split), exactly as a whole-fleet observer would."""
+        cv_share = per_machine * n_folds / (n_folds + 1)
+        fit_share = per_machine / (n_folds + 1)
         for _, (model, machine_out) in out:
             build_meta = machine_out.metadata.build_metadata.model
             build_meta.model_training_duration_sec = fit_share
@@ -1782,7 +1812,6 @@ class BatchedModelBuilder:
                     self._machine_output_dir(machine_out.name),
                     machine_out.to_dict(),
                 )
-        return out
 
     # --------------------------------------------------------- assembly
     def _assemble_and_persist(

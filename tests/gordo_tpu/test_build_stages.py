@@ -1,0 +1,211 @@
+"""The fleet build read from inside: the build thread's stages as spans
+that tile ``build()`` (and as ``gordo.`` marks in a profiler session), and
+the named scopes in the chunk program."""
+
+import glob
+import os
+import re
+import threading
+
+import jax
+import pytest
+import yaml
+
+from gordo_tpu.observability import metrics as metric_catalog
+from gordo_tpu.observability import telemetry
+from gordo_tpu.parallel import BatchedModelBuilder, batch_trainer, default_mesh
+from gordo_tpu.workflow.normalized_config import NormalizedConfig
+
+ONCE_A_BUILD = (
+    "plan", "fetch_stage", "validate_stage", "bucket_prep", "compile",
+    "train", "tail", "drain", "finalize",
+)
+ONCE_A_CHUNK = ("stack_h2d", "launch", "wait", "d2h", "slice")
+LEAVES = (
+    "plan", "fetch_stage", "validate_stage", "bucket_prep", "drain",
+    "finalize",
+) + ONCE_A_CHUNK
+CHUNKS = 2
+
+
+HOURGLASS = """gordo_tpu.models.models.AutoEncoder:
+                kind: feedforward_hourglass"""
+
+
+def _machines(prefix, estimator=HOURGLASS, n=2 * CHUNKS):
+    blocks = "".join(
+        f"""
+  - name: {prefix}-{i}
+    dataset:
+      tags: [{prefix}-{i}-a, {prefix}-{i}-b, {prefix}-{i}-c]
+      train_start_date: '2019-01-01T00:00:00+00:00'
+      train_end_date: '2019-01-02T00:00:00+00:00'
+      data_provider: {{type: RandomDataProvider}}
+    model:
+      gordo_tpu.models.anomaly.diff.DiffBasedAnomalyDetector:
+        require_thresholds: true
+        base_estimator:
+          sklearn.pipeline.Pipeline:
+            steps:
+            - sklearn.preprocessing.MinMaxScaler
+            - {estimator}
+                epochs: 1
+"""
+        for i in range(n)
+    )
+    config = yaml.safe_load("machines:" + blocks)
+    return NormalizedConfig(config, project_name="stages").machines
+
+
+def _build(prefix, out_dir):
+    """Four machines in two chunks of two on one device, on this thread."""
+    return BatchedModelBuilder(
+        _machines(prefix),
+        mesh=default_mesh(devices=jax.devices()[:1]),
+        chunk_size=2,
+        output_dir=str(out_dir),
+    ).build()
+
+
+@pytest.fixture(autouse=True)
+def _clean_telemetry():
+    telemetry.reset()
+    yield
+    telemetry.reset()
+
+
+def _phase_counts():
+    return {
+        phase: metric_catalog.BUILD_PHASE_SECONDS.count(phase=phase)
+        for phase in ONCE_A_BUILD + ONCE_A_CHUNK
+    }
+
+
+def test_build_thread_stages_tile_the_build(tmp_path):
+    telemetry.start_trace()
+    assert len(_build("tile", tmp_path)) == 2 * CHUNKS
+    events = [
+        e for e in telemetry.stop_trace()["traceEvents"]
+        if e["tid"] == threading.get_ident()
+    ]
+    by_name = {}
+    for event in events:
+        by_name.setdefault(event["name"], []).append(
+            (event["ts"], event["ts"] + event["dur"])
+        )
+    for name in ONCE_A_BUILD:
+        assert len(by_name[name]) == 1, name
+    for name in ONCE_A_CHUNK:
+        assert len(by_name[name]) == CHUNKS, name
+    # each observed its wall under its own phase label
+    assert _phase_counts() == {
+        **{name: 1 for name in ONCE_A_BUILD},
+        **{name: CHUNKS for name in ONCE_A_CHUNK},
+    }
+
+    # the leaves are contiguous and non-overlapping, and cover the build
+    (build_start, build_end), = by_name["batched_build"]
+    leaves = sorted(iv for name in LEAVES for iv in by_name[name])
+    for (_, end), (start, _) in zip(leaves, leaves[1:]):
+        assert start >= end - 1.0  # microseconds; one clock read apart
+    covered = sum(end - start for start, end in leaves)
+    assert covered >= 0.95 * (build_end - build_start)
+    assert leaves[0][0] >= build_start and leaves[-1][1] <= build_end
+
+    # the parents: compile holds the first chunk's stack_h2d + launch, train
+    # ends with the last wait, and tail starts there and holds what follows
+    (compile_start, compile_end), = by_name["compile"]
+    assert compile_start <= by_name["stack_h2d"][0][0]
+    assert by_name["launch"][0][1] <= compile_end
+    (_, train_end), = by_name["train"]
+    last_wait_end = max(end for _, end in by_name["wait"])
+    assert last_wait_end <= train_end
+    (tail_start, tail_end), = by_name["tail"]
+    assert tail_start >= last_wait_end
+    for name in ("drain", "finalize"):
+        (start, end), = by_name[name]
+        assert tail_start <= start and end <= tail_end
+    last = lambda name: max(by_name[name])  # noqa: E731
+    assert tail_start <= last("d2h")[0] and last("slice")[1] <= tail_end
+    # what _build_all does after its last bucket is under batched_build alone
+    assert build_end - tail_end <= 0.01 * (build_end - build_start)
+
+
+def test_spans_off_observes_no_stage_and_allocates_no_span(tmp_path):
+    assert not telemetry.spans_enabled()
+    for name in batch_trainer._STAGE_SECONDS:
+        assert batch_trainer._stage(name, machines=1) is telemetry._NULL_SPAN
+    assert len(_build("off", tmp_path)) == 2 * CHUNKS
+    assert set(_phase_counts().values()) == {0}
+    phases = {p for (p,), _ in metric_catalog.BUILD_PHASE_SECONDS.snapshot()}
+    assert not phases & set(batch_trainer._STAGE_SECONDS)
+
+
+def test_profiler_session_holds_the_stages_as_gordo_marks(tmp_path):
+    """Whoever opens a jax.profiler session turns spans on: the session then
+    holds the program's stages on its own clock, prefix ``gordo.``."""
+    from jax.profiler import ProfileData
+
+    telemetry.enable_spans()
+    jax.profiler.start_trace(str(tmp_path / "trace"))
+    try:
+        _build("marks", tmp_path / "out")
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(
+        os.path.join(tmp_path, "trace", "plugins", "profile", "*", "*.xplane.pb")
+    )
+    names = {
+        event.name
+        for plane in ProfileData.from_file(path).planes
+        for line in plane.lines
+        for event in line.events
+        if event.name.startswith(telemetry.PROFILER_MARK)
+    }
+    assert {"gordo.fetch_stage", "gordo.wait", "gordo.tail"} <= names
+
+
+# estimator block -> the scopes of docs/observability.md its spec reaches
+SCOPES = {
+    "hourglass": (HOURGLASS, ("dense", "optimizer_update", "fold_predict")),
+    "lstm": (
+        """gordo_tpu.models.models.LSTMAutoEncoder:
+                kind: lstm_symmetric
+                dims: [4]
+                funcs: [tanh]
+                lookback_window: 4""",
+        ("lstm_cell", "dense", "window_gather", "optimizer_update", "fold_predict"),
+    ),
+    "transformer": (
+        """gordo_tpu.models.models.TransformerAutoEncoder:
+                kind: transformer_model
+                d_model: 8
+                num_heads: 2
+                ff_dim: 8
+                num_blocks: 1
+                lookback_window: 4""",
+        ("attention", "dense", "window_gather", "optimizer_update", "fold_predict"),
+    ),
+}
+
+
+@pytest.mark.parametrize("kind", sorted(SCOPES))
+def test_bucket_program_carries_the_named_scopes(kind):
+    """The lowered chunk program names, in its debug info, every scope of
+    the table in docs/observability.md that the spec reaches."""
+    import numpy as np
+
+    estimator, scopes = SCOPES[kind]
+    (machine,) = _machines(f"scope-{kind}", estimator, n=1)
+    plan = batch_trainer._plan_machine(machine)
+    n_rows, n_tags = 64, 3
+    program = batch_trainer._bucket_program(
+        plan.spec, n_rows, ((16, 16, 32), (32, 32, 48), (48, 48, 64)),
+        1, 8, True, True,
+    )
+    X = np.zeros((2, n_rows, n_tags), np.float32)
+    text = program.lower(X, X, np.zeros((2,), np.uint32)).as_text(debug_info=True)
+    for scope in scopes:
+        assert re.search(rf'[("/]{scope}[)/]', text), scope
+    # forward and backward need no scope of their own: JAX writes them
+    assert "jvp(dense)" in text and "transpose(jvp(dense))" in text

@@ -400,22 +400,34 @@ def test_fleet_replace_cache_retrains(tmp_path):
     assert not _cache_marker(again[0][1])
 
 
-def test_profile_dir_captures_device_trace(tmp_path, monkeypatch):
-    """GORDO_TPU_PROFILE_DIR wraps the fleet build in jax.profiler.trace
-    and leaves an openable trace on disk (SURVEY §5 tracing hookup)."""
+@pytest.mark.parametrize("nested", [False, True])
+def test_profile_dir_captures_device_trace(tmp_path, monkeypatch, nested):
+    """GORDO_TPU_PROFILE_DIR wraps the fleet build in a jax.profiler session
+    and leaves an openable trace on disk (SURVEY §5 tracing hookup). Inside
+    a session that is open already (a harness's, /debug/profile?device=1:
+    jax allows one) it opens none and the build proceeds inside that one."""
     import os
 
-    monkeypatch.setenv("GORDO_TPU_PROFILE_DIR", str(tmp_path))
-    config = "machines:" + _machine_block("prof-0")
-    BatchedModelBuilder(_machines(config)).build()
-    trace_root = tmp_path / "batched-build"
-    assert trace_root.exists()
+    import jax
+
+    monkeypatch.setenv("GORDO_TPU_PROFILE_DIR", str(tmp_path / "own"))
+    config = "machines:" + _machine_block(f"prof-{int(nested)}")
+    if nested:
+        jax.profiler.start_trace(str(tmp_path / "outer"))
+    try:
+        assert len(BatchedModelBuilder(_machines(config)).build()) == 1
+    finally:
+        if nested:
+            jax.profiler.stop_trace()  # still the outer session's to close
+    trace_root = tmp_path / ("outer" if nested else "own/batched-build")
     files = [
         os.path.join(r, f)
         for r, _, fs in os.walk(trace_root)
         for f in fs
     ]
     assert files, "profiler produced no trace files"
+    if nested:
+        assert not any(fs for _, _, fs in os.walk(tmp_path / "own"))
 
 
 # --------------------------------------------------- seeded-KFold KFCV plans

@@ -134,9 +134,9 @@ def test_prometheus_bridge_without_prometheus_client(monkeypatch):
 
 # ------------------------------------------------------------------- spans
 def test_disabled_span_is_shared_noop_singleton():
-    """The acceptance guard: with no trace, no span timing, and no profile
-    dir, span() allocates nothing — every call returns the same no-op
-    object and records no events or metrics."""
+    """The acceptance guard: with no trace and no span timing, span()
+    allocates nothing — every call returns the same no-op object and
+    records no events or metrics."""
     s1 = telemetry.span("compile", machine="m-1")
     s2 = telemetry.span("train")
     assert s1 is s2
@@ -146,6 +146,13 @@ def test_disabled_span_is_shared_noop_singleton():
     assert not telemetry.spans_enabled()
     # and nothing observed into the phase histogram
     assert metric_catalog.BUILD_PHASE_SECONDS.count(phase="compile") == 0
+    # the fleet build's stage spans are the same object, and so are their
+    # phase labels' call sites
+    from gordo_tpu.parallel import batch_trainer
+
+    for name in batch_trainer._STAGE_SECONDS:
+        assert batch_trainer._stage(name, machines=3) is s1
+        assert metric_catalog.BUILD_PHASE_SECONDS.count(phase=name) == 0
 
 
 def test_span_records_chrome_trace_event_and_histogram():
@@ -233,22 +240,59 @@ def test_write_trace_without_trace_raises(tmp_path):
 
 
 # ------------------------------------------------- profiling integration
-def test_annotate_is_nullcontext_unless_profiling(monkeypatch):
-    import contextlib
-
-    monkeypatch.delenv(profiling.PROFILE_DIR_ENV, raising=False)
-    assert isinstance(profiling.annotate("x"), contextlib.nullcontext)
-    assert not profiling.profiling_enabled()
-
-
-def test_profile_dir_activates_spans(monkeypatch, tmp_path):
-    """With GORDO_TPU_PROFILE_DIR set, spans leave the no-op path so their
-    names reach the JAX device trace via annotate()."""
+def test_profile_dir_alone_leaves_spans_off(monkeypatch, tmp_path):
+    """The variable names where a session's trace goes; it is whoever opens
+    the session that turns spans on, for as long as it is open."""
     monkeypatch.setenv(profiling.PROFILE_DIR_ENV, str(tmp_path))
-    s = telemetry.span("compile")
-    assert s is not telemetry._NULL_SPAN
-    with s:  # enters a real jax TraceAnnotation without an active trace
+    assert telemetry.span("compile") is telemetry._NULL_SPAN
+    with telemetry.spans_on():
+        assert telemetry.span("compile") is not telemetry._NULL_SPAN
+    assert telemetry.span("compile") is telemetry._NULL_SPAN
+    # an enclosing trace or enable_spans() outlives the session
+    telemetry.enable_spans()
+    with telemetry.spans_on():
         pass
+    assert telemetry.spans_enabled()
+
+
+def test_live_span_is_a_gordo_mark_on_the_profilers_clock(tmp_path):
+    """A live span opens a TraceAnnotation ``gordo.<name>`` itself: inside a
+    jax.profiler session it is an event of the session, outside one a
+    branch."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    telemetry.enable_spans()
+    with telemetry.span("outside_any_session"):
+        pass
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with telemetry.span("fetch", machine="m-0"):
+            pass
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "plugins" / "profile" / "*" / "*.xplane.pb"))
+    names = {
+        event.name
+        for plane in ProfileData.from_file(path).planes
+        for line in plane.lines
+        for event in line.events
+    }
+    assert "gordo.fetch" in names
+    assert "gordo.outside_any_session" not in names
+
+
+def test_span_disabled_path_reads_no_environment(monkeypatch):
+    class _NoEnviron(dict):
+        def get(self, *args):  # pragma: no cover - must not run
+            raise AssertionError(f"span() read os.environ: {args}")
+
+        __getitem__ = get
+
+    monkeypatch.setattr(telemetry.os, "environ", _NoEnviron())
+    assert telemetry.span("fetch") is telemetry._NULL_SPAN
 
 
 # --------------------------------------------------------- end-to-end CLI
