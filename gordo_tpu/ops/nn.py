@@ -198,7 +198,8 @@ def init_model_params(rng: jax.Array, spec: ModelSpec) -> Params:
 
 
 # The named scopes here, in ops/train.py and in parallel/batch_trainer.py
-# (dense, lstm_cell, attention, window_gather, optimizer_update, fold_predict)
+# (dense, lstm_input_proj, lstm_cell, lstm_weight_grad, attention,
+# window_gather, optimizer_update, fold_predict)
 # are what a device trace is reduced by (scripts/trace_by_scope.py; the table
 # is in docs/observability.md): metadata only, and stable names, so rename
 # none. JAX writes jvp(...) / transpose(jvp(...)) into the op_name for the
@@ -209,47 +210,141 @@ def _apply_dense(layer: DenseLayer, p, x):
     return _activation(layer.activation)(out)
 
 
-def _apply_lstm(layer: LSTMLayer, p, x):
-    """
-    x: (batch, time, in_dim) → (batch, time, units) or (batch, units).
-
-    scan over time with a fused gate matmul — XLA maps the (batch, in+units) @
-    (in+units, 4*units) product onto the MXU per step.
-    """
-    units = layer.units
+def _lstm_gates(layer: LSTMLayer, kernel, recurrent_kernel, bias, x_t, h_prev):
+    """One step's gates (i, f, g, o; Keras' order) as (activation,
+    pre-activation) pairs. The products' operands are at the compute dtype;
+    both are accumulated, and summed with the bias, in float32."""
+    with jax.named_scope("lstm_input_proj"):
+        z = jnp.dot(x_t, kernel, preferred_element_type=jnp.float32) + bias.astype(
+            jnp.float32
+        )
+    z = z + jnp.dot(h_prev, recurrent_kernel, preferred_element_type=jnp.float32)
     act = _activation(layer.activation)
     rec_act = _activation(layer.recurrent_activation)
-    batch = x.shape[0]
+    units = layer.units
+    return [
+        (fn, z[..., k * units : (k + 1) * units])
+        for k, fn in enumerate((rec_act, rec_act, act, rec_act))
+    ]
 
-    W = jnp.concatenate([p["kernel"], p["recurrent_kernel"]], axis=0)
+
+def _lstm_scan(layer: LSTMLayer, kernel, recurrent_kernel, bias, x, save: bool):
+    """The layer's forward pass: a scan over time whose state (h, c) is
+    carried in float32 — bf16's 8-bit mantissa drifts badly over long scans
+    in ``c = f*c + i*g``.
+
+    ``save`` (the differentiated call) stacks, time-major, the little the
+    hand-written backward cannot recompute: the cell state each step started
+    from, and the outputs. The plain call stacks the outputs only, and
+    nothing at all for a many-to-one tail layer.
+    """
+    act = _activation(layer.activation)
+    x = jnp.swapaxes(x, 0, 1)  # time-major, as the scan reads and stacks
 
     @jax.named_scope("lstm_cell")
-    def step(carry, xt):
-        h, c = carry
-        # one fused (B, in+units) @ (in+units, 4*units) gate matmul; runs at
-        # the input (compute) dtype; the recurrent cell state accumulates in
-        # float32 — bf16's 8-bit mantissa drifts badly over long scans in
-        # `c = f*c + i*g`
-        z = (jnp.concatenate([xt, h.astype(xt.dtype)], axis=1) @ W
-             + p["bias"]).astype(jnp.float32)
-        i = rec_act(z[:, :units])
-        f = rec_act(z[:, units : 2 * units])
-        g = act(z[:, 2 * units : 3 * units])
-        o = rec_act(z[:, 3 * units :])
-        c = f * c + i * g
+    def step(carry, x_t):
+        h, c_prev = carry
+        i, f, g, o = (
+            fn(z) for fn, z in _lstm_gates(
+                layer, kernel, recurrent_kernel, bias, x_t, h.astype(x.dtype)
+            )
+        )
+        c = f * c_prev + i * g
         h = o * act(c)
-        # per-step outputs are only materialized when a sequence is
-        # consumed downstream; a many-to-one tail layer skips the (T, B, U)
-        # stacked buffer entirely
-        out = h.astype(xt.dtype) if layer.return_sequences else None
-        return (h, c), out
+        out = h.astype(x.dtype)
+        if save:
+            return (h, c), (c_prev, out)
+        return (h, c), (out if layer.return_sequences else None)
 
-    h0 = jnp.zeros((batch, units), jnp.float32)
-    c0 = jnp.zeros((batch, units), jnp.float32)
-    (h, _), hs = jax.lax.scan(step, (h0, c0), jnp.swapaxes(x, 0, 1))
+    zeros = jnp.zeros((x.shape[1], layer.units), jnp.float32)
+    (h, _), stacked = jax.lax.scan(step, (zeros, zeros), x)
+    hs = stacked[1] if save else stacked
+    out = jnp.swapaxes(hs, 0, 1) if layer.return_sequences else h.astype(x.dtype)
+    return out, ((x, *stacked) if save else ())
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _lstm(layer: LSTMLayer, kernel, recurrent_kernel, bias, x):
+    return _lstm_scan(layer, kernel, recurrent_kernel, bias, x, save=False)[0]
+
+
+def _lstm_fwd(layer, kernel, recurrent_kernel, bias, x):
+    out, saved = _lstm_scan(layer, kernel, recurrent_kernel, bias, x, save=True)
+    return out, (kernel, recurrent_kernel, bias, *saved)
+
+
+def _lstm_bwd(layer, residuals, d_out):
+    """Backward pass by hand. JAX's transpose of the forward scan saves every
+    operand of every step (thirteen stacked buffers a layer) and carries the
+    weight gradients through the loop; on the chip that step is bound by
+    those bytes, not by its arithmetic. Here the reverse scan recomputes a
+    step's gates from its inputs (two products, the same as forward), carries
+    (dh, dc) only and stacks the pre-activations' cotangent ``dz``. After
+    it, the kernel, recurrent-kernel and bias gradients are ONE product over
+    all of (time, batch) — the bias as the weight of a constant-one input —
+    and the input gradient another, accumulated in float32."""
+    kernel, recurrent_kernel, bias, x, c_prevs, hs = residuals  # x time-major
+    act = _activation(layer.activation)
+    f32 = jnp.float32
+    # h[t-1], the recurrent product's operand: the outputs shifted by one step
+    h_prevs = jnp.concatenate([jnp.zeros_like(hs[:1]), hs[:-1]], axis=0)
+
+    @jax.named_scope("lstm_cell")
+    def step(carry, saved):
+        dh, dc = carry
+        x_t, h_prev, c_prev, dh_t = saved
+        if layer.return_sequences:
+            dh = dh + dh_t.astype(f32)
+        # any activation the spec allows: jax.vjp takes its derivative at the
+        # recomputed pre-activation, so none is refused or approximated
+        (i, d_i), (f, d_f), (g, d_g), (o, d_o) = (
+            jax.vjp(fn, z) for fn, z in _lstm_gates(
+                layer, kernel, recurrent_kernel, bias, x_t, h_prev
+            )
+        )
+        act_c, d_act = jax.vjp(act, f * c_prev + i * g)
+        dc = dc + d_act(dh * o)[0]
+        dz = jnp.concatenate(
+            [d_i(dc * g)[0], d_f(dc * c_prev)[0], d_g(dc * i)[0],
+             d_o(dh * act_c)[0]],
+            axis=-1,
+        ).astype(x.dtype)
+        dh_prev = jax.lax.dot_general(
+            dz, recurrent_kernel, (((1,), (1,)), ((), ())),
+            preferred_element_type=f32,
+        )
+        return (dh_prev, dc * f), dz
+
+    zeros = jnp.zeros(c_prevs.shape[1:], f32)
     if layer.return_sequences:
-        return jnp.swapaxes(hs, 0, 1)
-    return h.astype(x.dtype)
+        carry, dhs = (zeros, zeros), jnp.swapaxes(d_out, 0, 1)
+    else:
+        carry, dhs = (d_out.astype(f32), zeros), None
+    _, dzs = jax.lax.scan(step, carry, (x, h_prevs, c_prevs, dhs), reverse=True)
+
+    with jax.named_scope("lstm_weight_grad"):
+        n_in = x.shape[-1]
+        inputs = jnp.concatenate(
+            [x, h_prevs, jnp.ones(x.shape[:2] + (1,), x.dtype)], axis=-1
+        )
+        d_weights = jnp.tensordot(
+            inputs, dzs, ((0, 1), (0, 1)), preferred_element_type=f32
+        )
+        dx = jnp.tensordot(dzs, kernel, ((2,), (1,)), preferred_element_type=f32)
+    return (
+        d_weights[:n_in].astype(kernel.dtype),
+        d_weights[n_in:-1].astype(recurrent_kernel.dtype),
+        d_weights[-1].astype(bias.dtype),
+        jnp.swapaxes(dx, 0, 1).astype(x.dtype),
+    )
+
+
+_lstm.defvjp(_lstm_fwd, _lstm_bwd)
+
+
+def _apply_lstm(layer: LSTMLayer, p, x):
+    """x: (batch, time, in_dim) → (batch, time, units) or (batch, units)."""
+    return _lstm(layer, p["kernel"], p["recurrent_kernel"], p["bias"], x)
 
 
 def _layer_norm(x, scale, bias, eps=1e-6):

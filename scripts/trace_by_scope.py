@@ -10,7 +10,8 @@ import re
 import sys
 from collections import defaultdict
 
-SCOPES = "lstm_cell|dense|attention|window_gather|optimizer_update|fold_predict"
+SCOPES = ("lstm_input_proj|lstm_cell|lstm_weight_grad|dense|attention|window_gather"
+          "|optimizer_update|fold_predict")
 _SCOPE = re.compile(r"[/(](%s)(?=[/)])" % SCOPES)
 _NOISE = {"body", "cond", "closed_call", "vmap()", "jvp()", "transpose(jvp())"}
 

@@ -174,7 +174,8 @@ SCOPES = {
                 dims: [4]
                 funcs: [tanh]
                 lookback_window: 4""",
-        ("lstm_cell", "dense", "window_gather", "optimizer_update", "fold_predict"),
+        ("lstm_input_proj", "lstm_cell", "lstm_weight_grad", "dense",
+         "window_gather", "optimizer_update", "fold_predict"),
     ),
     "transformer": (
         """gordo_tpu.models.models.TransformerAutoEncoder:
