@@ -29,7 +29,8 @@ is never lost, only speed.
 
 import datetime
 import functools
-from concurrent.futures import ThreadPoolExecutor
+from concurrent.futures import Future, ThreadPoolExecutor
+from concurrent.futures import wait as wait_for_futures
 import logging
 import os
 import time
@@ -91,8 +92,9 @@ _PHASE_ASSEMBLE = metric_catalog.BUILD_PHASE_SECONDS.labels(phase="assemble")
 _STAGE_SECONDS = {
     name: metric_catalog.BUILD_PHASE_SECONDS.labels(phase=name)
     for name in (
-        "plan", "fetch_stage", "validate_stage", "bucket_prep", "stack_h2d",
-        "launch", "wait", "d2h", "slice", "tail", "drain", "finalize",
+        "plan", "fetch_stage", "validate_stage", "bucket_prep", "fetch_wait",
+        "stack_h2d", "launch", "wait", "d2h", "slice", "tail", "drain",
+        "finalize",
     )
 }
 
@@ -164,20 +166,32 @@ class _Plan:
     # init in place of init_model_params when only the machine's data
     # drifted (same spec/config — the warm registry key matched)
     warm_params: Optional[Any] = None
+    # the machine's fetch in flight on a pool thread (load, non-finite
+    # check, warm params); its result is the machine's quarantine record,
+    # or None. The build thread takes it once (``_arrived``) and clears it
+    fetch: Optional[Future] = None
 
-    def bucket_key(self) -> Tuple:
+    def bucket_key(self, fetched: bool = True) -> Tuple:
+        """What machines of one compiled program share. ``fetched=False``
+        leaves out what only the data tells: the key by which machines are
+        ordered and grouped while their fetches are in flight."""
         return (
             self.spec,
-            len(self.X),
             self.epochs,
             self.batch_size,
             self.shuffle,
             self.scale_x,
             self.n_splits,
             self.cv,
-            # warm and cold machines cannot share a program (different
-            # argument structure), so they bucket separately
-            self.warm_params is not None,
+        ) + (
+            (
+                len(self.X),
+                # warm and cold machines cannot share a program (different
+                # argument structure), so they bucket separately
+                self.warm_params is not None,
+            )
+            if fetched
+            else ()
         )
 
 
@@ -713,6 +727,10 @@ class BatchedModelBuilder:
         self.quarantined: List[Machine] = []
         self.quarantine_records: List[QuarantineRecord] = []
         self._quarantined_names: set = set()
+        # machines (by index) that arrived with another shape than the
+        # bucket they were grouped into before their data was there;
+        # _build_all buckets them again once that bucket has ended
+        self._set_aside: Dict[int, _Plan] = {}
         # fleet programs that failed to compile during the last build():
         # {"bucket", "machines", "error"} each. The fault ladder may still
         # have built those machines some other way, so the exit report
@@ -767,7 +785,54 @@ class BatchedModelBuilder:
                 ),
             )
 
+    def _fetch_machine(self, plan: _Plan) -> Optional[QuarantineRecord]:
+        """One machine's whole fetch, on a pool thread: the guarded load,
+        the non-finite check, the warm-start params. Returns the machine's
+        quarantine record, or None with the plan filled. A NaN column would
+        train to NaN params and poison nothing but its own vmap lane — but
+        its thresholds/scores would be garbage and, before it is stacked,
+        it is trivially isolable."""
+        record = self._load_data_guarded(plan)
+        if record is not None:
+            return record
+        name = plan.machine.name
+        with _machine_trace(name), telemetry.span(
+            "validate", _PHASE_VALIDATE, machine=name
+        ):
+            bad = faults.non_finite_report(plan.X, plan.y)
+        if bad is not None:
+            if self.fail_fast:
+                raise faults.NonFiniteDataError(f"machine {name}: {bad}")
+            return QuarantineRecord(
+                machine=name,
+                stage=faults.STAGE_DATA_VALIDATION,
+                reason="non_finite_data",
+                error=bad,
+            )
+        plan.warm_params = self._maybe_warm_params(plan.machine, plan.spec)
+        return None
+
+    def _arrived(self, plan: _Plan, quarantine=None) -> bool:
+        """Block until the machine's fetch has ended and say whether its
+        data is there to build from. The first call takes the fetch's
+        outcome — under ``fail_fast`` that raises the machine's own
+        exception, here on the build thread; otherwise a failed machine is
+        handed to ``quarantine(machine, record)`` — and a later call (a
+        bucket retried or bisected) only answers: the provider is asked
+        once a machine."""
+        pending, plan.fetch = plan.fetch, None
+        if pending is not None:
+            record = pending.result()
+            if record is not None:
+                (quarantine or self._quarantine_record)(plan.machine, record)
+            elif plan.warm_params is not None:
+                metric_catalog.WARM_STARTS.inc()
+        return plan.machine.name not in self._quarantined_names
+
     # -------------------------------------------------------- quarantine
+    def _quarantine_record(self, machine: Machine, record) -> None:
+        self._quarantine(machine, record=record)
+
     def _quarantine(
         self,
         machine: Machine,
@@ -823,6 +888,7 @@ class BatchedModelBuilder:
         self.quarantined = []
         self.quarantine_records = []
         self._quarantined_names = set()
+        self._set_aside = {}
         self.compile_failures = []
         with maybe_profile("batched-build"):
             with telemetry.span("batched_build", machines=len(self.machines)):
@@ -954,30 +1020,6 @@ class BatchedModelBuilder:
         except Exception:  # noqa: BLE001 — same rationale as above
             return None
 
-    def _attach_warm_params(self, plans: Dict[int, "_Plan"]) -> None:
-        """Fill plan.warm_params for full-cache-missed machines (threaded:
-        one serializer.load per warm hit)."""
-        if not self.warm_start or not self.model_register_dir or not plans:
-            return
-        items = list(plans.values())
-        with ThreadPoolExecutor(max_workers=min(16, len(items))) as pool:
-            warms = list(
-                pool.map(
-                    lambda p: self._maybe_warm_params(p.machine, p.spec), items
-                )
-            )
-        n_warm = 0
-        for plan, warm in zip(items, warms):
-            if warm is not None:
-                plan.warm_params = warm
-                n_warm += 1
-        if n_warm:
-            metric_catalog.WARM_STARTS.inc(n_warm)
-            logger.info(
-                "warm-start delta rebuild: %d of %d machines initialize "
-                "from their prior artifact's params", n_warm, len(items),
-            )
-
     def _build_all(self, distributed) -> List[Tuple[Any, Machine]]:
         if self.elastic:
             return self._build_all_elastic(distributed)
@@ -1078,73 +1120,110 @@ class BatchedModelBuilder:
                         error=str(exc),
                     )
 
-        def quarantine(machine, record):
-            self._quarantine(machine, record=record)
-
-        self._fetch_stage(plans, quarantine)
-        buckets = self._validate_stage(plans, quarantine)
-
-        for key, idxs in buckets.items():
-            bucket_plans = [plans[i] for i in idxs]
-            for i, built in self._build_bucket_guarded(bucket_plans, idxs):
-                results[i] = built
+        # the fetch is a stream that the chunk loop consumes: a bucket's
+        # first chunk is dispatched when its own machines have arrived, and
+        # every later chunk's data is fetched while the device runs the
+        # chunk before it (_build_bucket waits for a group's fetches just
+        # before it stacks the group). Across processes every one has to
+        # agree on each chunk's membership before it is launched, so there
+        # all are fetched and validated first, as ever
+        pool = None
+        if distributed.is_multiprocess():
+            self._fetch_stage(plans, self._quarantine_record)
+            buckets = self._validate_stage(plans)
+        else:
+            buckets = self._validate_stage(plans, fetched=False)
+            pool = ThreadPoolExecutor(
+                max_workers=min(16, max(len(plans), 1)),
+                thread_name_prefix="gordo-fetch",
+            )
+        try:
+            if pool is not None:
+                self._fetch_stage(plans, pool=pool, buckets=buckets)
+            while buckets:
+                for idxs in buckets.values():
+                    bucket_plans = [plans[i] for i in idxs]
+                    for i, built in self._build_bucket_guarded(bucket_plans, idxs):
+                        results[i] = built
+                # machines that arrived with another shape than their
+                # bucket's first were set aside: their data is there now, so
+                # they are bucketed by the full key and built as ever
+                plans, self._set_aside = self._set_aside, {}
+                buckets = self._buckets_of(plans)
+        finally:
+            if pool is not None:
+                # nothing is pending unless the build is being abandoned
+                pool.shutdown(wait=True, cancel_futures=True)
 
         return [results[i] for i in sorted(results)]
 
-    def _fetch_stage(self, plans: Dict[int, _Plan], quarantine) -> None:
+    @staticmethod
+    def _buckets_of(
+        plans: Dict[int, _Plan], fetched: bool = True
+    ) -> Dict[Tuple, List[int]]:
+        buckets: Dict[Tuple, List[int]] = {}
+        for i, plan in plans.items():
+            buckets.setdefault(plan.bucket_key(fetched), []).append(i)
+        return buckets
+
+    def _chunk_width(self, n_machines: int) -> int:
+        """Machines a dispatch of a bucket of ``n_machines``: a fixed width
+        (a multiple of the mesh size), so that one compiled program is
+        reused for every chunk and compile cost does not scale with the
+        bucket."""
+        n_dev = int(np.prod(list(self.mesh.shape.values())))
+        return ((min(self.chunk_size, n_machines) + n_dev - 1) // n_dev) * n_dev
+
+    def _submit_fetches(self, pool: ThreadPoolExecutor, plans) -> None:
+        for plan in plans:
+            plan.fetch = pool.submit(self._fetch_machine, plan)
+
+    def _fetch_stage(
+        self, plans: Dict[int, _Plan], quarantine=None, pool=None, buckets=None
+    ) -> None:
         """Fetch every planned machine's data concurrently (provider I/O is
-        the per-machine serial cost the reference paid per pod). Each fetch
-        retries transient faults with backoff and, on exhaustion, the
-        machine is handed to ``quarantine(machine, record)`` and dropped
-        from ``plans`` — one dead sensor feed degrades one machine, not the
+        the per-machine serial cost the reference paid per pod), each with
+        its non-finite check and warm params on the same thread. A fetch
+        retries transient faults with backoff; on exhaustion the machine is
+        quarantined — one dead sensor feed degrades one machine, not the
         fleet (the blast radius the reference got from one-pod-per-machine).
+
+        As a barrier (no ``pool``): returns when every fetch has ended,
+        with the failed machines handed to ``quarantine(machine, record)``
+        and dropped from ``plans``. As a stream (the caller's ``pool``,
+        which outlives the stage): submits the machines in the order the
+        ``buckets``' chunks will consume them and returns when the first
+        chunk's have arrived; ``_build_bucket`` takes each group's outcomes.
         """
         with _stage("fetch_stage", machines=len(plans)):
             if not plans:
                 return
-            with ThreadPoolExecutor(max_workers=min(16, len(plans))) as pool:
-                records = list(pool.map(self._load_data_guarded, plans.values()))
-            for (i, plan), record in zip(list(plans.items()), records):
-                if record is not None:
-                    quarantine(plan.machine, record)
-                    del plans[i]
+            if pool is None:
+                with ThreadPoolExecutor(max_workers=min(16, len(plans))) as pool:
+                    self._submit_fetches(pool, plans.values())
+                for i in list(plans):
+                    if not self._arrived(plans[i], quarantine):
+                        del plans[i]
+                return
+            order = [plans[i] for idxs in buckets.values() for i in idxs]
+            n_first = len(next(iter(buckets.values())))
+            n_first = min(n_first, self._chunk_width(n_first))
+            # the first chunk's machines have the threads to themselves:
+            # nothing is dispatched before they are there
+            self._submit_fetches(pool, order[:n_first])
+            wait_for_futures([plan.fetch for plan in order[:n_first]])
+            self._submit_fetches(pool, order[n_first:])
 
     def _validate_stage(
-        self, plans: Dict[int, _Plan], quarantine
+        self, plans: Dict[int, _Plan], quarantine=None, fetched: bool = True
     ) -> Dict[Tuple, List[int]]:
-        """Pre-flight validation, warm-start params, then the buckets by
-        (spec, shapes, config). A NaN column would train to NaN params and
-        poison nothing but its own vmap lane — but its thresholds/scores
-        would be garbage and, pre-bucketing, it is trivially isolable."""
+        """What is left of validation on the build thread (the non-finite
+        check runs beside each fetch): the buckets by (spec, shapes,
+        config) — or, with the fetches still to come (``fetched=False``),
+        by what is known without the data. Nothing is quarantined here any
+        more; the elastic build still passes its ``quarantine``."""
         with _stage("validate_stage", machines=len(plans)):
-            for i in list(plans):
-                plan = plans[i]
-                with _machine_trace(plan.machine.name), telemetry.span(
-                    "validate", _PHASE_VALIDATE, machine=plan.machine.name
-                ):
-                    bad = faults.non_finite_report(plan.X, plan.y)
-                if bad is not None:
-                    if self.fail_fast:
-                        raise faults.NonFiniteDataError(
-                            f"machine {plan.machine.name}: {bad}"
-                        )
-                    quarantine(
-                        plan.machine,
-                        QuarantineRecord(
-                            machine=plan.machine.name,
-                            stage=faults.STAGE_DATA_VALIDATION,
-                            reason="non_finite_data",
-                            error=bad,
-                        ),
-                    )
-                    del plans[i]
-
-            self._attach_warm_params(plans)
-
-            buckets: Dict[Tuple, List[int]] = {}
-            for i, plan in plans.items():
-                buckets.setdefault(plan.bucket_key(), []).append(i)
-            return buckets
+            return self._buckets_of(plans, fetched)
 
     def _build_all_elastic(self, distributed) -> List[Tuple[Any, Machine]]:
         """The work-stealing fleet build (parallel/scheduler.py): every
@@ -1404,12 +1483,15 @@ class BatchedModelBuilder:
 
         ``fail_fast`` skips the whole ladder (pre-fault-domain behavior).
         """
-        # drop members quarantined since this bucket was assembled (e.g. on
-        # the retry after a mixed failure)
+        # drop members quarantined, or set aside for a bucket of their own
+        # shape, since this bucket was assembled (e.g. on the retry after a
+        # mixed failure). A member whose fetch is still in flight stays:
+        # whoever builds it waits for that fetch, and nobody fetches again
         live = [
             (p, i)
             for p, i in zip(bucket, global_idxs)
             if p.machine.name not in self._quarantined_names
+            and i not in self._set_aside
         ]
         if not live:
             return []
@@ -1504,7 +1586,43 @@ class BatchedModelBuilder:
             faults.fault_point(
                 "bucket_compile", machines=[p.machine.name for p in bucket]
             )
-            plan0 = bucket[0]
+            # M and the chunk are those of the bucket as planned: a machine
+            # lost to its fetch leaves a padding lane, not a narrower program
+            M = len(bucket)
+            chunk = self._chunk_width(M)
+            members = list(zip(bucket, global_idxs))
+            key: Optional[Tuple] = None
+
+            def take(start: int) -> List[Tuple[_Plan, int]]:
+                """The chunk's machines that are there to build, each with
+                its global index, once their fetches have ended: a machine
+                whose fetch failed is quarantined by then, and one that
+                arrived with another shape than the bucket's first (the row
+                count, warm or cold: what only the data tells) is set aside
+                for a bucket of its own."""
+                nonlocal key
+                group = []
+                for plan, i in members[start : start + chunk]:
+                    if not self._arrived(plan):
+                        continue
+                    arrived_as = plan.bucket_key()
+                    if key is None:
+                        key = arrived_as
+                    if arrived_as != key:
+                        self._set_aside[i] = plan
+                        continue
+                    group.append((plan, i))
+                return group
+
+            # the first chunk that has a machine left fixes the shapes
+            starts = list(range(0, M, chunk))
+            first_group = take(starts[0])
+            while not first_group:
+                del starts[0]
+                if not starts:
+                    return []
+                first_group = take(starts[0])
+            plan0 = first_group[0][0]
             spec = plan0.spec
             n_rows = len(plan0.X)
             kfold_folds: Optional[List[Tuple[np.ndarray, np.ndarray]]] = None
@@ -1534,7 +1652,6 @@ class BatchedModelBuilder:
                 ).astype(np.int32)
             else:
                 fold_bounds = self._fold_bounds(n_rows, plan0.n_splits)
-            n_dev = int(np.prod(list(self.mesh.shape.values())))
 
             # every CV fold must yield at least one training sample, mirroring the
             # serial path's explicit error (ops/train.py fit_arrays)
@@ -1546,11 +1663,6 @@ class BatchedModelBuilder:
                         f"lookahead={spec.lookahead} "
                         f"(machines: {[p.machine.name for p in bucket]})"
                     )
-
-            M = len(bucket)
-            # fixed chunk size (multiple of mesh size): one compiled program is
-            # reused for every chunk, so compile cost doesn't scale with M
-            chunk = ((min(self.chunk_size, M) + n_dev - 1) // n_dev) * n_dev
 
             from gordo_tpu.parallel import distributed
 
@@ -1606,18 +1718,30 @@ class BatchedModelBuilder:
 
         t0 = time.time()
 
-        def dispatch(start: int):
+        def take_late(start: int):
+            """A later chunk's machines: their fetches ran under the chunks
+            before it, and whatever is left of them the device now waits
+            for."""
+            late = sum(
+                1
+                for plan, _ in members[start : start + chunk]
+                if plan.fetch is not None and not plan.fetch.done()
+            )
+            with _stage("fetch_wait", chunk_start=start, machines=late):
+                return take(start)
+
+        def dispatch(start: int, group):
             with _stage("stack_h2d", chunk_start=start):
-                group = bucket[start : start + chunk]
-                pad = chunk - len(group)
-                X = np.stack([p.X for p in group] + [group[0].X] * pad)
-                y = np.stack([p.y for p in group] + [group[0].y] * pad)
+                lanes = [plan for plan, _ in group]
+                pad = chunk - len(lanes)
+                X = np.stack([p.X for p in lanes] + [lanes[0].X] * pad)
+                y = np.stack([p.y for p in lanes] + [lanes[0].y] * pad)
                 # per-machine RNG stream derived from (evaluation.seed, machine
                 # name): independent of bucket composition/ordering, so a
                 # machine's weights are reproducible no matter which other
                 # machines train alongside it
                 seeds = np.array(
-                    [_machine_seed(p.machine) for p in group] + [0] * pad,
+                    [_machine_seed(p.machine) for p in lanes] + [0] * pad,
                     dtype=np.uint32,
                 )
                 X_d = distributed.make_global_stacked(sharding, X)
@@ -1628,10 +1752,10 @@ class BatchedModelBuilder:
                     args = args + (perms_d,)
                 if warm:
                     # stack each machine's prior params on the machine axis
-                    # (padding lanes replicate group[0], like X/y above) and
+                    # (padding lanes replicate lanes[0], like X/y above) and
                     # shard the stacked tree exactly like the other inputs
-                    trees = [p.warm_params for p in group] + [
-                        group[0].warm_params
+                    trees = [p.warm_params for p in lanes] + [
+                        lanes[0].warm_params
                     ] * pad
                     stacked = jax.tree_util.tree_map(
                         lambda *leaves: np.stack(leaves), *trees
@@ -1693,7 +1817,8 @@ class BatchedModelBuilder:
                 per_machine_est = (time.time() - t0) / max(n_done, 1)
                 for j, row in enumerate(int(r) for r in rows):
                     if row >= len(group):
-                        continue  # padding rows replicate group[0]; skip
+                        continue  # padding rows replicate the first lane; skip
+                    plan, idx = group[row]
                     params_i = jax.tree_util.tree_map(lambda a: a[j], params_stack)
                     fold_preds_i = [fp[j] for fp in fold_preds]
                     # post-build divergence detection: a lane that trained to
@@ -1701,11 +1826,10 @@ class BatchedModelBuilder:
                     # its garbage must not be persisted as a servable artifact
                     bad = faults.params_non_finite(params_i, losses[j])
                     if bad is None and faults.should_fire(
-                        "diverge", group[row].machine.name
+                        "diverge", plan.machine.name
                     ):
                         bad = "injected divergence"
                     if bad is not None:
-                        plan = group[row]
                         if self.fail_fast:
                             raise faults.DivergedModelError(
                                 f"machine {plan.machine.name}: {bad}"
@@ -1726,8 +1850,8 @@ class BatchedModelBuilder:
                                     kfold_folds,
                                 ),
                             ),
-                            global_idxs[chunk_start + row],
-                            group[row],
+                            idx,
+                            plan,
                             params_i,
                             losses[j],
                             fold_preds_i,
@@ -1739,7 +1863,6 @@ class BatchedModelBuilder:
         # O(chunk) rather than O(M)
         bucket_name = f"{plan0.machine.name}+{M - 1}"
         with ThreadPoolExecutor(max_workers=8) as pool:
-            starts = list(range(0, M, chunk))
             # jit compiles synchronously during the first call (execution is
             # dispatched async), so the first-dispatch span is the compile
             # span — on a warm program cache it collapses to device_put time.
@@ -1751,7 +1874,8 @@ class BatchedModelBuilder:
             ):
                 t_compile = time.time()
                 try:
-                    in_flight, in_flight_start = dispatch(starts[0]), starts[0]
+                    in_flight = dispatch(starts[0], first_group)
+                    in_flight_start = starts[0]
                 except Exception as exc:
                     if not program_cached:
                         self._note_compile_failure(bucket_name, M, exc)
@@ -1763,7 +1887,10 @@ class BatchedModelBuilder:
                 chunk=chunk,
             ):
                 for start in starts[1:]:
-                    next_in_flight = dispatch(start)
+                    group = take_late(start)
+                    if not group:
+                        continue  # every machine of the chunk was lost
+                    next_in_flight = dispatch(start, group)
                     enqueue_assembly(
                         pool, fetch(*wait(*in_flight)), in_flight_start
                     )
@@ -1778,8 +1905,9 @@ class BatchedModelBuilder:
                 with _stage("drain"):
                     out = [f.result() for f in futures]
                 logger.info(
-                    "Batched bucket: %d machines (chunk %d) trained in %.2fs",
-                    M, chunk, train_duration,
+                    "Batched bucket: %d machines (chunk %d, %s start) trained "
+                    "in %.2fs",
+                    M, chunk, "warm" if warm else "cold", train_duration,
                 )
                 with _stage("finalize"):
                     self._finalize_durations(

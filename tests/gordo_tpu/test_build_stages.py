@@ -21,10 +21,12 @@ ONCE_A_BUILD = (
     "train", "tail", "drain", "finalize",
 )
 ONCE_A_CHUNK = ("stack_h2d", "launch", "wait", "d2h", "slice")
+# once a chunk after the first: the wait for the chunk's own fetches
+AFTER_THE_FIRST = ("fetch_wait",)
 LEAVES = (
     "plan", "fetch_stage", "validate_stage", "bucket_prep", "drain",
     "finalize",
-) + ONCE_A_CHUNK
+) + ONCE_A_CHUNK + AFTER_THE_FIRST
 CHUNKS = 2
 
 
@@ -77,7 +79,7 @@ def _clean_telemetry():
 def _phase_counts():
     return {
         phase: metric_catalog.BUILD_PHASE_SECONDS.count(phase=phase)
-        for phase in ONCE_A_BUILD + ONCE_A_CHUNK
+        for phase in ONCE_A_BUILD + ONCE_A_CHUNK + AFTER_THE_FIRST
     }
 
 
@@ -97,10 +99,13 @@ def test_build_thread_stages_tile_the_build(tmp_path):
         assert len(by_name[name]) == 1, name
     for name in ONCE_A_CHUNK:
         assert len(by_name[name]) == CHUNKS, name
+    for name in AFTER_THE_FIRST:
+        assert len(by_name[name]) == CHUNKS - 1, name
     # each observed its wall under its own phase label
     assert _phase_counts() == {
         **{name: 1 for name in ONCE_A_BUILD},
         **{name: CHUNKS for name in ONCE_A_CHUNK},
+        **{name: CHUNKS - 1 for name in AFTER_THE_FIRST},
     }
 
     # the leaves are contiguous and non-overlapping, and cover the build
@@ -111,6 +116,14 @@ def test_build_thread_stages_tile_the_build(tmp_path):
     covered = sum(end - start for start, end in leaves)
     assert covered >= 0.95 * (build_end - build_start)
     assert leaves[0][0] >= build_start and leaves[-1][1] <= build_end
+
+    # the fetch stage ends with the first chunk's machines, before anything
+    # is stacked; a later chunk waits for its own just before it is stacked
+    (_, fetch_stage_end), = by_name["fetch_stage"]
+    stacks = sorted(by_name["stack_h2d"])
+    assert fetch_stage_end <= stacks[0][0]
+    for (_, wait_end), (stack_start, _) in zip(by_name["fetch_wait"], stacks[1:]):
+        assert stacks[0][1] <= wait_end <= stack_start
 
     # the parents: compile holds the first chunk's stack_h2d + launch, train
     # ends with the last wait, and tail starts there and holds what follows
