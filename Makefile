@@ -44,26 +44,13 @@ smoke:
 render-gate:
 	python -m pytest tests/gordo_tpu/test_workflow_template_render.py -q
 
-bench:
-	python bench.py
-
-# serving hot path only (ISSUE 19): the smoke + open-loop load sections —
-# fast-lane/UDS/gateway percentiles, syscalls per request, pipeline
-# overlaps — without the training-side sections. Minutes, not the full
-# harness; the partial record must NOT be committed as a BENCH_r*.json
-# round (bench-gate compares full rounds).
-bench-hotpath:
-	GORDO_TPU_BENCH_SECTIONS=tpu_smoke,serving_load python bench.py
-
-# hard perf regression gate: diff the two most recent BENCH_r*.json
-# records with comparable-section matching (exit 1 on a >15% regression;
-# see docs/benchmarking.md "Reading the gate")
-bench-gate:
-	python scripts/bench_compare.py --latest .
-
-# schema check on every checked-in bench record (also runs in tier-1)
-lint-bench-records:
-	python scripts/lint_bench_record.py
+# the chip benchmark's control flow on the CPU, at a tiny size: never a
+# number (BENCHMARK.json + chipbench/ are the yardstick and need the chip:
+# chipbench/README.md)
+chipbench-rehearsal:
+	JAX_PLATFORMS=cpu python3 -m chipbench.run --rehearsal \
+		--manifest chipbench/rehearsal/manifest.json \
+		--workload lstm_tiny.rehearsal --seed 1 --seconds 1 --trace 1
 
 # metric <-> dashboard consistency, both directions: every catalog metric
 # is plotted/documented somewhere, and every gordo_* name a dashboard
@@ -90,6 +77,5 @@ chaos-smoke:
 profile-smoke:
 	JAX_PLATFORMS=cpu python scripts/profile_smoke.py
 
-.PHONY: image push test dryrun chip-smoke smoke render-gate bench bench-hotpath \
-	bench-gate lint-bench-records lint-dashboards lint-chaos-scenarios \
-	chaos-smoke profile-smoke
+.PHONY: image push test dryrun chip-smoke smoke render-gate chipbench-rehearsal \
+	lint-dashboards lint-chaos-scenarios chaos-smoke profile-smoke

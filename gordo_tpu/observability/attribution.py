@@ -12,11 +12,9 @@ turns those per-request numbers into an *explanation*:
   ``int(now // width)`` so worker shards merge by exact addition), riding
   the telemetry shard plane like slo/drift/device. ``GET /debug/perf``
   serves the current-vs-previous-window decomposition.
-- **BENCH records** — :func:`phase_stats_from_record` extracts the same
-  phase stats from a committed ``BENCH_r*.json`` (embedded in
-  ``parsed.serving_load`` for new records, recovered from the record's
-  detail JSON for older ones), so ``scripts/bench_compare.py --explain``
-  prints *which phase* a gate failure came from.
+- **Any two windows' stats** — :func:`decompose_stats` takes two
+  ``{"total", "phases"}`` blocks (``window_stats`` makes them), so a
+  recorded window can be set against a live one.
 
 The decomposition contract: the reported rows always sum **exactly** to
 the headline delta. Measured phases (decode/predict/encode) contribute
@@ -36,7 +34,6 @@ these windows) is enabled — the serving path is byte-identical with the
 knobs unset.
 """
 
-import json
 import math
 import os
 import threading
@@ -356,76 +353,8 @@ def snapshot() -> Dict[str, Any]:
     }
 
 
-# ------------------------------------------------- BENCH record extraction
-def _stats_from_qps_block(qps: Any) -> Optional[Dict[str, Any]]:
-    if not isinstance(qps, dict):
-        return None
-    phases = qps.get("phases")
-    if not isinstance(phases, dict) or not phases:
-        return None
-    total = {
-        "p50_ms": qps.get("p50_ms"),
-        "p99_ms": qps.get("p99_ms"),
-    }
-    if total["p50_ms"] is None and total["p99_ms"] is None:
-        return None
-    blocks = {
-        name: {"p50_ms": row.get("p50_ms"), "p99_ms": row.get("p99_ms")}
-        for name, row in phases.items()
-        if isinstance(row, dict)
-    }
-    return {"total": total, "phases": blocks}
-
-
-def phase_stats_from_record(
-    record: Dict[str, Any], base_dir: str = "."
-) -> Optional[Dict[str, Any]]:
-    """Recover serving-phase stats from a BENCH record, trying in order:
-    the ``parsed.serving_load.phases`` block (records >= r10), a
-    ``{"detail": ...}`` JSON line in the record's captured tail, then
-    the ``parsed.detail_file`` sidecar next to the record."""
-    parsed = record.get("parsed") or {}
-    serving = parsed.get("serving_load") or {}
-
-    stats = _stats_from_qps_block(
-        dict(
-            serving,
-            p50_ms=serving.get("p50_ms", parsed.get("server_load_p50_ms")),
-            p99_ms=serving.get("p99_ms", parsed.get("server_load_p99_ms")),
-        )
-    )
-    if stats:
-        return stats
-
-    detail = None
-    tail = record.get("tail") or ""
-    for line in reversed(tail.splitlines()):
-        line = line.strip()
-        if line.startswith("{") and '"detail"' in line:
-            try:
-                detail = json.loads(line).get("detail")
-            except ValueError:
-                continue
-            if detail:
-                break
-    if detail is None:
-        detail_file = parsed.get("detail_file")
-        if detail_file:
-            path = os.path.join(base_dir, str(detail_file))
-            if os.path.exists(path):
-                try:
-                    with open(path) as fh:
-                        detail = json.load(fh)
-                except (OSError, ValueError):
-                    detail = None
-    if not isinstance(detail, dict):
-        return None
-    result = (detail.get("serving_load") or {}).get("result") or {}
-    return _stats_from_qps_block(result.get("qps"))
-
-
 def format_decomposition(decomp: Dict[str, Any]) -> List[str]:
-    """Human-readable table lines for bench_compare / CLI output."""
+    """Human-readable table lines of a decomposition."""
     lines = [
         "  {:<18} {:>10} {:>10} {:>10} {:>8}".format(
             f"phase ({decomp['percentile']})", "base_ms", "new_ms",

@@ -12,12 +12,12 @@ midpoints are therefore exact to a *relative* error bound of
 range — nanoseconds to hours — with O(1) record cost and a few KB of
 memory, where a sorted-array percentile would retain every sample.
 
-Built for the closed-loop load harness (``benchmarks/load_test.py``) and
-the bench sections (``bench.py``):
+Built for the load harness (``benchmarks/load_test.py``) and the serving
+windows (slo.py, attribution.py):
 
 - **mergeable**: worker threads each record into their own histogram with
   zero contention and ``merge`` folds them associatively afterwards; a
-  bench section child can ship its histogram across a process boundary as
+  worker process can ship its histogram across a process boundary as
   JSON (``to_dict``/``from_dict``) for the parent to merge.
 - **coordinated-omission aware**: ``record_with_expected_interval``
   back-fills the latencies a stalled server *prevented from being

@@ -31,9 +31,8 @@ able to name the hot threads even when steady sampling is off.
 Cost model when on: one ``sys._current_frames()`` call per tick returns
 every thread's current frame without stopping the world; folding walks at
 most ``_MAX_DEPTH`` frames per registered thread. At 99 Hz over three
-registered threads this is tens of microseconds per tick — the
-``profiler_overhead`` bench arm (bench.py serving_load) gates the
-end-to-end p50 cost at <= 3%.
+registered threads this is tens of microseconds per tick; what it costs a
+request end to end is not measured (no cell serves yet, PERF.md §7).
 """
 
 import logging

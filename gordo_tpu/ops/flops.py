@@ -4,13 +4,13 @@ Analytic FLOPs accounting per :class:`~gordo_tpu.models.spec.ModelSpec`.
 The reference publishes no performance numbers at all (BASELINE.md); for a
 TPU-native framework the honest single-chip yardstick is MFU — achieved
 FLOP/s divided by the chip's peak for the compute dtype. This module derives
-the FLOP count of a forward pass (and standard 3x training step) by walking
-the spec's layers, so ``bench.py`` can report MFU without instrumenting the
-compiled program.
+the FLOP count of a forward pass by walking the spec's layers, so the serving
+MFU gauge (server/batcher.py, observability/device.py) needs no
+instrumentation of the compiled program. A build's operations are counted by
+the benchmark's own ``chipbench/flops.py``.
 
 Conventions (standard accounting, matmul-dominated):
 - a matmul of (m, k) x (k, n) costs 2*m*k*n FLOPs
-- backward pass costs ~2x forward (grad wrt inputs + grad wrt weights)
 - elementwise work (activations, norms, residuals) is ignored — it is
   bandwidth-, not FLOP-, bound and contributes <1% on these shapes
 """
@@ -75,42 +75,6 @@ def forward_flops_per_sample(spec: ModelSpec) -> float:
             if isinstance(layer, PoolLayer):
                 seq = False
         in_dim = layer_out_dim(layer, in_dim)
-    return total
-
-
-def training_flops_per_sample(spec: ModelSpec) -> float:
-    """Forward + backward (~2x forward); remat re-runs forward once more."""
-    mult = 4.0 if spec.remat else 3.0
-    return mult * forward_flops_per_sample(spec)
-
-
-def n_windows(spec: ModelSpec, n_rows: int) -> int:
-    """Output rows for an input of ``n_rows`` (window semantics parity with
-    reference models.py:715-796 via ModelSpec.output_offset)."""
-    return max(n_rows - spec.output_offset, 0)
-
-
-def cv_build_flops(
-    spec: ModelSpec,
-    n_rows: int,
-    epochs: int,
-    n_splits: int = 3,
-) -> float:
-    """Total FLOPs of one machine build: ``n_splits`` TimeSeriesSplit fold
-    trainings + fold predictions + the final full fit (the reference builder
-    contract, gordo/builder/build_model.py:169-289).
-
-    sklearn's TimeSeriesSplit on N rows yields train sizes k*N/(n_splits+1)
-    and test size N/(n_splits+1) per fold.
-    """
-    fwd = forward_flops_per_sample(spec)
-    train = training_flops_per_sample(spec)
-    fold = n_rows // (n_splits + 1)
-    total = 0.0
-    for k in range(1, n_splits + 1):
-        total += train * n_windows(spec, k * fold) * epochs
-        total += fwd * n_windows(spec, fold)
-    total += train * n_windows(spec, n_rows) * epochs
     return total
 
 

@@ -107,9 +107,9 @@ def make_epoch_fn(
     """
     Pure single-epoch step ``epoch(params, opt_state, X, y, rng) ->
     (params, opt_state, mean_loss)``: one ``lax.scan`` over minibatches with
-    zero-weighted index padding. Shared by the host-loop trainer
-    (``fit_arrays``) and the fully-scanned vmapped trainer
-    (``make_scanned_fit``) so the two paths cannot drift numerically.
+    zero-weighted index padding. The host-loop trainer (``fit_arrays``)
+    runs it; the fleet program's ``make_masked_epoch_fn`` is the same body
+    with a traced live-sample count, and a test holds the two equal.
     """
     n_steps = max((n_samples + batch_size - 1) // batch_size, 1)
     n_pad = n_steps * batch_size
@@ -294,40 +294,6 @@ def make_masked_epoch_fn(
         return params, opt_state, loss_sum / jnp.maximum(w_sum, 1.0)
 
     return epoch
-
-
-# ------------------------------------------------- pure scanned fit (vmap)
-def make_scanned_fit(
-    spec: ModelSpec,
-    n_samples: int,
-    batch_size: int,
-    epochs: int,
-    shuffle: bool = True,
-):
-    """
-    Build a pure function ``fit(params, X, y, rng) -> (params, losses)`` with
-    ALL epochs fused into one ``lax.scan`` — no host round-trips, no
-    callbacks. This is the unit the batched multi-machine trainer ``vmap``s
-    over the machine axis: same spec + same shapes = one XLA program for any
-    number of machines.
-    """
-    batch_size = min(batch_size, max(n_samples, 1))
-    opt = make_optimizer(spec.optimizer)
-    epoch_fn = make_epoch_fn(spec, n_samples, batch_size, shuffle)
-
-    def fit(params, X, y, rng):
-        opt_state = opt.init(params)
-
-        def epoch_body(carry, epoch_rng):
-            params, opt_state = carry
-            params, opt_state, loss = epoch_fn(params, opt_state, X, y, epoch_rng)
-            return (params, opt_state), loss
-
-        rngs = jax.random.split(rng, epochs)
-        (params, _), losses = jax.lax.scan(epoch_body, (params, opt_state), rngs)
-        return params, losses
-
-    return fit
 
 
 # ------------------------------------------------------------------ fitting

@@ -25,6 +25,31 @@ Numerical parity notes:
 Machines whose model config the planner cannot express (arbitrary sklearn
 steps, custom estimators) fall back to the serial ModelBuilder — capability
 is never lost, only speed.
+
+The pipeline of one ``build()``, in the order of this file:
+
+1. **plan** (``_plan_fleet``, once for both orchestrators): the resume
+   prefilter returns what the registry already holds, ``_plan_machine``
+   plans what can be batched, the rest is listed for the serial builder.
+   Who owns a cache hit is the one thing the orchestrators decide: the hash
+   partition (``_build_all``) or a claim (``_build_all_elastic``).
+2. **fetch stream** (``_fetch_stage``): every planned machine's data is
+   fetched on a pool, in the order the chunks will consume it; the stage
+   ends when the first chunk's machines have arrived. A build that must
+   agree on membership before a launch (several processes, elastic hosts)
+   waits the stream out and takes every outcome first (``_fetch_all``).
+3. **buckets** (``_Plan.bucket_key``): machines of one compiled program,
+   grouped by what is known of them — everything but the row count and
+   warm/cold before their data is there, the full key after.
+4. **chunk loop** (``_build_bucket``): the fold geometry
+   (``_fold_geometry``) and the program (``_program_for`` →
+   ``_bucket_program``) from the bucket's first arrival, then fixed-width
+   chunks, two in flight; a later chunk waits for its own fetches just
+   before it is stacked. ``_build_bucket_guarded`` is the recovery ladder
+   round it.
+5. **assembly** (``_assemble_and_persist``, on a pool, chunk by chunk under
+   the device): thresholds, scores, metadata, the artifact's dump and its
+   registry keys; the bucket's end rewrites the durations.
 """
 
 import datetime
@@ -66,7 +91,6 @@ from gordo_tpu.ops.nn import apply_model, init_model_params
 from gordo_tpu.ops.train import (
     make_masked_epoch_fn,
     make_optimizer,
-    make_scanned_fit,
     n_train_samples,
 )
 from gordo_tpu.observability import metrics as metric_catalog
@@ -171,11 +195,12 @@ class _Plan:
     # or None. The build thread takes it once (``_arrived``) and clears it
     fetch: Optional[Future] = None
 
-    def bucket_key(self, fetched: bool = True) -> Tuple:
-        """What machines of one compiled program share. ``fetched=False``
-        leaves out what only the data tells: the key by which machines are
-        ordered and grouped while their fetches are in flight."""
-        return (
+    def bucket_key(self) -> Tuple:
+        """What machines of one compiled program share. Before the machine's
+        data is there the key leaves out what only the data tells (the row
+        count, warm or cold): by that partial key machines are ordered and
+        grouped while their fetches are in flight."""
+        key = (
             self.spec,
             self.epochs,
             self.batch_size,
@@ -183,16 +208,12 @@ class _Plan:
             self.scale_x,
             self.n_splits,
             self.cv,
-        ) + (
-            (
-                len(self.X),
-                # warm and cold machines cannot share a program (different
-                # argument structure), so they bucket separately
-                self.warm_params is not None,
-            )
-            if fetched
-            else ()
         )
+        if self.X is None:
+            return key
+        # warm and cold machines cannot share a program (different argument
+        # structure), so they bucket separately
+        return key + (len(self.X), self.warm_params is not None)
 
 
 def _plan_machine(machine: Machine) -> Optional[_Plan]:
@@ -256,7 +277,7 @@ def _plan_machine(machine: Machine) -> Optional[_Plan]:
             if tuple(
                 getattr(inner.steps[0][1], "feature_range", (0, 1))
             ) != (0, 1):
-                # the in-program _minmax hardcodes the default range
+                # the program's in-stage scaling hardcodes the default range
                 return None
             scale_x = True
             inner = inner.steps[1][1]
@@ -365,18 +386,6 @@ def _plan_machine(machine: Machine) -> Optional[_Plan]:
 
 
 # ------------------------------------------------------------ the programs
-def _minmax(x_train, x_apply):
-    """Per-feature min-max scale of x_apply by x_train's stats (sklearn
-    MinMaxScaler semantics incl. the near-zero-range guard: sklearn's
-    _handle_zeros_in_scale treats ranges < 10*eps as constant → scale=1)."""
-    mn = x_train.min(axis=0)
-    mx = x_train.max(axis=0)
-    rng = mx - mn
-    tiny = 10 * jnp.finfo(x_train.dtype).eps
-    scale = 1.0 / jnp.where(rng < tiny, 1.0, rng)
-    return (x_apply - mn) * scale
-
-
 @jax.named_scope("fold_predict")
 def _predict_windows(spec: ModelSpec, params, X):
     """Model output over a contiguous slice (windowed for recurrent specs)."""
@@ -438,14 +447,14 @@ def _bucket_program(
     fit). A delta rebuild whose data merely drifted starts each fit from
     yesterday's optimum instead of a random init.
     """
+    # one predict shape serves every fold: TimeSeriesSplit's test slices are
+    # all n // (n_splits + 1) rows, and _fold_geometry pads KFold's to the
+    # largest fold
     te_lens = {te_end - te_start for _, te_start, te_end in fold_bounds}
     if len(te_lens) != 1:
-        # non-uniform test slices can't share one predict shape; rare
-        # (TimeSeriesSplit always yields equal test sizes, and the KFold
-        # planner pads bounds to the max fold size)
-        return _bucket_program_unrolled(
-            spec, n_rows, fold_bounds, epochs, batch_size, shuffle, scale_x,
-            out_sharding, warm_start=warm_start,
+        raise ValueError(
+            f"_bucket_program needs one test length across the folds, got "
+            f"{sorted(te_lens)} from fold_bounds={fold_bounds}"
         )
     te_len = te_lens.pop()
 
@@ -512,7 +521,7 @@ def _bucket_program(
             stages = stages + (perms,)
         _, (params_all, losses_all, preds_all) = jax.lax.scan(stage, None, stages)
         p_final = jax.tree_util.tree_map(lambda a: a[-1], params_all)
-        # tuple-of-folds output keeps the same contract as the unrolled path
+        # one prediction a fold; the final stage's is not an output
         return p_final, losses_all[-1], tuple(preds_all[k] for k in range(n_folds))
 
     in_axes: Tuple = (0, 0, 0)
@@ -526,55 +535,95 @@ def _bucket_program(
     return jax.jit(batched)
 
 
-def _bucket_program_unrolled(
-    spec: ModelSpec,
-    n_rows: int,
-    fold_bounds: Tuple[Tuple[int, int, int], ...],
-    epochs: int,
-    batch_size: int,
-    shuffle: bool,
-    scale_x: bool,
-    out_sharding=None,
-    warm_start: bool = False,
-):
-    """Fallback bucket program with one separately-shaped fit per fold
-    (pre-fused structure); only used when fold test slices are unequal."""
-    n_full = n_train_samples(spec, n_rows)
-    fit_full = make_scanned_fit(spec, n_full, batch_size, epochs, shuffle)
-    fold_fits = [
-        make_scanned_fit(
-            spec, n_train_samples(spec, tr_end), batch_size, epochs, shuffle
+def _fold_geometry(
+    plan: _Plan, n_rows: int
+) -> Tuple[
+    Tuple[Tuple[int, int, int], ...],
+    Optional[List[Tuple[np.ndarray, np.ndarray]]],
+    Optional[np.ndarray],
+]:
+    """The fold geometry of a bucket, from its first machine's plan and row
+    count: ``(fold_bounds, kfold_folds, perms)``.
+
+    ``fold_bounds`` is ``(train_end, test_start, test_end)`` a fold, every
+    test slice of one length (what ``_bucket_program`` needs). For
+    ``TimeSeriesSplit`` they are sklearn's own and the other two are None.
+    For seeded shuffled-KFold geometry (KFCV plans) the exact sklearn fold
+    assignment is computed on host — identical to the serial detector's —
+    as ``kfold_folds`` (train and test index arrays a fold) and expressed
+    as ``perms``, per-stage row permutations [train..., test...] (the last,
+    for the final fit, the identity), so the program keeps static
+    train-prefix / test-tail shapes. The bounds then pad every fold's test
+    slice to the largest fold; assembly discards the padded leading rows.
+    """
+    spec = plan.spec
+    kfold_folds = None
+    perms = None
+    if plan.cv[0] == "kfold":
+        _, n_splits, shuffle, seed = plan.cv
+        splitter = KFold(
+            n_splits=n_splits, shuffle=shuffle,
+            random_state=seed if shuffle else None,
         )
-        for tr_end, _, _ in fold_bounds
-    ]
+        kfold_folds = list(splitter.split(np.zeros((n_rows, 1))))
+        te_max = max(len(te) for _, te in kfold_folds)
+        fold_bounds = tuple(
+            (len(tr), n_rows - te_max, n_rows) for tr, _ in kfold_folds
+        )
+        perms = np.stack(
+            [np.concatenate([tr, te]) for tr, te in kfold_folds]
+            + [np.arange(n_rows)]
+        ).astype(np.int32)
+    else:
+        splitter = TimeSeriesSplit(n_splits=plan.n_splits)
+        fold_bounds = tuple(
+            (int(tr[-1]) + 1, int(te[0]), int(te[-1]) + 1)
+            for tr, te in splitter.split(np.zeros((n_rows, 1)))
+        )
 
-    def one_machine(X, y, seed, *extra):
-        warm = extra[0] if warm_start else None
-        rng = jax.random.fold_in(jax.random.PRNGKey(0), seed)
-        fold_preds = []
-        for k, (tr_end, te_start, te_end) in enumerate(fold_bounds):
-            k_init, k_fit = jax.random.split(jax.random.fold_in(rng, k))
-            Xtr, ytr = X[:tr_end], y[:tr_end]
-            Xte = X[te_start:te_end]
-            if scale_x:
-                Xte = _minmax(Xtr, Xte)
-                Xtr = _minmax(Xtr, Xtr)
-            p0 = warm if warm_start else init_model_params(k_init, spec)
-            p, _ = fold_fits[k](p0, Xtr, ytr, k_fit)
-            fold_preds.append(_predict_windows(spec, p, Xte))
+    # every CV fold must yield at least one training sample, mirroring the
+    # serial path's explicit error (ops/train.py fit_arrays)
+    for tr_end, _, _ in fold_bounds:
+        if n_train_samples(spec, tr_end) <= 0:
+            raise ValueError(
+                f"CV fold with {tr_end} rows yields no training samples for "
+                f"lookback_window={spec.lookback_window} "
+                f"lookahead={spec.lookahead} "
+                f"(the bucket of machine {plan.machine.name})"
+            )
+    return fold_bounds, kfold_folds, perms
 
-        k_init, k_fit = jax.random.split(jax.random.fold_in(rng, len(fold_bounds)))
-        Xs = _minmax(X, X) if scale_x else X
-        p0 = warm if warm_start else init_model_params(k_init, spec)
-        p_final, losses = fit_full(p0, Xs, y, k_fit)
-        return p_final, losses, tuple(fold_preds)
 
-    batched = jax.vmap(
-        one_machine, in_axes=(0, 0, 0, 0) if warm_start else (0, 0, 0)
+def _program_for(
+    plan: _Plan, n_rows: int, fold_bounds, use_perms: bool, out_sharding
+):
+    """The compiled-once chunk program of the bucket whose first machine is
+    ``plan``: ``(program, key, cached)``, with the program cache's
+    effectiveness counted — a hit reuses an already-traced program, and its
+    remembered first-compile wall (``_first_compile_walls[key]``, which the
+    chunk loop notes after a miss's first dispatch) is credited as time
+    saved."""
+    key = (
+        plan.spec,
+        n_rows,
+        fold_bounds,
+        plan.epochs,
+        plan.batch_size,
+        plan.shuffle,
+        plan.scale_x,
+        out_sharding,
+        use_perms,
+        plan.warm_params is not None,
     )
-    if out_sharding is not None:
-        return jax.jit(batched, out_shardings=out_sharding)
-    return jax.jit(batched)
+    hits_before = _bucket_program.cache_info().hits
+    program = _bucket_program(*key)
+    cached = _bucket_program.cache_info().hits > hits_before
+    metric_catalog.PROGRAM_CACHE.labels(result="hit" if cached else "miss").inc()
+    if cached:
+        saved = _first_compile_walls.get(key)
+        if saved:
+            metric_catalog.COMPILE_SECONDS_SAVED.inc(saved)
+    return program, key, cached
 
 
 # ------------------------------------------------- vectorized fold metrics
@@ -682,8 +731,8 @@ class BatchedModelBuilder:
         combine with ``distributed.initialize``); coordination is purely
         the shared filesystem. Default from ``$GORDO_TPU_ELASTIC``.
         ``scheduler_policy="static"`` keeps the queue's nominal partition
-        with no stealing (the measured baseline for the fleet_build
-        bench). ``host_rank``/``num_hosts`` default to
+        with no stealing (the baseline stealing is compared
+        against). ``host_rank``/``num_hosts`` default to
         ``$GORDO_TPU_PROCESS_ID``/``$GORDO_TPU_NUM_PROCESSES``.
 
         ``warm_start``: when a machine's full cache key misses but its
@@ -719,7 +768,7 @@ class BatchedModelBuilder:
         self.host_rank = host_rank
         self.num_hosts = num_hosts
         # the live ElasticScheduler of the current/most recent elastic
-        # build(): tests and the fleet_build bench read its stats
+        # build(): tests read its stats
         self.scheduler = None
         # fault-domain outcome of the last build(): Machine objects whose
         # BuildMetadata.fault_domain records stage/reason, plus the raw
@@ -727,6 +776,10 @@ class BatchedModelBuilder:
         self.quarantined: List[Machine] = []
         self.quarantine_records: List[QuarantineRecord] = []
         self._quarantined_names: set = set()
+        # how this build quarantines a machine lost before training (to its
+        # fetch, or to its serial build): plainly, or claim-gated in the
+        # elastic build. Fixed once a build(), called as (machine, record)
+        self._quarantine_lost = self._quarantine
         # machines (by index) that arrived with another shape than the
         # bucket they were grouped into before their data was there;
         # _build_all buckets them again once that bucket has ended
@@ -812,41 +865,28 @@ class BatchedModelBuilder:
         plan.warm_params = self._maybe_warm_params(plan.machine, plan.spec)
         return None
 
-    def _arrived(self, plan: _Plan, quarantine=None) -> bool:
+    def _arrived(self, plan: _Plan) -> bool:
         """Block until the machine's fetch has ended and say whether its
         data is there to build from. The first call takes the fetch's
         outcome — under ``fail_fast`` that raises the machine's own
         exception, here on the build thread; otherwise a failed machine is
-        handed to ``quarantine(machine, record)`` — and a later call (a
-        bucket retried or bisected) only answers: the provider is asked
-        once a machine."""
+        quarantined as this build quarantines (``_quarantine_lost``) — and
+        a later call (a bucket retried or bisected) only answers: the
+        provider is asked once a machine."""
         pending, plan.fetch = plan.fetch, None
         if pending is not None:
             record = pending.result()
             if record is not None:
-                (quarantine or self._quarantine_record)(plan.machine, record)
+                self._quarantine_lost(plan.machine, record)
             elif plan.warm_params is not None:
                 metric_catalog.WARM_STARTS.inc()
         return plan.machine.name not in self._quarantined_names
 
     # -------------------------------------------------------- quarantine
-    def _quarantine_record(self, machine: Machine, record) -> None:
-        self._quarantine(machine, record=record)
-
-    def _quarantine(
-        self,
-        machine: Machine,
-        stage: str = "",
-        reason: str = "",
-        error: str = "",
-        attempts: int = 1,
-        record: Optional[QuarantineRecord] = None,
-    ) -> None:
+    def _quarantine(self, machine: Machine, record: QuarantineRecord) -> None:
         """Drop one machine from the build, recording why. The machine's
         reasons land in a fresh ``BuildMetadata.fault_domain`` (the fleet
         analog of a crashed pod's termination message)."""
-        if record is None:
-            record = QuarantineRecord(machine.name, stage, reason, error, attempts)
         logger.error(
             "Machine %s QUARANTINED at %s (%s): %s",
             record.machine, record.stage, record.reason, record.error,
@@ -888,6 +928,7 @@ class BatchedModelBuilder:
         self.quarantined = []
         self.quarantine_records = []
         self._quarantined_names = set()
+        self._quarantine_lost = self._quarantine
         self._set_aside = {}
         self.compile_failures = []
         with maybe_profile("batched-build"):
@@ -1020,105 +1061,122 @@ class BatchedModelBuilder:
         except Exception:  # noqa: BLE001 — same rationale as above
             return None
 
-    def _build_all(self, distributed) -> List[Tuple[Any, Machine]]:
-        if self.elastic:
-            return self._build_all_elastic(distributed)
+    def _plan_fleet(
+        self, owns_hit
+    ) -> Tuple[Dict[int, Tuple[Any, Machine]], Dict[int, _Plan], List[int]]:
+        """The plan stage of both orchestrators: what this process returns
+        from the cache, the batchable machines' plans, and the machines
+        only the serial builder can build (all by global index).
+
+        Resume prefilter: registry lookups (cheap) run threaded for the
+        whole fleet, and each hit is returned by exactly one process — the
+        one for which ``owns_hit(machine)`` is true: the hash partition in
+        the static build, whoever claims the hit first in the elastic one.
+        The owner unpickles and returns it, the others skip it entirely.
+        Ownership goes by the machine, never by its position in the locally
+        observed hit list: registries can drift between processes
+        (overlapping builds registering keys mid-prefilter), and
+        position-keyed ownership would then double- or zero-own a machine.
+        """
         results: Dict[int, Tuple[Any, Machine]] = {}
         plans: Dict[int, _Plan] = {}
         serial: List[int] = []
 
-        with _stage("plan", machines=len(self.machines)):
-            # resume prefilter. Registry lookups (cheap) run threaded for the
-            # whole fleet, and each hit is OWNED by exactly one process — keyed
-            # by the machine's GLOBAL index, not its position in the locally
-            # observed hit list: registries can drift between processes
-            # (overlapping builds registering keys mid-prefilter), and
-            # position-keyed ownership would then double- or zero-own a machine.
-            # The owner unpickles and returns it, the others skip it entirely.
-            cached_results: Dict[int, Tuple[Any, Machine]] = {}
-            foreign_cached: set = set()
-            if self.model_register_dir and self.machines:
-                idxs = list(range(len(self.machines)))
-                with ThreadPoolExecutor(max_workers=min(16, len(idxs))) as pool:
-                    paths = list(
-                        pool.map(lambda i: self._cached_path(self.machines[i]), idxs)
+        hits: Dict[int, str] = {}
+        if self.model_register_dir and self.machines:
+            with ThreadPoolExecutor(
+                max_workers=min(16, len(self.machines))
+            ) as pool:
+                paths = pool.map(self._cached_path, self.machines)
+                hits = {i: path for i, path in enumerate(paths) if path}
+        owned = {
+            i: path for i, path in hits.items() if owns_hit(self.machines[i])
+        }
+        loaded: Dict[int, Optional[Tuple[Any, Machine]]] = {}
+        if owned:
+            with ThreadPoolExecutor(max_workers=min(16, len(owned))) as pool:
+                loaded = dict(
+                    zip(
+                        owned,
+                        pool.map(self._load_cached_guarded, owned, owned.values()),
                     )
-                owned_hits = []
-                for i, path in zip(idxs, paths):
-                    if not path:
-                        continue
-                    if distributed.owns_serial_machine(
-                        _machine_seed(self.machines[i])
-                    ):
-                        owned_hits.append((i, path))
-                    else:
-                        foreign_cached.add(i)
-                if owned_hits:
-                    with ThreadPoolExecutor(
-                        max_workers=min(16, len(owned_hits))
-                    ) as pool:
-                        loaded = pool.map(
-                            lambda ip: self._load_cached_guarded(*ip), owned_hits
-                        )
-                        cached_results = {
-                            i: c
-                            for (i, _), c in zip(owned_hits, loaded)
-                            if c is not None
-                        }
+                )
 
-            for i, machine in enumerate(self.machines):
-                if i in foreign_cached:
-                    continue  # cached; another process owns and returns it
-                if i in cached_results:
-                    cached = cached_results[i]
-                    logger.info("Machine %s: loaded from cache", machine.name)
-                    metric_catalog.BUILD_MACHINES.labels(outcome="cached").inc()
-                    results[i] = cached
-                    model_dir = self._machine_output_dir(machine.name)
-                    if model_dir and not os.path.exists(
-                        os.path.join(model_dir, "model.pkl")
-                    ):
-                        # cache hit from a previous run's output_dir; materialize
-                        # the artifact in this run's tree too
-                        self._persist(machine, *cached)
-                    continue
-                plan = _plan_machine(machine)
-                if plan is None:
-                    serial.append(i)
-                else:
-                    plans[i] = plan
-
-            # ownership keyed by a stable hash of the machine name (same rule as
-            # the cached-hit loop above): the serial list's composition depends
-            # on local cache state, so list-POSITION ownership could diverge
-            # between processes, while raw global indices could concentrate load
-            # on one process when unbatchable machines land on a stride
-            for i in serial:
-                if not self.serial_fallback:
-                    raise ValueError(
-                        f"Machine {self.machines[i].name} is not batchable and "
-                        f"serial_fallback=False"
-                    )
-                if not distributed.owns_serial_machine(
-                    _machine_seed(self.machines[i])
+        for i, machine in enumerate(self.machines):
+            if i in hits and i not in loaded:
+                continue  # cached; another process owns and returns it
+            cached = loaded.get(i)
+            if cached is not None:
+                logger.info("Machine %s: loaded from cache", machine.name)
+                metric_catalog.BUILD_MACHINES.labels(outcome="cached").inc()
+                results[i] = cached
+                model_dir = self._machine_output_dir(machine.name)
+                if model_dir and not os.path.exists(
+                    os.path.join(model_dir, "model.pkl")
                 ):
+                    # cache hit from a previous run's output_dir; materialize
+                    # the artifact in this run's tree too
+                    self._persist(machine, *cached)
+                continue
+            # no hit, or a corrupt one this process owns (evicted): build it
+            plan = _plan_machine(machine)
+            if plan is None:
+                serial.append(i)
+            else:
+                plans[i] = plan
+
+        if serial and not self.serial_fallback:
+            raise ValueError(
+                f"Machine {self.machines[serial[0]].name} is not batchable "
+                f"and serial_fallback=False"
+            )
+        return results, plans, serial
+
+    def _build_serial(
+        self, machine: Machine, reason: str, stage: str, quarantine
+    ) -> Optional[Tuple[Any, Machine]]:
+        """One machine through the serial ``ModelBuilder``, counted under
+        ``reason``; one whose serial build fails too is handed to
+        ``quarantine(machine, record)`` at ``stage`` and answers None."""
+        logger.info("Machine %s: serial build (%s)", machine.name, reason)
+        metric_catalog.SERIAL_FALLBACKS.labels(reason=reason).inc()
+        try:
+            return ModelBuilder(machine).build(
+                output_dir=self._machine_output_dir(machine.name),
+                model_register_dir=self.model_register_dir,
+            )
+        except Exception as exc:
+            if self.fail_fast:
+                raise
+            quarantine(
+                machine,
+                QuarantineRecord(machine.name, stage, type(exc).__name__, str(exc)),
+            )
+            return None
+
+    def _build_all(self, distributed) -> List[Tuple[Any, Machine]]:
+        if self.elastic:
+            return self._build_all_elastic(distributed)
+
+        # ownership keyed by a stable hash of the machine name: the hit and
+        # serial lists' composition depends on local cache state, so
+        # list-POSITION ownership could diverge between processes, while raw
+        # global indices could concentrate load on one process when
+        # unbatchable machines land on a stride
+        def owns(machine: Machine) -> bool:
+            return distributed.owns_serial_machine(_machine_seed(machine))
+
+        with _stage("plan", machines=len(self.machines)):
+            results, plans, serial = self._plan_fleet(owns)
+            for i in serial:
+                if not owns(self.machines[i]):
                     continue
-                logger.info("Machine %s: serial fallback", self.machines[i].name)
-                metric_catalog.SERIAL_FALLBACKS.labels(reason="unbatchable").inc()
-                try:
-                    results[i] = ModelBuilder(self.machines[i]).build(
-                        output_dir=self._machine_output_dir(self.machines[i].name),
-                        model_register_dir=self.model_register_dir,
-                    )
-                except Exception as exc:
-                    if self.fail_fast:
-                        raise
-                    self._quarantine(
-                        self.machines[i],
-                        stage=faults.STAGE_SERIAL_BUILD,
-                        reason=type(exc).__name__,
-                        error=str(exc),
-                    )
+                built = self._build_serial(
+                    self.machines[i], "unbatchable",
+                    faults.STAGE_SERIAL_BUILD, self._quarantine_lost,
+                )
+                if built is not None:
+                    results[i] = built
 
         # the fetch is a stream that the chunk loop consumes: a bucket's
         # first chunk is dispatched when its own machines have arrived, and
@@ -1126,20 +1184,24 @@ class BatchedModelBuilder:
         # chunk before it (_build_bucket waits for a group's fetches just
         # before it stacks the group). Across processes every one has to
         # agree on each chunk's membership before it is launched, so there
-        # all are fetched and validated first, as ever
-        pool = None
-        if distributed.is_multiprocess():
-            self._fetch_stage(plans, self._quarantine_record)
-            buckets = self._validate_stage(plans)
-        else:
-            buckets = self._validate_stage(plans, fetched=False)
-            pool = ThreadPoolExecutor(
-                max_workers=min(16, max(len(plans), 1)),
-                thread_name_prefix="gordo-fetch",
-            )
+        # the stream is waited out first (_fetch_all)
+        pool = ThreadPoolExecutor(
+            max_workers=min(16, max(len(plans), 1)),
+            thread_name_prefix="gordo-fetch",
+        )
         try:
-            if pool is not None:
-                self._fetch_stage(plans, pool=pool, buckets=buckets)
+            if distributed.is_multiprocess():
+                buckets = self._fetch_all(pool, plans)
+            else:
+                # no data yet: ordered and grouped by the partial key
+                with _stage("validate_stage", machines=len(plans)):
+                    buckets = self._buckets_of(plans)
+                first = next(iter(buckets.values()), [])
+                self._fetch_stage(
+                    pool,
+                    [plans[i] for idxs in buckets.values() for i in idxs],
+                    min(len(first), self._chunk_width(len(first))),
+                )
             while buckets:
                 for idxs in buckets.values():
                     bucket_plans = [plans[i] for i in idxs]
@@ -1151,19 +1213,16 @@ class BatchedModelBuilder:
                 plans, self._set_aside = self._set_aside, {}
                 buckets = self._buckets_of(plans)
         finally:
-            if pool is not None:
-                # nothing is pending unless the build is being abandoned
-                pool.shutdown(wait=True, cancel_futures=True)
+            # nothing is pending unless the build is being abandoned
+            pool.shutdown(wait=True, cancel_futures=True)
 
         return [results[i] for i in sorted(results)]
 
     @staticmethod
-    def _buckets_of(
-        plans: Dict[int, _Plan], fetched: bool = True
-    ) -> Dict[Tuple, List[int]]:
+    def _buckets_of(plans: Dict[int, _Plan]) -> Dict[Tuple, List[int]]:
         buckets: Dict[Tuple, List[int]] = {}
         for i, plan in plans.items():
-            buckets.setdefault(plan.bucket_key(fetched), []).append(i)
+            buckets.setdefault(plan.bucket_key(), []).append(i)
         return buckets
 
     def _chunk_width(self, n_machines: int) -> int:
@@ -1174,56 +1233,44 @@ class BatchedModelBuilder:
         n_dev = int(np.prod(list(self.mesh.shape.values())))
         return ((min(self.chunk_size, n_machines) + n_dev - 1) // n_dev) * n_dev
 
-    def _submit_fetches(self, pool: ThreadPoolExecutor, plans) -> None:
-        for plan in plans:
-            plan.fetch = pool.submit(self._fetch_machine, plan)
-
     def _fetch_stage(
-        self, plans: Dict[int, _Plan], quarantine=None, pool=None, buckets=None
+        self, pool: ThreadPoolExecutor, order: List[_Plan], n_first: int
     ) -> None:
-        """Fetch every planned machine's data concurrently (provider I/O is
-        the per-machine serial cost the reference paid per pod), each with
-        its non-finite check and warm params on the same thread. A fetch
-        retries transient faults with backoff; on exhaustion the machine is
-        quarantined — one dead sensor feed degrades one machine, not the
-        fleet (the blast radius the reference got from one-pod-per-machine).
+        """Start the fetch of every planned machine on the caller's
+        ``pool`` (provider I/O is the per-machine serial cost the reference
+        paid per pod), each with its non-finite check and warm params on
+        the same thread, and return when the first ``n_first`` of ``order``
+        have ended. Those have the threads to themselves — nothing is
+        dispatched before they are there — and the rest are submitted as
+        the stage ends, in the order the chunks will consume them.
 
-        As a barrier (no ``pool``): returns when every fetch has ended,
-        with the failed machines handed to ``quarantine(machine, record)``
-        and dropped from ``plans``. As a stream (the caller's ``pool``,
-        which outlives the stage): submits the machines in the order the
-        ``buckets``' chunks will consume them and returns when the first
-        chunk's have arrived; ``_build_bucket`` takes each group's outcomes.
-        """
-        with _stage("fetch_stage", machines=len(plans)):
-            if not plans:
-                return
-            if pool is None:
-                with ThreadPoolExecutor(max_workers=min(16, len(plans))) as pool:
-                    self._submit_fetches(pool, plans.values())
-                for i in list(plans):
-                    if not self._arrived(plans[i], quarantine):
-                        del plans[i]
-                return
-            order = [plans[i] for idxs in buckets.values() for i in idxs]
-            n_first = len(next(iter(buckets.values())))
-            n_first = min(n_first, self._chunk_width(n_first))
-            # the first chunk's machines have the threads to themselves:
-            # nothing is dispatched before they are there
-            self._submit_fetches(pool, order[:n_first])
-            wait_for_futures([plan.fetch for plan in order[:n_first]])
-            self._submit_fetches(pool, order[n_first:])
+        A fetch retries transient faults with backoff; on exhaustion the
+        machine is quarantined — one dead sensor feed degrades one machine,
+        not the fleet (the blast radius the reference got from
+        one-pod-per-machine). Whoever waits for a machine takes its outcome
+        (``_arrived``)."""
+        with _stage("fetch_stage", machines=len(order)):
+            first, rest = order[:n_first], order[n_first:]
+            for plan in first:
+                plan.fetch = pool.submit(self._fetch_machine, plan)
+            wait_for_futures([plan.fetch for plan in first])
+            for plan in rest:
+                plan.fetch = pool.submit(self._fetch_machine, plan)
 
-    def _validate_stage(
-        self, plans: Dict[int, _Plan], quarantine=None, fetched: bool = True
+    def _fetch_all(
+        self, pool: ThreadPoolExecutor, plans: Dict[int, _Plan]
     ) -> Dict[Tuple, List[int]]:
-        """What is left of validation on the build thread (the non-finite
-        check runs beside each fetch): the buckets by (spec, shapes,
-        config) — or, with the fetches still to come (``fetched=False``),
-        by what is known without the data. Nothing is quarantined here any
-        more; the elastic build still passes its ``quarantine``."""
+        """The barrier, as a use of the stream: wait for every fetch, take
+        every outcome (a machine lost to its fetch is quarantined and leaves
+        ``plans``), and only then bucket, by the full key. For the builds
+        that have to agree on each chunk's membership before anything is
+        launched: across processes, and every host of an elastic build."""
+        self._fetch_stage(pool, list(plans.values()), len(plans))
         with _stage("validate_stage", machines=len(plans)):
-            return self._buckets_of(plans, fetched)
+            for i in list(plans):
+                if not self._arrived(plans[i]):
+                    del plans[i]
+            return self._buckets_of(plans)
 
     def _build_all_elastic(self, distributed) -> List[Tuple[Any, Machine]]:
         """The work-stealing fleet build (parallel/scheduler.py): every
@@ -1261,9 +1308,6 @@ class BatchedModelBuilder:
                 "queue lives in its _scheduler/ subdir) or scheduler_dir"
             )
 
-        results: Dict[int, Tuple[Any, Machine]] = {}
-        plans: Dict[int, _Plan] = {}
-        serial: List[int] = []
         sched = ElasticScheduler(
             base_dir,
             host_rank=self.host_rank,
@@ -1290,65 +1334,25 @@ class BatchedModelBuilder:
                 "scheduler_dir)",
                 base_dir, n_done,
             )
+        # every host observes the same bad feed or unbuildable machine;
+        # exactly one records it
+        self._quarantine_lost = functools.partial(self._quarantine_claimed, sched)
         try:
             with _stage("plan", machines=len(self.machines)):
-                # resume prefilter, elastic form: full-key registry hits are
-                # claimed exactly once fleet-wide by a done marker instead of
-                # the hash partition — whoever claims first loads and returns
-                # the machine; everyone else drops it entirely
-                cached_paths: Dict[int, str] = {}
-                if self.model_register_dir and self.machines:
-                    idxs = list(range(len(self.machines)))
-                    with ThreadPoolExecutor(max_workers=min(16, len(idxs))) as pool:
-                        paths = list(
-                            pool.map(
-                                lambda i: self._cached_path(self.machines[i]), idxs
-                            )
-                        )
-                    cached_paths = {i: p for i, p in zip(idxs, paths) if p}
+                # a full-key registry hit is claimed exactly once fleet-wide
+                # by a done marker: whoever claims first loads and returns
+                # the machine (or, the artifact corrupt, rebuilds it)
+                results, plans, serial = self._plan_fleet(
+                    lambda machine: sched.try_claim(
+                        unit_id_for([machine.name], "cached"),
+                        {"machine": machine.name},
+                    )
+                )
 
-                for i, machine in enumerate(self.machines):
-                    if i in cached_paths:
-                        if not sched.try_claim(
-                            unit_id_for([machine.name], "cached"),
-                            {"machine": machine.name},
-                        ):
-                            continue  # a peer claimed and returns this hit
-                        cached = self._load_cached_guarded(i, cached_paths[i])
-                        if cached is not None:
-                            logger.info(
-                                "Machine %s: loaded from cache", machine.name
-                            )
-                            metric_catalog.BUILD_MACHINES.labels(
-                                outcome="cached"
-                            ).inc()
-                            results[i] = cached
-                            model_dir = self._machine_output_dir(machine.name)
-                            if model_dir and not os.path.exists(
-                                os.path.join(model_dir, "model.pkl")
-                            ):
-                                self._persist(machine, *cached)
-                            continue
-                        # corrupt artifact: we hold the claim; rebuild below
-                    plan = _plan_machine(machine)
-                    if plan is None:
-                        serial.append(i)
-                    else:
-                        plans[i] = plan
-
-                for i in serial:
-                    if not self.serial_fallback:
-                        raise ValueError(
-                            f"Machine {self.machines[i].name} is not batchable "
-                            f"and serial_fallback=False"
-                        )
-
-            # data fetch + validation: the static build's stages, except that
-            # quarantines are claim-gated — every host observes the same bad
-            # feed, exactly one records it
-            quarantine = functools.partial(self._quarantine_claimed, sched)
-            self._fetch_stage(plans, quarantine)
-            buckets = self._validate_stage(plans, quarantine)
+            with ThreadPoolExecutor(
+                max_workers=min(16, max(len(plans), 1))
+            ) as pool:
+                buckets = self._fetch_all(pool, plans)
 
             units: Dict[str, WorkUnit] = {}
             members: Dict[str, Tuple[str, List[int]]] = {}
@@ -1392,7 +1396,11 @@ class BatchedModelBuilder:
                 )
                 kind, idxs = members[lease.unit.unit_id]
                 if kind == "serial":
-                    built_list = self._build_serial_elastic(sched, idxs[0])
+                    built = self._build_serial(
+                        self.machines[idxs[0]], "unbatchable",
+                        faults.STAGE_SERIAL_BUILD, self._quarantine_lost,
+                    )
+                    built_list = [(idxs[0], built)] if built is not None else []
                 else:
                     bucket_plans = [plans[i] for i in idxs]
                     built_list = self._build_bucket_guarded(bucket_plans, idxs)
@@ -1423,44 +1431,9 @@ class BatchedModelBuilder:
         if sched.try_claim(
             unit_id_for([record.machine], "quarantine"), record.to_dict()
         ):
-            self._quarantine(machine, record=record)
+            self._quarantine(machine, record)
         else:
             self._quarantined_names.add(record.machine)
-
-    def _build_serial_elastic(
-        self, sched, i: int
-    ) -> List[Tuple[int, Tuple[Any, Machine]]]:
-        """One leased serial-fallback machine (elastic path)."""
-        machine = self.machines[i]
-        logger.info("Machine %s: serial fallback", machine.name)
-        metric_catalog.SERIAL_FALLBACKS.labels(reason="unbatchable").inc()
-        try:
-            built = ModelBuilder(machine).build(
-                output_dir=self._machine_output_dir(machine.name),
-                model_register_dir=self.model_register_dir,
-            )
-            return [(i, built)]
-        except Exception as exc:
-            if self.fail_fast:
-                raise
-            self._quarantine_claimed(
-                sched,
-                machine,
-                QuarantineRecord(
-                    machine=machine.name,
-                    stage=faults.STAGE_SERIAL_BUILD,
-                    reason=type(exc).__name__,
-                    error=str(exc),
-                ),
-            )
-            return []
-
-    def _fold_bounds(self, n_rows: int, n_splits: int) -> Tuple[Tuple[int, int, int], ...]:
-        splitter = TimeSeriesSplit(n_splits=n_splits)
-        bounds = []
-        for train_idx, test_idx in splitter.split(np.zeros((n_rows, 1))):
-            bounds.append((int(train_idx[-1]) + 1, int(test_idx[0]), int(test_idx[-1]) + 1))
-        return tuple(bounds)
 
     def _build_bucket_guarded(
         self,
@@ -1561,22 +1534,12 @@ class BatchedModelBuilder:
         also fails is quarantined, never the fleet."""
         out = []
         for i, plan in zip(global_idxs, bucket):
-            metric_catalog.SERIAL_FALLBACKS.labels(
-                reason="bucket_failure"
-            ).inc()
-            try:
-                built = ModelBuilder(plan.machine).build(
-                    output_dir=self._machine_output_dir(plan.machine.name),
-                    model_register_dir=self.model_register_dir,
-                )
+            built = self._build_serial(
+                plan.machine, "bucket_failure", faults.STAGE_TRAINING,
+                self._quarantine,
+            )
+            if built is not None:
                 out.append((i, built))
-            except Exception as exc:
-                self._quarantine(
-                    plan.machine,
-                    stage=faults.STAGE_TRAINING,
-                    reason=type(exc).__name__,
-                    error=str(exc),
-                )
         return out
 
     def _build_bucket(
@@ -1623,87 +1586,18 @@ class BatchedModelBuilder:
                     return []
                 first_group = take(starts[0])
             plan0 = first_group[0][0]
-            spec = plan0.spec
             n_rows = len(plan0.X)
-            kfold_folds: Optional[List[Tuple[np.ndarray, np.ndarray]]] = None
-            perms: Optional[np.ndarray] = None
-            if plan0.cv[0] == "kfold":
-                # seeded shuffled-KFold geometry (KFCV plans): exact sklearn fold
-                # assignment computed on host — identical to the serial
-                # detector's — expressed as per-stage row permutations
-                # [train..., test...] so the program keeps static train-prefix /
-                # test-tail shapes. Bounds pad every fold's test slice to the
-                # largest fold; assembly discards the padded leading rows.
-                _, n_sp, shuffle_cv, seed_cv = plan0.cv
-                splitter = KFold(
-                    n_splits=n_sp, shuffle=shuffle_cv,
-                    random_state=seed_cv if shuffle_cv else None,
-                )
-                kfold_folds = [
-                    (tr, te) for tr, te in splitter.split(np.zeros((n_rows, 1)))
-                ]
-                te_max = max(len(te) for _, te in kfold_folds)
-                fold_bounds = tuple(
-                    (len(tr), n_rows - te_max, n_rows) for tr, _ in kfold_folds
-                )
-                perms = np.stack(
-                    [np.concatenate([tr, te]) for tr, te in kfold_folds]
-                    + [np.arange(n_rows)]
-                ).astype(np.int32)
-            else:
-                fold_bounds = self._fold_bounds(n_rows, plan0.n_splits)
-
-            # every CV fold must yield at least one training sample, mirroring the
-            # serial path's explicit error (ops/train.py fit_arrays)
-            for tr_end, _, _ in fold_bounds:
-                if n_train_samples(spec, tr_end) <= 0:
-                    raise ValueError(
-                        f"CV fold with {tr_end} rows yields no training samples for "
-                        f"lookback_window={spec.lookback_window} "
-                        f"lookahead={spec.lookahead} "
-                        f"(machines: {[p.machine.name for p in bucket]})"
-                    )
+            fold_bounds, kfold_folds, perms = _fold_geometry(plan0, n_rows)
 
             from gordo_tpu.parallel import distributed
 
             multiprocess = distributed.is_multiprocess()
             warm = plan0.warm_params is not None
             sharding = machines_sharding(self.mesh)
-            program_key = (
-                spec,
-                n_rows,
-                fold_bounds,
-                plan0.epochs,
-                plan0.batch_size,
-                plan0.shuffle,
-                plan0.scale_x,
+            program, program_key, program_cached = _program_for(
+                plan0, n_rows, fold_bounds, perms is not None,
                 sharding if multiprocess else None,
-                perms is not None,
-                warm,
             )
-            cache_before = _bucket_program.cache_info()
-            program = _bucket_program(
-                spec,
-                n_rows,
-                fold_bounds,
-                plan0.epochs,
-                plan0.batch_size,
-                plan0.shuffle,
-                plan0.scale_x,
-                out_sharding=sharding if multiprocess else None,
-                use_perms=perms is not None,
-                warm_start=warm,
-            )
-            # program-cache effectiveness: a hit reuses an already-compiled
-            # program; credit its remembered first-compile wall as time saved
-            program_cached = _bucket_program.cache_info().hits > cache_before.hits
-            metric_catalog.PROGRAM_CACHE.labels(
-                result="hit" if program_cached else "miss"
-            ).inc()
-            if program_cached:
-                saved = _first_compile_walls.get(program_key)
-                if saved:
-                    metric_catalog.COMPILE_SECONDS_SAVED.inc(saved)
             perms_d = None
             if perms is not None:
                 from jax.sharding import NamedSharding, PartitionSpec
@@ -1836,9 +1730,10 @@ class BatchedModelBuilder:
                             )
                         self._quarantine(
                             plan.machine,
-                            stage=faults.STAGE_TRAINING,
-                            reason="diverged",
-                            error=bad,
+                            QuarantineRecord(
+                                plan.machine.name, faults.STAGE_TRAINING,
+                                "diverged", bad,
+                            ),
                         )
                         continue
                     futures.append(

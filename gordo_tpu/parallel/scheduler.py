@@ -40,8 +40,7 @@ host count). Leasing your own share counts as ``kind="fresh"``; leasing a
 peer's share — because you drained yours early, or their lease expired —
 counts as ``kind="steal"`` (``gordo_build_scheduler_leases_total``).
 ``policy="static"`` restricts every host to its nominal share with no
-stealing: the measured baseline the bench's ``fleet_build`` section
-compares elastic mode against.
+stealing: the baseline elastic mode is compared against.
 
 Host death is injectable for the chaos suite: the builder fires the
 ``scheduler_lease`` fault site as each lease activates, and a fault-plan

@@ -1,8 +1,7 @@
 """
 Numpy-native serving codec: the hot-path decode/encode fast lane.
 
-BENCH_r05 measured the anomaly-POST p50 at 9.6 ms against a 0.007 ms
-device/d2h floor — >90% of serving latency was host-side JSON→pandas→JSON
+Most of an anomaly POST's latency used to be host-side JSON→pandas→JSON
 work, not compute. This module short-circuits that work for the canonical
 request/response shapes while guaranteeing **byte-identical JSON** to the
 pandas path (asserted by tests/gordo_tpu/test_fast_codec.py):

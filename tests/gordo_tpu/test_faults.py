@@ -228,7 +228,7 @@ def test_params_non_finite():
     assert "parameters" in faults.params_non_finite(bad)
 
 
-def test_quarantine_record_to_dict():
+def test_quarantined_machines_record_to_dict():
     record = QuarantineRecord(
         machine="m", stage="data_fetch", reason="permanent_fetch_failure",
         error="boom", attempts=3,
@@ -238,7 +238,7 @@ def test_quarantine_record_to_dict():
     assert d["machine"] == "m" and d["attempts"] == 3
 
 
-def test_quarantine_record_attributes_observing_host(monkeypatch):
+def test_quarantined_machines_record_attributes_observing_host(monkeypatch):
     """A merged pod-scale quarantine report must say WHICH host observed
     each fault: host/process_index ride along in every record."""
     monkeypatch.setenv("GORDO_TPU_HOST_ID", "host-east-3")
@@ -250,7 +250,7 @@ def test_quarantine_record_attributes_observing_host(monkeypatch):
     assert d["process_index"] == 3
 
 
-def test_quarantine_record_attribution_defaults(monkeypatch):
+def test_quarantined_machines_record_attribution_defaults(monkeypatch):
     """Without the env knobs the attribution still resolves: hostname-pid
     and the live jax process index (0 in a single-process world)."""
     monkeypatch.delenv("GORDO_TPU_HOST_ID", raising=False)

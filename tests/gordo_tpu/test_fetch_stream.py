@@ -4,7 +4,6 @@ data is fetched under the device, and a machine lost to its fetch, or one
 that arrives with another shape, ends as it did behind the old barrier."""
 
 import collections
-import concurrent.futures
 import json
 import os
 import pickle
@@ -122,17 +121,15 @@ def _open_gate_at_first_launch(monkeypatch):
 
 
 def _fetch_everything_first(monkeypatch):
-    """The old barrier: every fetch has ended before the first dispatch."""
+    """The old barrier: every fetch has ended before the first dispatch.
+    The stage waits for the first ``n`` of its machines; here for all."""
     stage = BatchedModelBuilder._fetch_stage
 
-    def barrier(self, plans, quarantine=None, pool=None, buckets=None):
-        stage(self, plans, quarantine, pool=pool, buckets=buckets)
-        concurrent.futures.wait(
-            [p.fetch for p in plans.values() if p.fetch is not None]
-        )
-        assert all(p.fetch is None or p.fetch.done() for p in plans.values())
+    def wait_for_all(self, pool, order, n_first):
+        stage(self, pool, order, len(order))
+        assert all(plan.fetch.done() for plan in order)
 
-    monkeypatch.setattr(BatchedModelBuilder, "_fetch_stage", barrier)
+    monkeypatch.setattr(BatchedModelBuilder, "_fetch_stage", wait_for_all)
 
 
 def _artifact(model, machine):
@@ -238,6 +235,48 @@ def test_machine_lost_in_a_later_chunk_is_quarantined_alone(
     assert _persisted(tmp_path) == others  # and no padding lane
     [record] = builder.quarantine_records
     assert (record.machine, record.stage, record.reason) == (lost, stage, reason)
+
+
+@pytest.mark.parametrize("fault", sorted(LOST))
+def test_barrier_is_the_stream_waited_out_and_quarantines_alike(
+    fault, monkeypatch, tmp_path
+):
+    """Where every fetch is waited for and every outcome taken before
+    anything is bucketed (``_fetch_all``: the elastic build here), a machine
+    lost to its fetch gets the record the stream gives it."""
+    rule, (stage, reason), _ = LOST[fault]
+    prefix = f"all-{fault.replace('_', '-')}"
+    lost = f"{prefix}-{LATE}"
+    _set_plan(monkeypatch, [dict(rule, machine=lost)])
+    waited_out = []
+    fetch_all = BatchedModelBuilder._fetch_all
+
+    def spy(self, pool, plans):
+        buckets = fetch_all(self, pool, plans)
+        # nothing is in flight, the lost machine is out, the key is full
+        waited_out.append(sorted(p.machine.name for p in plans.values()))
+        assert all(p.fetch is None and p.X is not None for p in plans.values())
+        assert set(buckets) == {p.bucket_key() for p in plans.values()}
+        return buckets
+
+    monkeypatch.setattr(BatchedModelBuilder, "_fetch_all", spy)
+    records = {}
+    for mode in ("streamed", "waited_out"):
+        faults.reset_plan()
+        builder = _builder(
+            _machines(prefix), tmp_path / mode, elastic=mode == "waited_out"
+        )
+        results = builder.build()
+        assert [m.name for _, m in results] == [
+            f"{prefix}-{i}" for i in range(N) if i != LATE
+        ]
+        [record] = builder.quarantine_records
+        records[mode] = record.to_dict()
+    assert waited_out == [[f"{prefix}-{i}" for i in range(N) if i != LATE]]
+    assert records["streamed"] == records["waited_out"]
+    assert (records["streamed"]["stage"], records["streamed"]["reason"]) == (
+        stage, reason,
+    )
 
 
 @pytest.mark.parametrize("fault", sorted(LOST))

@@ -1,4 +1,4 @@
-"""Analytic FLOPs/MFU accounting (ops/flops.py) — the bench's MFU inputs."""
+"""Analytic FLOPs/MFU accounting (ops/flops.py) — the serving MFU gauge's inputs."""
 
 import jax
 import jax.numpy as jnp
@@ -68,24 +68,6 @@ def test_forward_flops_scale_with_window_and_width():
     assert (
         flops_mod.forward_flops_per_sample(tr64)
         > 4.0 * flops_mod.forward_flops_per_sample(tr16)
-    )
-
-
-def test_cv_build_flops_composition():
-    """3 folds + final fit, training 3x forward, remat 4x."""
-    spec = _spec(AutoEncoder(kind="feedforward_hourglass"))
-    fwd = flops_mod.forward_flops_per_sample(spec)
-    total = flops_mod.cv_build_flops(spec, n_rows=400, epochs=2, n_splits=3)
-    # train work: folds of 100/200/300 rows + full 400, 2 epochs, 3x fwd;
-    # predict work: 3 x 100-row fold predictions
-    expected = 3 * fwd * (100 + 200 + 300 + 400) * 2 + fwd * 300
-    np.testing.assert_allclose(total, expected, rtol=1e-9)
-
-    import dataclasses
-
-    remat = dataclasses.replace(spec, remat=True)
-    assert flops_mod.training_flops_per_sample(remat) == pytest.approx(
-        4 / 3 * flops_mod.training_flops_per_sample(spec)
     )
 
 

@@ -12,10 +12,6 @@ Tier-1 lint gates.
   docs/ or README.md (scripts/lint_env_knobs.py): the knob count has
   outgrown anyone's memory, and an undocumented knob is undiscoverable
   at exactly the moment an operator needs it.
-- Every ``BENCH_r*.json`` record conforms to the schema-v2 harness
-  contract (scripts/lint_bench_record.py): all canonical sections
-  present with an explicit status, summary metrics number-or-null —
-  the round-4/5 "bench ran, record useless" postmortems made checkable.
 - Every ``gordo_*`` metric a generated Grafana dashboard plots exists in
   a metrics catalog (lint_metric_names.py --dashboards): a panel keyed
   on a renamed metric renders empty silently. Plus a tiny-budget fleet
@@ -37,7 +33,6 @@ REPO_ROOT = pathlib.Path(__file__).resolve().parents[2]
 LINT = REPO_ROOT / "scripts" / "lint_bare_except.py"
 METRIC_LINT = REPO_ROOT / "scripts" / "lint_metric_names.py"
 KNOB_LINT = REPO_ROOT / "scripts" / "lint_env_knobs.py"
-RECORD_LINT = REPO_ROOT / "scripts" / "lint_bench_record.py"
 MANIFEST_LINT = REPO_ROOT / "scripts" / "lint_artifact_manifest.py"
 SCENARIO_LINT = REPO_ROOT / "scripts" / "lint_chaos_scenario.py"
 
@@ -228,84 +223,6 @@ def test_metric_lint_catalog_coverage(tmp_path):
     assert result.returncode == 1
     assert "gordo_orphan_total" in result.stdout
     assert "gordo_plotted_total" not in result.stdout
-
-
-# -------------------------------------------------- bench-record lint
-def _run_record_lint(*args):
-    return subprocess.run(
-        [sys.executable, str(RECORD_LINT), *map(str, args)],
-        cwd=str(REPO_ROOT),
-        capture_output=True,
-        text=True,
-    )
-
-
-def _write_record(tmp_path, name, parsed):
-    path = tmp_path / name
-    path.write_text(json.dumps({"n": 99, "rc": 0, "parsed": parsed}))
-    return path
-
-
-def _valid_parsed():
-    if str(REPO_ROOT) not in sys.path:
-        sys.path.insert(0, str(REPO_ROOT))
-    import bench
-
-    return {
-        "schema_version": bench.RECORD_SCHEMA_VERSION,
-        "metric": "m",
-        "unit": "machines/min",
-        "platform": "cpu",
-        "value": 123.0,
-        "server_samples_per_sec": None,
-        "sections": {name: "completed" for name in bench.SECTION_NAMES},
-    }
-
-
-def test_bench_record_lint_checked_in_records_pass():
-    """The default invocation (what tier-1 runs): every checked-in record
-    is valid or legacy — a future round committing a malformed record
-    fails the suite."""
-    result = _run_record_lint()
-    assert result.returncode == 0, result.stdout + result.stderr
-
-
-def test_bench_record_lint_accepts_valid_schema_v2(tmp_path):
-    good = _write_record(tmp_path, "BENCH_r90.json", _valid_parsed())
-    result = _run_record_lint(good)
-    assert result.returncode == 0, result.stdout + result.stderr
-
-
-def test_bench_record_lint_flags_unaccounted_section(tmp_path):
-    parsed = _valid_parsed()
-    del parsed["sections"]["windowed"]
-    bad = _write_record(tmp_path, "BENCH_r91.json", parsed)
-    result = _run_record_lint(bad)
-    assert result.returncode == 1
-    assert "windowed" in result.stdout and "unaccounted" in result.stdout
-
-
-def test_bench_record_lint_flags_unknown_status_and_bad_types(tmp_path):
-    parsed = _valid_parsed()
-    parsed["sections"]["headline"] = "exploded"  # not in the vocabulary
-    parsed["value"] = "fast"  # not number-or-null
-    bad = _write_record(tmp_path, "BENCH_r92.json", parsed)
-    result = _run_record_lint(bad)
-    assert result.returncode == 1
-    assert "exploded" in result.stdout
-    assert "parsed.value" in result.stdout
-
-
-def test_bench_record_lint_legacy_skip_and_strict(tmp_path):
-    """Pre-schema records (r01–r05 shape, parsed without schema_version or
-    even parsed: null) are skipped by default and rejected by --strict."""
-    legacy = _write_record(tmp_path, "BENCH_r01.json", {"value": 1.0})
-    lost = tmp_path / "BENCH_r04.json"
-    lost.write_text(json.dumps({"n": 4, "rc": 124, "parsed": None}))
-    assert _run_record_lint(legacy, lost).returncode == 0
-    result = _run_record_lint("--strict", legacy, lost)
-    assert result.returncode == 1
-    assert "legacy" in result.stdout
 
 
 def test_metric_lint_default_invocation_checks_real_catalog():

@@ -227,8 +227,13 @@ def test_batched_seed_determinism():
     assert np.allclose(out1, out2)
 
 
-def test_serial_fallback_disabled_raises():
-    cfg = "machines:" + _machine_block(
+@pytest.mark.parametrize("elastic", [False, True], ids=["static", "elastic"])
+def test_serial_fallback_disabled_raises(elastic, tmp_path):
+    """Both orchestrators refuse in the plan stage, by the machine's name,
+    before anything is fetched or built."""
+    import os
+
+    cfg = "machines:" + _machine_block("fall-0", n_tags=2) + _machine_block(
         "nofall",
         n_tags=2,
         model="""
@@ -239,8 +244,13 @@ def test_serial_fallback_disabled_raises():
 """,
     )
     machines = _machines(cfg)
-    with pytest.raises(ValueError):
-        BatchedModelBuilder(machines, serial_fallback=False).build()
+    builder = BatchedModelBuilder(
+        machines, serial_fallback=False, elastic=elastic,
+        output_dir=str(tmp_path),
+    )
+    with pytest.raises(ValueError, match="nofall is not batchable"):
+        builder.build()
+    assert set(os.listdir(tmp_path)) <= {"_scheduler"}
 
 
 def test_seed_independent_of_bucket_composition():
