@@ -56,8 +56,8 @@ class BuildMetadata:
     fault_domain: Dict[str, Any] = field(default_factory=dict)
     # per-phase build durations in seconds (observability/telemetry.py span
     # taxonomy: fetch/validate/cross_validation/fit/...). The serial builder
-    # records measured walls; the fleet builder apportions bucket walls the
-    # same way it does the legacy *_duration_sec fields
+    # records measured walls; the fleet builder apportions each chunk's wall
+    # the same way it does the legacy *_duration_sec fields
     phases: Dict[str, float] = field(default_factory=dict)
 
 

@@ -48,8 +48,10 @@ The pipeline of one ``build()``, in the order of this file:
    before it is stacked. ``_build_bucket_guarded`` is the recovery ladder
    round it.
 5. **assembly** (``_assemble_and_persist``, on a pool, chunk by chunk under
-   the device): thresholds, scores, metadata, the artifact's dump and its
-   registry keys; the bucket's end rewrites the durations.
+   the device): a machine's slice of the chunk's outputs and its divergence
+   check, thresholds, scores, metadata with the chunk's own durations, the
+   artifact's dump and its registry keys. A machine is final when its job
+   returns: the bucket's end adds nothing to any artifact.
 """
 
 import datetime
@@ -118,7 +120,6 @@ _STAGE_SECONDS = {
     for name in (
         "plan", "fetch_stage", "validate_stage", "bucket_prep", "fetch_wait",
         "stack_h2d", "launch", "wait", "d2h", "slice", "tail", "drain",
-        "finalize",
     )
 }
 
@@ -1666,10 +1667,20 @@ class BatchedModelBuilder:
                 _note_shard_devices(X_d, outputs[0])
             return group, outputs
 
+        # the completion of the chunk before: the bucket's start for the first
+        chunk_done = t0
+
         def wait(group, outputs):
+            """Block until the chunk is ready, and take its wall: from the
+            completion of the chunk before to its own (so a first compile is
+            charged to the first chunk's machines). It is what the chunk's
+            machines report as their training durations."""
+            nonlocal chunk_done
             with _stage("wait"):
                 jax.block_until_ready(outputs)
-            return group, outputs
+            done = time.time()
+            wall, chunk_done = done - chunk_done, done
+            return wall
 
         def fetch(group, outputs):
             with _stage("d2h"):
@@ -1694,64 +1705,27 @@ class BatchedModelBuilder:
                 fold_preds_np = [distributed.local_rows(fp)[1] for fp in fold_preds]
                 return group, rows, params_np, losses_np, fold_preds_np
 
-        # host-side assembly per machine (~10ms each: threshold stats,
-        # scores, metadata) runs on a thread pool, enqueued per chunk AS SOON
-        # as that chunk is fetched — it overlaps the next chunks' device time
-        # instead of serializing after the whole fleet has trained
+        # host-side assembly per machine (its slice of the chunk, threshold
+        # stats, scores, metadata, the dump: ~25ms each on the chip's host,
+        # PERF.md §5) runs on a thread pool, enqueued per chunk AS SOON as
+        # that chunk is fetched — it overlaps the next chunks' device time
+        # instead of serializing after the whole fleet has trained. The build
+        # thread only submits
         futures = []
 
-        def enqueue_assembly(pool, fetched, chunk_start):
+        def enqueue_assembly(pool, fetched, wall, chunk_start):
             with _stage("slice", chunk_start=chunk_start):
-                group, rows, params_stack, losses, fold_preds = fetched
-                # provisional per-machine duration for checkpointed metadata: the
-                # wall so far over the machines so far (the bucket-level
-                # apportionment below refreshes it once the bucket completes,
-                # but a mid-bucket kill must not leave zeros behind)
-                n_done = chunk_start + len(group)
-                per_machine_est = (time.time() - t0) / max(n_done, 1)
+                group, rows, *stacks = fetched
+                per_machine = wall / len(group)
                 for j, row in enumerate(int(r) for r in rows):
                     if row >= len(group):
                         continue  # padding rows replicate the first lane; skip
                     plan, idx = group[row]
-                    params_i = jax.tree_util.tree_map(lambda a: a[j], params_stack)
-                    fold_preds_i = [fp[j] for fp in fold_preds]
-                    # post-build divergence detection: a lane that trained to
-                    # NaN/Inf params (bad lr, degenerate data) is quarantined —
-                    # its garbage must not be persisted as a servable artifact
-                    bad = faults.params_non_finite(params_i, losses[j])
-                    if bad is None and faults.should_fire(
-                        "diverge", plan.machine.name
-                    ):
-                        bad = "injected divergence"
-                    if bad is not None:
-                        if self.fail_fast:
-                            raise faults.DivergedModelError(
-                                f"machine {plan.machine.name}: {bad}"
-                            )
-                        self._quarantine(
-                            plan.machine,
-                            QuarantineRecord(
-                                plan.machine.name, faults.STAGE_TRAINING,
-                                "diverged", bad,
-                            ),
-                        )
-                        continue
-                    futures.append(
-                        pool.submit(
-                            lambda idx, plan, p, l, fp: (
-                                idx,
-                                self._assemble_and_persist(
-                                    plan, p, l, fp, fold_bounds, per_machine_est,
-                                    kfold_folds,
-                                ),
-                            ),
-                            idx,
-                            plan,
-                            params_i,
-                            losses[j],
-                            fold_preds_i,
-                        )
+                    assembled = pool.submit(
+                        self._assemble_and_persist, plan, j, *stacks,
+                        fold_bounds, per_machine, kfold_folds,
                     )
+                    futures.append((idx, plan, assembled))
 
         # keep at most 2 chunks in flight: dispatch chunk k+1 (async) before
         # fetching chunk k, so transfers overlap compute while peak HBM stays
@@ -1786,69 +1760,71 @@ class BatchedModelBuilder:
                     if not group:
                         continue  # every machine of the chunk was lost
                     next_in_flight = dispatch(start, group)
+                    wall = wait(*in_flight)
                     enqueue_assembly(
-                        pool, fetch(*wait(*in_flight)), in_flight_start
+                        pool, fetch(*in_flight), wall, in_flight_start
                     )
                     in_flight, in_flight_start = next_in_flight, start
                 # the device's work on this bucket ends with this wait
-                ready = wait(*in_flight)
+                wall = wait(*in_flight)
             # ... and everything after it is the tail: the last chunk's pull
-            # and slicing, the assembly pool's drain, the metadata rewrite
+            # and submits, and the assembly pool's drain
             with _stage("tail", bucket=bucket_name, machines=M):
-                enqueue_assembly(pool, fetch(*ready), in_flight_start)
-                train_duration = time.time() - t0
-                with _stage("drain"):
-                    out = [f.result() for f in futures]
+                enqueue_assembly(pool, fetch(*in_flight), wall, in_flight_start)
                 logger.info(
                     "Batched bucket: %d machines (chunk %d, %s start) trained "
                     "in %.2fs",
-                    M, chunk, "warm" if warm else "cold", train_duration,
+                    M, chunk, "warm" if warm else "cold", chunk_done - t0,
                 )
-                with _stage("finalize"):
-                    self._finalize_durations(
-                        out, train_duration / M, len(fold_bounds)
-                    )
+                out = []
+                with _stage("drain"):
+                    # a diverged lane comes back as its verdict and is
+                    # quarantined here: _quarantine is the build thread's
+                    for idx, plan, assembled in futures:
+                        result = assembled.result()
+                        if isinstance(result, QuarantineRecord):
+                            self._quarantine(plan.machine, result)
+                        else:
+                            out.append((idx, result))
                 return out
-
-    def _finalize_durations(self, out, per_machine: float, n_folds: int) -> None:
-        """Duration metadata: the fused program interleaves CV-fold training
-        with the final fit, and compile time belongs to no one machine —
-        apportion the bucket wall uniformly (by fold count for the
-        cv-vs-fit split), exactly as a whole-fleet observer would."""
-        cv_share = per_machine * n_folds / (n_folds + 1)
-        fit_share = per_machine / (n_folds + 1)
-        for _, (model, machine_out) in out:
-            build_meta = machine_out.metadata.build_metadata.model
-            build_meta.model_training_duration_sec = fit_share
-            build_meta.cross_validation.cv_duration_sec = cv_share
-            phases = machine_out.metadata.build_metadata.phases
-            phases["fit"] = fit_share
-            phases["cross_validation"] = cv_share
-        if self.output_dir:
-            # checkpointed artifacts were written at assembly time with
-            # chunk-level duration estimates — the apportionment above needs
-            # the full bucket wall; refresh just their metadata.json
-            # (atomic: a kill mid-refresh must not corrupt a registered
-            # artifact)
-            for _, (_, machine_out) in out:
-                serializer.dump_metadata(
-                    self._machine_output_dir(machine_out.name),
-                    machine_out.to_dict(),
-                )
 
     # --------------------------------------------------------- assembly
     def _assemble_and_persist(
-        self, plan: _Plan, params, losses, fold_preds, fold_bounds,
-        per_machine_est: float, kfold_folds=None,
-    ) -> Tuple[Any, Machine]:
+        self, plan: _Plan, j: int, params_stack, losses, fold_preds,
+        fold_bounds, per_machine: float, kfold_folds=None,
+    ):
+        """The pool job that makes one machine final, once: lane ``j`` of its
+        chunk's outputs sliced and checked, the machine assembled and its
+        artifact written. Its durations are its share of its chunk's wall
+        (``per_machine``: the wall over the chunk's live lanes), split by
+        fold count, since the fused program interleaves CV-fold training
+        with the final fit. Returns ``(model, machine)``; for a lane that
+        diverged, its ``QuarantineRecord`` (raised under ``fail_fast``):
+        nothing of it is persisted, and the build thread quarantines it when
+        it takes the future."""
+        name = plan.machine.name
         n_stages = len(fold_bounds) + 1
-        with _machine_trace(plan.machine.name), telemetry.span(
-            "assemble", _PHASE_ASSEMBLE, machine=plan.machine.name
+        with _machine_trace(name), telemetry.span(
+            "assemble", _PHASE_ASSEMBLE, machine=name
         ):
+            params = jax.tree_util.tree_map(lambda a: a[j], params_stack)
+            # post-build divergence detection: a lane that trained to NaN/Inf
+            # params (bad lr, degenerate data) is quarantined — its garbage
+            # must not be persisted as a servable artifact
+            bad = faults.params_non_finite(params, losses[j])
+            if bad is None and faults.should_fire("diverge", name):
+                bad = "injected divergence"
+            if bad is not None:
+                if self.fail_fast:
+                    raise faults.DivergedModelError(f"machine {name}: {bad}")
+                return QuarantineRecord(
+                    name, faults.STAGE_TRAINING, "diverged", bad
+                )
             built = self._assemble(
-                plan, params, losses, fold_preds, fold_bounds,
-                per_machine_est / n_stages,
-                per_machine_est * len(fold_bounds) / n_stages,
+                plan, params, losses[j], [fp[j] for fp in fold_preds],
+                fold_bounds,
+                per_machine / n_stages,
+                per_machine * len(fold_bounds) / n_stages,
                 kfold_folds,
             )
         self._persist(plan.machine, *built)
@@ -1940,7 +1916,7 @@ class BatchedModelBuilder:
                 else {}
             ),
             # serial-path parity (build_model.py): the batched equivalents
-            # are apportioned shares of the bucket wall, like the legacy
+            # are apportioned shares of the chunk's wall, like the legacy
             # duration fields above
             phases={
                 "fetch": plan.query_duration,

@@ -18,14 +18,13 @@ from gordo_tpu.workflow.normalized_config import NormalizedConfig
 
 ONCE_A_BUILD = (
     "plan", "fetch_stage", "validate_stage", "bucket_prep", "compile",
-    "train", "tail", "drain", "finalize",
+    "train", "tail", "drain",
 )
 ONCE_A_CHUNK = ("stack_h2d", "launch", "wait", "d2h", "slice")
 # once a chunk after the first: the wait for the chunk's own fetches
 AFTER_THE_FIRST = ("fetch_wait",)
 LEAVES = (
     "plan", "fetch_stage", "validate_stage", "bucket_prep", "drain",
-    "finalize",
 ) + ONCE_A_CHUNK + AFTER_THE_FIRST
 CHUNKS = 2
 
@@ -135,13 +134,65 @@ def test_build_thread_stages_tile_the_build(tmp_path):
     assert last_wait_end <= train_end
     (tail_start, tail_end), = by_name["tail"]
     assert tail_start >= last_wait_end
-    for name in ("drain", "finalize"):
-        (start, end), = by_name[name]
-        assert tail_start <= start and end <= tail_end
+    # the tail is the last chunk's pull and submits, then the pool's drain:
+    # nothing is done to an artifact once its own job has returned
     last = lambda name: max(by_name[name])  # noqa: E731
-    assert tail_start <= last("d2h")[0] and last("slice")[1] <= tail_end
+    (drain_start, drain_end), = by_name["drain"]
+    assert tail_start <= last("d2h")[0] and last("slice")[1] <= drain_start
+    assert drain_end <= tail_end
+    assert "finalize" not in by_name
     # what _build_all does after its last bucket is under batched_build alone
     assert build_end - tail_end <= 0.01 * (build_end - build_start)
+
+
+def _files(machine_dir):
+    return {
+        name: os.stat(os.path.join(machine_dir, name)).st_mtime_ns
+        for name in sorted(os.listdir(machine_dir))
+    }
+
+
+def test_each_artifact_is_written_once(tmp_path, monkeypatch):
+    """A machine is final when its own pool job returns: one ``dump`` a
+    machine, no second pass over any ``metadata.json``, and no file of it
+    touched afterwards (against the job's return, not a chunk's ``wait``,
+    which a tiny CPU model can beat)."""
+    from gordo_tpu import serializer
+
+    dumped, rewritten = [], []
+    dump, dump_metadata = serializer.dump, serializer.dump_metadata
+
+    def counted_dump(obj, dest_dir, metadata=None):
+        dumped.append(os.path.basename(dest_dir))
+        return dump(obj, dest_dir, metadata=metadata)
+
+    def counted_dump_metadata(dest_dir, metadata):
+        rewritten.append(os.path.basename(dest_dir))
+        return dump_metadata(dest_dir, metadata)
+
+    # batch_trainer calls both through the package (dump writes its own
+    # metadata.json inside the serializer module, past this name)
+    monkeypatch.setattr(serializer, "dump", counted_dump)
+    monkeypatch.setattr(serializer, "dump_metadata", counted_dump_metadata)
+
+    when_final, job_threads = {}, set()
+    assemble_and_persist = BatchedModelBuilder._assemble_and_persist
+
+    def job(self, plan, *args, **kwargs):
+        built = assemble_and_persist(self, plan, *args, **kwargs)
+        when_final[plan.machine.name] = _files(tmp_path / plan.machine.name)
+        job_threads.add(threading.get_ident())
+        return built
+
+    monkeypatch.setattr(BatchedModelBuilder, "_assemble_and_persist", job)
+    names = [machine.name for _, machine in _build("once", tmp_path)]
+    assert len(names) == 2 * CHUNKS
+    assert sorted(dumped) == sorted(names)
+    assert rewritten == []
+    assert threading.get_ident() not in job_threads
+    for name in names:
+        assert {"model.pkl", "metadata.json"} <= set(when_final[name])
+        assert _files(tmp_path / name) == when_final[name], name
 
 
 def test_spans_off_observes_no_stage_and_allocates_no_span(tmp_path):
