@@ -53,9 +53,9 @@ def sample_indices(seed: int, n_built: int, n_sample: int) -> List[int]:
     return sorted(others.tolist() + [n_built - 1])
 
 
-def _leaves(tree) -> List[np.ndarray]:
-    """Leaves of a list-of-dicts parameter tree, by layer then key."""
-    return [np.asarray(layer[key], np.float64) for layer in tree for key in sorted(layer)]
+def _leaf_keys(tree) -> List[tuple]:
+    """Where the leaves of a list-of-dicts parameter tree lie, by layer then key."""
+    return [(i, key) for i, layer in enumerate(tree) for key in sorted(layer)]
 
 
 def _rms(a) -> float:
@@ -86,16 +86,27 @@ def as_observed(rec: Dict[str, object], frame_probe: np.ndarray) -> Dict[str, ob
 
 
 def gaps(observed: Dict[str, object], ref: Dict[str, object], frame_probe: np.ndarray) -> Dict[str, float]:
-    """The six numbers for one machine."""
-    init, grads = _leaves(ref["init"]), _leaves(ref["first_grad"])
-    d_ref = [a - b for a, b in zip(_leaves(ref["params"]), init)]
-    d_obs = [a - b for a, b in zip(_leaves(observed["params"]), init)]
-    grad_norms = np.array([np.linalg.norm(g) for g in grads])
+    """The six numbers for one machine. The weights are read a leaf at a
+    time, each converted to float64 alone and dropped: a machine's trees are
+    never copied whole."""
+
+    def leaf(tree, where):
+        return np.asarray(tree[where[0]][where[1]], np.float64)
+
+    grad_norms, ref_norms, obs_norms, diff_sq, base_sq = [], [], [], [], []
+    for where in _leaf_keys(ref["init"]):
+        init = leaf(ref["init"], where)
+        d_ref = leaf(ref["params"], where) - init
+        d_obs = leaf(observed["params"], where) - init
+        grad_norms.append(np.linalg.norm(leaf(ref["first_grad"], where)))
+        ref_norms.append(np.linalg.norm(d_ref))
+        obs_norms.append(np.linalg.norm(d_obs))
+        diff_sq.append(np.sum((d_obs - d_ref) ** 2))
+        base_sq.append(np.sum(d_ref**2))
+    grad_norms, ref_norms, obs_norms = (np.array(a) for a in (grad_norms, ref_norms, obs_norms))
     counted = grad_norms >= 1e-3 * np.median(grad_norms)
-    diff = np.sqrt(sum(np.sum((a - b) ** 2) for a, b, c in zip(d_obs, d_ref, counted) if c))
-    base = np.sqrt(sum(np.sum(b**2) for b, c in zip(d_ref, counted) if c))
-    ref_norms = np.array([np.linalg.norm(b) for b in d_ref])
-    obs_norms = np.array([np.linalg.norm(a) for a in d_obs])
+    diff = np.sqrt(sum(a for a, c in zip(diff_sq, counted) if c))
+    base = np.sqrt(sum(b for b, c in zip(base_sq, counted) if c))
     leaf_gaps = np.abs(obs_norms - ref_norms) / np.maximum(ref_norms, np.median(ref_norms))
     thr_obs = np.concatenate([[observed["aggregate_threshold"]], np.ravel(observed["feature_thresholds"])])
     thr_ref = np.concatenate([[ref["aggregate_threshold"]], np.ravel(ref["feature_thresholds"])])

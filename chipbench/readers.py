@@ -4,9 +4,12 @@ metric as for a per-layer one. ``ctx`` holds what the harness saw of the
 window: the machines its builds persisted (``machines``), its length and the
 set-up before it (``window_s``, ``setup_s``), each machine's time to its
 artifact (``ready_s``), the program's counters before and after it
-(``before``, ``after``), the cell (``cell``), the device (``device_kind``,
-``n_devices``) and, in a traced run, the reduced trace (``trace``). A reader
-that finds nothing to read returns None."""
+(``before``, ``after``: the harness's own keys and every ``gordo_build_*``
+series of the program's catalog under ``<name>{label=value,…}``), the cell
+(``cell``), the device (``device_kind``, ``n_devices``) and, in a traced run,
+the reduced trace (``trace``: the window's ``busy_s``, ``module_s``, …, and the
+detail cut's ``detail_s``, ``scope_s``, ``op_s``, ``op_scope``). A reader that
+finds nothing to read returns None."""
 
 from __future__ import annotations
 
@@ -18,6 +21,8 @@ STEP_PROGRAM = "jit_one_machine"
 
 
 def counter_delta(ctx: dict, key: str) -> Optional[float]:
+    """What a counter (or a phase's or histogram's sum) gained over the
+    window's builds; None where the program never touched the series."""
     if key not in ctx["after"]:
         return None
     return ctx["after"][key] - ctx["before"].get(key, 0.0)

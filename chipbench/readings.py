@@ -30,7 +30,7 @@ import tempfile
 import time
 
 from chipbench import check, reference
-from chipbench.run import ROOT, Fleet, load_cell, program_gaps, sample, set_up
+from chipbench.run import ROOT, Fleet, load_cell, program_gaps, release_program, sample, set_up
 
 
 def main(argv=None) -> int:
@@ -45,7 +45,7 @@ def main(argv=None) -> int:
     args = parser.parse_args(argv)
     cell = load_cell(args.manifest, args.workload)
     config, tr, limits = cell["config"], cell["traffic"], cell["file"]["limits"]
-    set_up(cell, args.rehearsal)
+    devices = set_up(cell, args.rehearsal)
     modes = {
         "control": dict(precision="float8"),
         "bfloat16": dict(precision="bfloat16"),
@@ -60,6 +60,10 @@ def main(argv=None) -> int:
                 build = Fleet(cell, seed, out_root).build(args.machines or tr.chunk_machines)
                 t_build = time.time() - t0
                 names, paths, frames, probe = sample(cell, seed, [build])
+                # as a run does before its reference, once a build: it drops
+                # every compiled program, the reference's own of the seed
+                # before among them, so reference_s holds their reload
+                release_program(devices)
                 t0 = time.time()
                 refs = reference.build_machines(config, names, frames, seed)
                 t_ref = time.time() - t0

@@ -16,6 +16,11 @@ reference), ``bfloat16`` (what the configurations state; a diagnostic) and
 ``half_batch`` plants the fault "half of the batch left out, the mean taken
 over the rest".
 
+A machine of half a billion parameters has to fit beside nothing but itself:
+the step is given its weights and Adam's two moments to update in place
+(16 bytes a parameter with the gradient, not 28), and a stage's state is
+freed before the next stage's weights are made.
+
 No kernels, no cache, no vmap over a fleet: sampled machines are stacked on
 a leading axis only so that one compiled step serves them all.
 """
@@ -102,6 +107,7 @@ def _windows(X, starts, lookback):
 
 @functools.lru_cache(maxsize=None)
 def _step_fn(reference: str, model_key: str, opt_key: str, precision: str):
+    """The compiled training step and prediction of one configuration."""
     model, opt = json.loads(model_key), json.loads(opt_key)
     forward = model_reference({"reference": reference}).forward
     mm = matmul(precision)
@@ -128,9 +134,14 @@ def _step_fn(reference: str, model_key: str, opt_key: str, precision: str):
         return forward(model, params, _windows(X, starts, lookback), mm)
 
     # the sampled machines ride on a leading axis; `t`, the batch order's
-    # length and the window starts are the same for all of them
+    # length and the window starts are the same for all of them. The step
+    # is given its state to overwrite: the caller keeps nothing of what it
+    # passes as params, m and v
     return (
-        jax.jit(jax.vmap(step, in_axes=(0, 0, 0, None, 0, 0, 0, 0))),
+        jax.jit(
+            jax.vmap(step, in_axes=(0, 0, 0, None, 0, 0, 0, 0)),
+            donate_argnums=(0, 1, 2),
+        ),
         jax.jit(jax.vmap(predict, in_axes=(0, 0, None))),
     )
 
@@ -197,8 +208,12 @@ def build_machines(
                 pos = jnp.arange(n_max)
                 order = jnp.argsort(jnp.where(pos < n_valid, keys, keys + 2.0))
                 orders.append(np.asarray(order)[:n_valid])
-            init = jax.tree_util.tree_map(lambda *a: jnp.stack(a), *inits)
-            params = init
+            params = jax.tree_util.tree_map(lambda *a: jnp.stack(a), *inits)
+            del inits
+            # the step overwrites what it is given: the initial weights go to
+            # the host before the first one (a copy: on a CPU backend
+            # np.asarray would be a view of the buffer given away)
+            init = jax.tree_util.tree_map(np.array, params) if final else None
             m = jax.tree_util.tree_map(jnp.zeros_like, params)
             v = jax.tree_util.tree_map(jnp.zeros_like, params)
             t = 0
@@ -225,6 +240,10 @@ def build_machines(
             loss_sum = np.asarray(sum(losses))
             starts = jnp.arange(te_end - te_start - lookback + 1) + te_start
             pred = np.asarray(predict(params, Xd, starts))
+            trained = jax.tree_util.tree_map(np.asarray, params) if final else None
+            # the next stage's weights are made beside nothing: this stage's
+            # state goes first (a stage's peak is its own 12 bytes a parameter)
+            del params, m, v
             y_true = X_raw[:, te_start + lookback - 1 : te_end]
             if not final:
                 # the last fold's errors set the detector's thresholds
@@ -239,8 +258,8 @@ def build_machines(
             for s, rec in enumerate(out):
                 mn, mx = X_raw[s].min(0), X_raw[s].max(0)
                 span = np.where(mx - mn < 10 * np.finfo(np.float32).eps, 1.0, mx - mn)
-                rec["init"] = jax.tree_util.tree_map(lambda a: np.asarray(a[s]), init)
-                rec["params"] = jax.tree_util.tree_map(lambda a: np.asarray(a[s]), params)
+                rec["init"] = jax.tree_util.tree_map(lambda a: a[s], init)
+                rec["params"] = jax.tree_util.tree_map(lambda a: a[s], trained)
                 rec["first_grad"] = jax.tree_util.tree_map(lambda a: a[s], first_grad)
                 rec["loss"] = float(loss_sum[s] / n_valid)
                 rec["output"] = pred[s]  # over the windows of probe_rows()

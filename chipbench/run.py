@@ -13,6 +13,8 @@ import time
 _PROCESS_START = time.time()
 
 import argparse
+import dataclasses
+import gc
 import importlib
 import json
 import os
@@ -63,9 +65,17 @@ def load_cell(manifest_path: str, workload: str) -> dict:
 
 
 # ------------------------------------------- what is taken from the program
+PROGRAM_SERIES = "gordo_build_"
+
+
 def program_counters() -> Dict[str, float]:
-    """A snapshot of the program's own counters and phase sums."""
+    """A snapshot of the program's own counters and phase sums: the keys the
+    harness itself reads, and every series of the program's build catalog
+    (``gordo_build_*`` in its default registry, whoever registered it) under
+    ``<name>{label=value,…}`` (the bare name where it has no labels):
+    counters and gauges as their values, a histogram as its sum."""
     from gordo_tpu.observability import metrics as catalog
+    from gordo_tpu.observability import telemetry
 
     out = {
         "compiles": catalog.XLA_COMPILES.value(source="compiled"),
@@ -75,6 +85,13 @@ def program_counters() -> Dict[str, float]:
     }
     for (phase,), (_, total) in catalog.BUILD_PHASE_SECONDS.snapshot():
         out[f"phase_s.{phase}"] = total
+    for metric in telemetry.default_registry().collect():
+        if not metric.name.startswith(PROGRAM_SERIES):
+            continue
+        for labels, value in metric.snapshot():
+            pairs = ",".join(f"{k}={v}" for k, v in zip(metric.labelnames, labels))
+            key = f"{metric.name}{{{pairs}}}" if pairs else metric.name
+            out[key] = value[1] if metric.kind == "histogram" else value
     return out
 
 
@@ -233,6 +250,37 @@ def memory_peak(devices) -> int:
     return int(max(peaks))
 
 
+def device_bytes(devices) -> Dict[str, int]:
+    """What the fullest chip holds now: its live buffers and what the runtime
+    keeps reserved for the loaded programs' scratch."""
+    stats = [device.memory_stats() or {} for device in devices]
+    return {
+        key: int(max(s.get(key, 0) for s in stats))
+        for key in ("bytes_in_use", "bytes_reserved")
+    }
+
+
+def release_program(devices) -> None:
+    """Give the device back before the reference runs on it: the builds'
+    results are out of scope by now, so collect what cycles still hold, and
+    drop every compiled program jit has loaded (the fleet trainer's chunk
+    programs are plain ``jax.jit``s; the runtime keeps a loaded program's
+    scratch reserved until the executable goes). The window, ``setup_s`` and
+    the memory peak are read before this; what runs afterwards (the
+    reference, the artifacts' own predictions) compiles or loads anew."""
+    import jax
+
+    before = device_bytes(devices)
+    gc.collect()
+    jax.clear_caches()
+    gc.collect()
+    print(
+        f"chipbench: released the program's device memory: before {json.dumps(before)}, "
+        f"after {json.dumps(device_bytes(devices))}",
+        file=sys.stderr,
+    )
+
+
 def calibrate():
     """A program of the harness's own, some 0.3 s of matmuls, run five times
     alone and to its end inside the operation-level session: the executions
@@ -261,8 +309,10 @@ def device_ops_detail(fleet: "Fleet", tracing: dict, out_dir: str, rehearsal: bo
     traced operation by operation over the calibration program and one more
     build of one chunk. Where a chunk issues millions of operations the
     session is cut ``detail_seconds`` into that build: the scan's steps are
-    all alike. Returns the operations by self time and the calibration's two
-    readings; raises :class:`chipbench.trace.TraceError` where they differ."""
+    all alike. Returns what :func:`chipbench.trace.read_detail` reads of the
+    cut (operations by self time, by kind, by compiled instance and by named
+    scope) and the calibration's two readings; raises
+    :class:`chipbench.trace.TraceError` where they differ."""
     import threading
 
     import jax
@@ -285,11 +335,12 @@ def device_ops_detail(fleet: "Fleet", tracing: dict, out_dir: str, rehearsal: bo
         timer.cancel()
         timer.join()
     close()
-    planes = trace.read_planes(trace.find_xplane(out_dir))
+    path = trace.find_xplane(out_dir)
+    planes = trace.read_planes(path)
     checked = None
     if not rehearsal:
         checked = trace.cross_check(planes)
-    return trace.op_ranking(planes, rehearsal), checked
+    return trace.read_detail(planes, trace.op_names(path), rehearsal), checked
 
 
 def read_metrics(metrics: List[dict], ctx: dict) -> Dict[str, dict]:
@@ -336,7 +387,9 @@ def program_gaps(paths, frames, refs, probe: slice) -> List[Dict[str, float]]:
 def compare(cell: dict, seed: int, builds: List[dict]) -> Dict[str, Dict[str, float]]:
     """Decide ``correct``: the sampled machines against the plain reference."""
     names, paths, frames, probe = sample(cell, seed, builds)
+    t0 = time.time()
     refs = reference.build_machines(cell["config"], names, frames, seed)
+    print(f"chipbench: reference_s {time.time() - t0:.3f}", file=sys.stderr)
     return check.verdict(
         check.typical(program_gaps(paths, frames, refs, probe)), cell["file"]["limits"]
     )
@@ -430,10 +483,11 @@ def main(argv: Optional[List[str]] = None) -> int:
                 args.rehearsal,
             )
             t_detail = time.time()
-            reduced.device_ops, checked = device_ops_detail(
+            detail, checked = device_ops_detail(
                 fleet, cell["file"]["trace"], os.path.join(out_root, "trace-detail"),
                 args.rehearsal,
             )
+            reduced = dataclasses.replace(reduced, **detail)
             print(
                 f"chipbench: calibration read both ways {json.dumps(checked)}; "
                 f"the operation-level session took {time.time() - t_detail:.1f} s",
@@ -447,6 +501,7 @@ def main(argv: Optional[List[str]] = None) -> int:
             result["breakdown"] = {
                 "device_ops": reduced.device_ops,
                 "idle_gaps": reduced.idle_gaps,
+                "device_scopes": reduced.device_scopes(),
             }
         else:
             result["metrics"] = read_metrics(cell["end_to_end"], ctx)
@@ -454,7 +509,9 @@ def main(argv: Optional[List[str]] = None) -> int:
                 raise Refused("no machine was persisted inside the window")
         result["device"] = device
 
-        # the comparison runs once the window has closed and the peak is read
+        # the comparison runs once the window has closed, the peak is read
+        # and the program's state is freed
+        release_program(devices)
         compared = compare(cell, args.seed, builds)
         result["correct"] = check.is_correct(compared) and result["failed"] == 0
         result["compared"] = compared

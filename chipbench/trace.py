@@ -17,12 +17,19 @@ millisecond (tests/chipbench); every traced run checks that again on a
 program of the harness's own (:func:`cross_check`). A CPU rehearsal has
 neither; with ``rehearsal=True`` the host plane's XLA client threads stand in, so that the
 harness's control flow can be exercised. Such numbers are never device numbers.
+
+The operation-level session after the window is also read by named scope
+(:func:`read_detail`): an event's ``op_name``, where ``jax.named_scope`` and
+the transformations leave their names, is the stat ``tf_op`` of the event's
+*metadata* (libtpu 0.0.34), which ``ProfileData`` does not expose, so that one
+map is read off the file's wire format (:func:`op_names`).
 """
 
 from __future__ import annotations
 
 import glob
 import os
+import re
 import statistics
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Sequence, Tuple
@@ -33,7 +40,12 @@ DEVICE_PLANE = "/device:TPU:"
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
 REHEARSAL_LINE = "tf_XLA"  # the CPU client's and its thread pool's threads
-HOST_MARK = "chipbench."
+# the harness's own marks and the program's stages (``telemetry.span``), on
+# one clock: an idle gap goes to the innermost of either that covers it, on
+# the threads the harness marked itself (the build thread's stages say what
+# kept the device waiting; the pools' jobs run beside them)
+HARNESS_MARK = "chipbench."
+HOST_MARK = (HARNESS_MARK, "gordo.")
 LAUNCH = "tpu::System::Execute"
 DONE = "tpu::System::Execute=>Done"
 DROPPED = "Trace Buffers Dropped"
@@ -56,6 +68,16 @@ class Reduced:
     module_runs: Dict[str, int] = field(default_factory=dict)
     device_ops: List[List[object]] = field(default_factory=list)
     idle_gaps: List[List[object]] = field(default_factory=list)
+    # from the operation-level session after the window (:func:`read_detail`)
+    detail_s: float = 0.0
+    scope_s: Dict[str, float] = field(default_factory=dict)
+    op_s: Dict[str, List[float]] = field(default_factory=dict)
+    op_scope: Dict[str, str] = field(default_factory=dict)
+
+    def device_scopes(self, top: int = 10) -> List[List[object]]:
+        """The detail cut's named scopes by self time; the operations under
+        no scope are the row ``no_scope``."""
+        return _rank({k or "no_scope": v for k, v in self.scope_s.items()}, top)
 
     def program_seconds(self, pattern: str) -> Optional[float]:
         """Device seconds of every execution of the programs whose name
@@ -114,25 +136,39 @@ def _base_name(name: str) -> str:
     return head if head and tail.isdigit() else name
 
 
-def self_seconds(events: Sequence[Tuple[str, float, float]]) -> Dict[str, float]:
-    """Seconds an operation ran itself, by kind: an enclosing operation (a
-    ``while`` around its body) is charged only what its children leave."""
-    out: Dict[str, float] = {}
-    stack: List[List[object]] = []  # [name, end, seconds of children]
+def _instruction(name: str) -> str:
+    """``%fusion.123 = bf16[…] fusion(…)`` → ``fusion.123``: one compiled
+    instance of an operation."""
+    return name.split(" = ")[0].lstrip("%")
+
+
+def self_times(events: Sequence[Tuple[str, float, float]], key=_base_name) -> Dict[str, List[float]]:
+    """[seconds an operation ran itself, its runs] by ``key`` of its name: an
+    enclosing operation (a ``while`` around its body) is charged only what
+    its children leave."""
+    out: Dict[str, List[float]] = {}
+    stack: List[List[object]] = []  # [key, end, start, seconds of children]
 
     def close():
         name, end, start, inner = stack.pop()
-        out[name] = out.get(name, 0.0) + (end - start) - inner
+        entry = out.setdefault(name, [0.0, 0])
+        entry[0] += (end - start) - inner
+        entry[1] += 1
         if stack:
             stack[-1][3] += end - start
 
     for name, a, b in sorted(events, key=lambda e: (e[1], -e[2])):
         while stack and stack[-1][1] <= a:
             close()
-        stack.append([_base_name(name), b, a, 0.0])
+        stack.append([key(name), b, a, 0.0])
     while stack:
         close()
     return out
+
+
+def self_seconds(events: Sequence[Tuple[str, float, float]]) -> Dict[str, float]:
+    """Seconds an operation ran itself, by kind."""
+    return {kind: seconds for kind, (seconds, _) in self_times(events).items()}
 
 
 def host_executions(host_events: Sequence[Tuple[str, float, float]]) -> List[Tuple[str, float, float]]:
@@ -186,6 +222,18 @@ def _sort(planes, rehearsal: bool):
     return devices, host_events
 
 
+def _stage_marks(planes) -> List[Tuple[str, float, float]]:
+    """The host marks of every thread that carries one of the harness's own."""
+    out: List[Tuple[str, float, float]] = []
+    for plane in planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                marks = [e for e in _events(line) if e[0].startswith(HOST_MARK)]
+                if any(e[0].startswith(HARNESS_MARK) for e in marks):
+                    out += marks
+    return out
+
+
 def _mark(host_events, name: str) -> Interval:
     """The stretch a host mark covers; everything, where it is not there."""
     hits = [(a, b) for n, a, b in host_events if n == name]
@@ -237,14 +285,113 @@ def cross_check(planes, program: str = CALIBRATION, mark: str = CALIBRATE_MARK,
 def op_ranking(planes, rehearsal: bool = False, after: str = CALIBRATE_MARK, top: int = 10):
     """The device's operations by self time, the ``top`` kinds; operations
     that started before the mark ``after`` ended are left out."""
+    return read_detail(planes, {}, rehearsal, after, top)["device_ops"]
+
+
+# ---------------------------------------------------- device time by scope
+def _varint(buf, i):
+    value = shift = 0
+    while True:
+        value |= (buf[i] & 0x7F) << shift
+        shift, i = shift + 7, i + 1
+        if buf[i - 1] < 0x80:
+            return value, i
+
+
+def _fields(buf):
+    """(field number, int | bytes | None for fixed-width) of one message."""
+    i = 0
+    while i < len(buf):
+        key, i = _varint(buf, i)
+        if key & 7 == 0:
+            value, i = _varint(buf, i)
+        elif key & 7 == 2:
+            n, i = _varint(buf, i)
+            value, i = buf[i:i + n], i + n
+        else:
+            value, i = None, i + (8 if key & 7 == 1 else 4)
+        yield key >> 3, value
+
+
+def op_names(path: str) -> Dict[str, str]:
+    """{event name (the HLO's text): op_name} of the TPU planes. XSpace.planes=1;
+    XPlane.name=2, .event_metadata=4, .stat_metadata=5 (maps: key=1, value=2);
+    X*Metadata.name=2, .stats=5; XStat.metadata_id=1, .str_value=5, .ref_value=7."""
+    out = {}
+    with open(path, "rb") as fh:
+        space = fh.read()
+    for plane in (list(_fields(v)) for n, v in _fields(space) if n == 1):
+        if not dict(plane)[2].startswith(DEVICE_PLANE.encode()):
+            continue
+        entries = [(n, dict(_fields(v))) for n, v in plane if n in (4, 5)]
+        stat_name = {e[1]: dict(_fields(e[2]))[2].decode() for n, e in entries if n == 5}
+        for meta in (list(_fields(e[2])) for n, e in entries if n == 4):
+            for stat in (dict(_fields(v)) for n, v in meta if n == 5):
+                if stat_name.get(stat.get(1)) == "tf_op":
+                    op_name = stat[5].decode() if 5 in stat else stat_name.get(stat.get(7), "")
+                    out[dict(meta)[2].decode()] = op_name
+    return out
+
+
+# what JAX itself writes between the scopes of an op_name: control flow, and
+# the functions that jit and pmap name
+_NOT_A_SCOPE = re.compile(r"while|body|cond|body_pred|closed_call|checkpoint|rematted_computation|branch_\d+_fun")
+_CALLS = {"jit", "pjit", "pmap"}
+
+
+def classify(op_name: str) -> Tuple[str, str]:
+    """(innermost named scope, '' where there is none; 'bwd' | 'fwd' |
+    'plain') of an operation's ``op_name``, any scope name. JAX writes
+    ``a/b/primitive`` with ``jax.named_scope`` names as plain parts and each
+    transformation round the part it was applied under: ``jvp(...)`` round a
+    forward under differentiation, ``transpose(jvp(...))`` round its
+    backward; neither is a plain forward."""
+    way = "bwd" if "transpose(" in op_name else "fwd" if "jvp(" in op_name else "plain"
+    for part in reversed(op_name.rstrip(":").split("/")[:-1]):
+        called = False
+        while part.endswith(")") and "(" in part:
+            head, part = part[:-1].split("(", 1)
+            called = called or head in _CALLS
+        if part and not called and not _NOT_A_SCOPE.fullmatch(part):
+            return part, way
+    return "", way
+
+
+def read_detail(planes, names: Dict[str, str], rehearsal: bool = False,
+                after: str = CALIBRATE_MARK, top: int = 10) -> Dict[str, object]:
+    """The operation-level session after the window, once the mark ``after``
+    has ended (the detail build's cut), as fields of :class:`Reduced`:
+    ``detail_s`` the device self seconds the cut holds; ``op_s`` every
+    compiled operation's [self seconds, runs], summed over devices;
+    ``op_scope`` its innermost named scope by ``names`` (:func:`op_names`);
+    ``scope_s`` the self seconds by scope, unscoped time under ``""``;
+    ``device_ops`` the ``top`` kinds of operation."""
     devices, host_events = _sort(planes, rehearsal)
     _, since = _mark(host_events, after)
     since = since if since != float("inf") else float("-inf")
-    op_s: Dict[str, float] = {}
+    op_s: Dict[str, List[float]] = {}
+    kind_s: Dict[str, float] = {}
+    op_scope: Dict[str, str] = {}
     for ops, _ in devices:
-        for key, seconds in self_seconds([e for e in ops if e[1] >= since]).items():
-            op_s[key] = op_s.get(key, 0.0) + seconds
-    return _rank(op_s, top)
+        cut = [e for e in ops if e[1] >= since]
+        for name, (seconds, runs) in self_times(cut, key=lambda n: n).items():
+            op = _instruction(name)
+            entry = op_s.setdefault(op, [0.0, 0])
+            entry[0] += seconds
+            entry[1] += runs
+            op_scope[op] = classify(names.get(name, ""))[0]
+            kind = _base_name(name)
+            kind_s[kind] = kind_s.get(kind, 0.0) + seconds
+    scope_s: Dict[str, float] = {}
+    for op, (seconds, _) in op_s.items():
+        scope_s[op_scope[op]] = scope_s.get(op_scope[op], 0.0) + seconds
+    return {
+        "detail_s": sum(scope_s.values()),
+        "op_s": op_s,
+        "op_scope": op_scope,
+        "scope_s": scope_s,
+        "device_ops": _rank(kind_s, top),
+    }
 
 
 def _rank(seconds_by_name: Dict[str, float], top: int) -> List[List[object]]:
@@ -252,8 +399,9 @@ def _rank(seconds_by_name: Dict[str, float], top: int) -> List[List[object]]:
 
 
 def reduce_planes(planes, window_s: float, rehearsal: bool = False, top: int = 10) -> Reduced:
+    planes = list(planes)
     devices, host_events = _sort(planes, rehearsal)
-    marks = [e for e in host_events if e[0].startswith(HOST_MARK)]
+    marks = _stage_marks(planes)
     if not devices and any(e[0] == LAUNCH for e in host_events):
         # a host-only session: one chip's executions, as the runtime saw them
         runs = host_executions(host_events)

@@ -33,7 +33,7 @@ def test_lstm_forward_by_hand():
 
 
 def test_lstm_forward_at_other_widths():
-    # the widths of bench.py's windowed family, as ISSUE 25 counted them
+    # the widths of the repo's windowed fleet at 6755c13, as ISSUE 25 counted them
     config = dict(_config("lstm_ae_144"))
     config["model"] = dict(config["model"], dims=[64, 32], funcs=["tanh", "tanh"])
     step = 8 * ((8 * 64 + 64 * 64) + (64 * 32 + 32 * 32) + (32 * 32 + 32 * 32) + (32 * 64 + 64 * 64))

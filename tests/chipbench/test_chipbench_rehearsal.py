@@ -55,7 +55,7 @@ def test_last_line(capsys, host_peak, workload, trace):
         assert 0 < device["busy_s"] <= device["window_s"]
         assert 0 < line["metrics"]["fleet_step_mfu"]["value"]
         assert line["metrics"]["window_compiles"]["value"] == 0
-        assert set(line["breakdown"]) == {"device_ops", "idle_gaps"}
+        assert set(line["breakdown"]) == {"device_ops", "idle_gaps", "device_scopes"}
         assert all(len(v) <= 10 for v in line["breakdown"].values())
     else:
         assert all(v["value"] > 0 for v in line["metrics"].values())
