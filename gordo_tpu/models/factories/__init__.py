@@ -5,6 +5,7 @@ from .feedforward_autoencoder import (
 )
 from .lstm_autoencoder import lstm_model, lstm_symmetric, lstm_hourglass
 from .transformer import transformer_model
+from .hybrid import hybrid_moe_model
 from .tcn import tcn_model
 
 __all__ = [
@@ -15,5 +16,6 @@ __all__ = [
     "lstm_symmetric",
     "lstm_hourglass",
     "transformer_model",
+    "hybrid_moe_model",
     "tcn_model",
 ]

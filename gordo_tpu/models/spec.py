@@ -94,6 +94,49 @@ class MoEBlock:
 
 
 @dataclass(frozen=True)
+class HybridBlock:
+    """
+    Pre-RMSNorm block of two residual sublayers, ``x + operator(norm(x))``
+    then ``x + ffn(norm(x))`` (the LFM2 family's layer; equations in
+    ops/nn.py). ``operator`` is a gated short convolution (``conv``:
+    in-projection to 3·d_model, ``c * causal_depthwise_conv(b * u)``,
+    out-projection) or grouped-query attention (``attention``: ``num_heads``
+    query heads on ``num_kv_heads`` key/value heads of ``head_dim``, RMSNorm a
+    head on q and k, RoPE, causal). ``ffn`` is a SwiGLU of width ``ff_dim``
+    (``dense``), or ``routed``: a sigmoid router over ``num_experts`` picks
+    ``top_k`` by score plus a selection bias, and the layer computes the part
+    its own ``experts_held`` SwiGLU experts (ids ``expert_offset`` and up)
+    give — every assignment to a held expert, none dropped; what experts held
+    elsewhere would add is left out (one chip's share of a layer divided over
+    ``num_experts / experts_held`` chips).
+    """
+
+    d_model: int
+    operator: str = "conv"
+    ffn: str = "dense"
+    ff_dim: int = 128
+    num_heads: int = 4
+    num_kv_heads: int = 4
+    head_dim: int = 16
+    rope_theta: float = 1000000.0
+    conv_kernel: int = 3
+    num_experts: int = 8
+    experts_held: int = 8
+    expert_offset: int = 0
+    top_k: int = 2
+    norm_eps: float = 1e-5
+    # attention implementation, as TransformerBlock's: auto | xla | flash
+    attention_impl: str = "auto"
+
+
+@dataclass(frozen=True)
+class RMSNormLayer:
+    """``x * rsqrt(mean(x^2) + eps) * scale`` over the feature axis."""
+
+    eps: float = 1e-5
+
+
+@dataclass(frozen=True)
 class TCNBlock:
     """
     Temporal-convolutional residual block: two causal dilated 1-D convs with
@@ -120,6 +163,8 @@ LayerSpec = Union[
     PositionalEncoding,
     TransformerBlock,
     MoEBlock,
+    HybridBlock,
+    RMSNormLayer,
     TCNBlock,
     PoolLayer,
 ]

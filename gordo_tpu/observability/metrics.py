@@ -66,6 +66,27 @@ PROGRAM_CACHE = telemetry.counter(
     "In-process bucket-program (jit) cache lookups, by result (hit/miss)",
     ("result",),
 )
+MOE_ASSIGNMENTS = telemetry.counter(
+    "gordo_build_moe_assignments_total",
+    "Token-to-expert assignments the fleet's routed layers made in training "
+    "steps, by where the chosen expert lives: held (computed here, none "
+    "dropped) or absent (another chip's share, left out). held + absent = "
+    "tokens x top_k a routed layer",
+    ("where",),
+)
+MOE_TOKENS = telemetry.counter(
+    "gordo_build_moe_tokens_total",
+    "Tokens routed in training steps, summed over routed layers",
+)
+MOE_LAYER_STEPS = telemetry.counter(
+    "gordo_build_moe_layer_steps_total",
+    "Routed layers times live training steps",
+)
+MOE_PEAK_LOAD = telemetry.counter(
+    "gordo_build_moe_peak_load_total",
+    "Assignments the fullest held expert took, summed over routed layers "
+    "and live training steps (over held / experts_held a layer-step: 1 is even)",
+)
 COMPILE_SECONDS_SAVED = telemetry.counter(
     "gordo_build_compile_seconds_saved_total",
     "Estimated compile seconds avoided by bucket-program cache hits "

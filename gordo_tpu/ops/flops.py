@@ -21,11 +21,13 @@ from typing import Optional
 
 from gordo_tpu.models.spec import (
     DenseLayer,
+    HybridBlock,
     LSTMLayer,
     ModelSpec,
     MoEBlock,
     PoolLayer,
     PositionalEncoding,
+    RMSNormLayer,
     TCNBlock,
     TransformerBlock,
 )
@@ -65,13 +67,32 @@ def forward_flops_per_sample(spec: ModelSpec) -> float:
             # router + top-1 expert FFN per token
             total += 2.0 * d * layer.num_experts * T
             total += 4.0 * d * layer.expert_dim * T
+        elif isinstance(layer, HybridBlock):
+            d = layer.d_model
+            if layer.operator == "conv":
+                # in-projection to 3d and out-projection (the taps are elementwise)
+                total += 2.0 * d * 3 * d * T + 2.0 * d * d * T
+            else:
+                hq = layer.num_heads * layer.head_dim
+                hkv = layer.num_kv_heads * layer.head_dim
+                total += 2.0 * d * (2 * hq + 2 * hkv) * T
+                # causal: half of scores (T x Dh x T) + weighted values a head
+                total += 2.0 * T * T * hq
+            if layer.ffn == "dense":
+                total += 6.0 * d * layer.ff_dim * T
+            else:
+                # the router, and the held experts at the load an even router
+                # gives them: top_k x experts_held / num_experts a token
+                load = layer.top_k * layer.experts_held / layer.num_experts
+                total += 2.0 * d * layer.num_experts * T
+                total += load * 6.0 * d * layer.ff_dim * T
         elif isinstance(layer, TCNBlock):
             # two causal dilated convs (+ a possible 1x1 residual projection)
             k, f = layer.kernel_size, layer.filters
             total += 2.0 * k * in_dim * f * T + 2.0 * k * f * f * T
             if in_dim != f:
                 total += 2.0 * in_dim * f * T
-        elif isinstance(layer, (PoolLayer, PositionalEncoding)):
+        elif isinstance(layer, (PoolLayer, PositionalEncoding, RMSNormLayer)):
             if isinstance(layer, PoolLayer):
                 seq = False
         in_dim = layer_out_dim(layer, in_dim)
@@ -144,6 +165,21 @@ def spec_param_count(spec: ModelSpec) -> int:
             total += 4 * d * d
             total += d * layer.num_experts
             total += layer.num_experts * 2 * d * layer.expert_dim
+        elif isinstance(layer, HybridBlock):
+            d, f = layer.d_model, layer.ff_dim
+            if layer.operator == "conv":
+                total += 4 * d * d + d * layer.conv_kernel
+            else:
+                hq = layer.num_heads * layer.head_dim
+                hkv = layer.num_kv_heads * layer.head_dim
+                total += 2 * d * hq + 2 * d * hkv + 2 * layer.head_dim
+            if layer.ffn == "dense":
+                total += 3 * d * f
+            else:
+                total += (d + 1) * layer.num_experts + layer.experts_held * 3 * d * f
+            total += 2 * d
+        elif isinstance(layer, RMSNormLayer):
+            total += in_dim
         elif isinstance(layer, TCNBlock):
             k, f = layer.kernel_size, layer.filters
             total += k * in_dim * f + k * f * f

@@ -15,18 +15,21 @@ from typing import Any, Dict, List
 
 import jax
 import jax.numpy as jnp
+from jax.custom_batching import sequential_vmap
 
 from gordo_tpu.models.spec import (
     DenseLayer,
+    HybridBlock,
     LSTMLayer,
     ModelSpec,
     MoEBlock,
     PoolLayer,
     PositionalEncoding,
+    RMSNormLayer,
     TCNBlock,
     TransformerBlock,
 )
-from gordo_tpu.ops.attention import multihead_attention
+from gordo_tpu.ops.attention import dot_product_attention, multihead_attention
 
 Params = List[Dict[str, Any]]
 
@@ -145,6 +148,62 @@ def init_moe_block(rng, in_dim: int, layer: MoEBlock):
     }
 
 
+def _normal(rng, shape, std=0.02):
+    return std * jax.random.normal(rng, shape, jnp.float32)
+
+
+def init_hybrid_block(rng, in_dim: int, layer: HybridBlock):
+    """normal(0, 0.02) matrices and taps (the LFM2 family's
+    ``initializer_range``), unit gains, a zero selection bias; one key for
+    the operator and one for the FFN, each split over its matrices."""
+    if in_dim != layer.d_model:
+        raise ValueError(
+            f"HybridBlock d_model={layer.d_model} but incoming dim is "
+            f"{in_dim}; insert a Dense projection first"
+        )
+    d, f = layer.d_model, layer.ff_dim
+    k_op, k_ffn = jax.random.split(rng)
+    p = {
+        "op_norm": jnp.ones((d,), jnp.float32),
+        "ffn_norm": jnp.ones((d,), jnp.float32),
+    }
+    if layer.operator == "conv":
+        ks = jax.random.split(k_op, 3)
+        p["conv_in"] = _normal(ks[0], (d, 3 * d))
+        p["conv_taps"] = _normal(ks[1], (d, layer.conv_kernel))
+        p["conv_out"] = _normal(ks[2], (d, d))
+    elif layer.operator == "attention":
+        ks = jax.random.split(k_op, 4)
+        hq = layer.num_heads * layer.head_dim
+        hkv = layer.num_kv_heads * layer.head_dim
+        p["wq"] = _normal(ks[0], (d, hq))
+        p["wk"] = _normal(ks[1], (d, hkv))
+        p["wv"] = _normal(ks[2], (d, hkv))
+        p["wo"] = _normal(ks[3], (hq, d))
+        p["q_norm"] = jnp.ones((layer.head_dim,), jnp.float32)
+        p["k_norm"] = jnp.ones((layer.head_dim,), jnp.float32)
+    else:
+        raise ValueError(f"Unknown HybridBlock operator {layer.operator!r}")
+    if layer.ffn == "dense":
+        ks = jax.random.split(k_ffn, 3)
+        p["w1"] = _normal(ks[0], (d, f))
+        p["w3"] = _normal(ks[1], (d, f))
+        p["w2"] = _normal(ks[2], (f, d))
+    elif layer.ffn == "routed":
+        ks = jax.random.split(k_ffn, 4)
+        held = layer.experts_held
+        p["router"] = _normal(ks[0], (d, layer.num_experts))
+        # enters the selection only: no gradient reaches it, and its update
+        # rule (not in the published config) is not implemented
+        p["expert_bias"] = jnp.zeros((layer.num_experts,), jnp.float32)
+        p["w1"] = _normal(ks[1], (held, d, f))
+        p["w3"] = _normal(ks[2], (held, d, f))
+        p["w2"] = _normal(ks[3], (held, f, d))
+    else:
+        raise ValueError(f"Unknown HybridBlock ffn {layer.ffn!r}")
+    return p
+
+
 def init_tcn_block(rng, in_dim: int, layer: TCNBlock):
     k1, k2, k3 = jax.random.split(rng, 3)
     filters, ksize = layer.filters, layer.kernel_size
@@ -164,11 +223,11 @@ def layer_out_dim(layer, in_dim: int) -> int:
     """Feature dimension a layer produces given its input dimension."""
     if isinstance(layer, (DenseLayer, LSTMLayer)):
         return layer.units
-    if isinstance(layer, (TransformerBlock, MoEBlock)):
+    if isinstance(layer, (TransformerBlock, MoEBlock, HybridBlock)):
         return layer.d_model
     if isinstance(layer, TCNBlock):
         return layer.filters
-    if isinstance(layer, (PositionalEncoding, PoolLayer)):
+    if isinstance(layer, (PositionalEncoding, PoolLayer, RMSNormLayer)):
         return in_dim
     raise TypeError(f"Unknown layer spec: {layer!r}")
 
@@ -187,6 +246,10 @@ def init_model_params(rng: jax.Array, spec: ModelSpec) -> Params:
             params.append(init_transformer_block(layer_rng, in_dim, layer))
         elif isinstance(layer, MoEBlock):
             params.append(init_moe_block(layer_rng, in_dim, layer))
+        elif isinstance(layer, HybridBlock):
+            params.append(init_hybrid_block(layer_rng, in_dim, layer))
+        elif isinstance(layer, RMSNormLayer):
+            params.append({"scale": jnp.ones((in_dim,), jnp.float32)})
         elif isinstance(layer, TCNBlock):
             params.append(init_tcn_block(layer_rng, in_dim, layer))
         elif isinstance(layer, (PositionalEncoding, PoolLayer)):
@@ -198,8 +261,9 @@ def init_model_params(rng: jax.Array, spec: ModelSpec) -> Params:
 
 
 # The named scopes here, in ops/train.py and in parallel/batch_trainer.py
-# (dense, lstm_input_proj, lstm_cell, lstm_weight_grad, attention,
-# window_gather, optimizer_update, fold_predict)
+# (dense, lstm_input_proj, lstm_cell, lstm_weight_grad, attention, rms_norm,
+# gated_conv, moe_router, moe_dispatch, moe_experts, window_gather,
+# optimizer_update, fold_predict)
 # are what a device trace is reduced by (scripts/trace_by_scope.py; the table
 # is in docs/observability.md): metadata only, and stable names, so rename
 # none. JAX writes jvp(...) / transpose(jvp(...)) into the op_name for the
@@ -519,6 +583,203 @@ def _apply_moe_block(
     return out, aux
 
 
+@jax.named_scope("rms_norm")
+def _rms_norm(x, scale, eps: float):
+    """``x * rsqrt(mean(x^2) + eps) * scale`` over the last axis, computed in
+    float32 whatever the compute dtype."""
+    x32 = x.astype(jnp.float32)
+    ms = jnp.mean(x32 * x32, axis=-1, keepdims=True)
+    return (x32 * jax.lax.rsqrt(ms + eps) * scale.astype(jnp.float32)).astype(x.dtype)
+
+
+def _rope(x, theta: float):
+    """Rotary position embedding, rotate-half convention, on (..., T, Dh):
+    pair ``i`` of the two halves turns by ``t * theta**(-2i/Dh)``."""
+    t, dh = x.shape[-2], x.shape[-1]
+    inv_freq = theta ** (-jnp.arange(0, dh, 2, dtype=jnp.float32) / dh)
+    angles = jnp.arange(t, dtype=jnp.float32)[:, None] * inv_freq[None, :]
+    cos = jnp.concatenate([jnp.cos(angles)] * 2, axis=-1)
+    sin = jnp.concatenate([jnp.sin(angles)] * 2, axis=-1)
+    x32 = x.astype(jnp.float32)
+    rotated = jnp.concatenate([-x32[..., dh // 2 :], x32[..., : dh // 2]], axis=-1)
+    return (x32 * cos + rotated * sin).astype(x.dtype)
+
+
+@jax.named_scope("gated_conv")
+def _gated_conv(layer: HybridBlock, p, h):
+    """``[b, c, u] = split(h @ W_in)``; ``y = c * conv(b * u)`` with a
+    depthwise causal convolution over time, ``conv(z)[t] = sum_j taps[:, j] *
+    z[t - (K-1) + j]`` and zeros before the start; out ``y @ W_out``."""
+    b, c, u = jnp.split(h @ p["conv_in"], 3, axis=-1)
+    z = b * u
+    kw, t = layer.conv_kernel, z.shape[1]
+    zp = jnp.pad(z, ((0, 0), (kw - 1, 0), (0, 0)))
+    conv = sum(zp[:, j : j + t, :] * p["conv_taps"][:, j] for j in range(kw))
+    return (c * conv) @ p["conv_out"]
+
+
+@jax.named_scope("attention")
+def _gqa_attention(layer: HybridBlock, p, h):
+    """Grouped-query causal attention: RMSNorm a head on q and k, RoPE, each
+    key/value head serving ``num_heads / num_kv_heads`` consecutive query
+    heads; through the dispatcher of ops/attention.py."""
+    bsz, t, _ = h.shape
+    dh, rep = layer.head_dim, layer.num_heads // layer.num_kv_heads
+
+    def heads(a, n):
+        return a.reshape(bsz, t, n, dh).transpose(0, 2, 1, 3)
+
+    q = heads(h @ p["wq"], layer.num_heads)
+    k = heads(h @ p["wk"], layer.num_kv_heads)
+    v = heads(h @ p["wv"], layer.num_kv_heads)
+    q = _rope(_rms_norm(q, p["q_norm"], layer.norm_eps), layer.rope_theta)
+    k = _rope(_rms_norm(k, p["k_norm"], layer.norm_eps), layer.rope_theta)
+    k, v = jnp.repeat(k, rep, axis=1), jnp.repeat(v, rep, axis=1)
+    impl = layer.attention_impl
+    out = dot_product_attention(
+        q, k, v, causal=True, impl=None if impl == "auto" else impl
+    )
+    return out.transpose(0, 2, 1, 3).reshape(bsz, t, layer.num_heads * dh) @ p["wo"]
+
+
+def _swiglu(p, h):
+    return (jax.nn.silu(h @ p["w1"]) * (h @ p["w3"])) @ p["w2"]
+
+
+# ---- grouped products over the experts held. Rows are sorted by group; group
+# g owns the next group_sizes[g] rows; rows past the live count (the sum)
+# belong to no group and give zeros, in the product and in its gradients.
+# jax.lax.ragged_dot is one kernel a product on the TPU, which takes no batch
+# dimension: under vmap (the fleet's machine axis, the server's model axis)
+# the products run one lane after another, and the gradients are written out
+# so that differentiation never meets the loop.
+_ROWS_BY_ROWS = jax.lax.RaggedDotDimensionNumbers(
+    dot_dimension_numbers=(((0,), (0,)), ((), ())),
+    lhs_ragged_dimensions=[0],
+    rhs_group_dimensions=[],
+)
+
+
+def _live_rows(rows, group_sizes, n_rows: int):
+    live = jnp.arange(n_rows) < jnp.sum(group_sizes)
+    return jnp.where(live[:, None], rows, jnp.zeros((), rows.dtype))
+
+
+@sequential_vmap
+def _grouped_rows(x, w, group_sizes):
+    """(R, K) rows by (G, K, N) weights → (R, N)."""
+    out = jax.lax.ragged_dot(x, w, group_sizes, preferred_element_type=jnp.float32)
+    return _live_rows(out, group_sizes, x.shape[0]).astype(x.dtype)
+
+
+@sequential_vmap
+def _grouped_weights(x, dy, group_sizes):
+    """(R, K) rows and (R, N) rows, contracted group by group → (G, K, N)."""
+    return jax.lax.ragged_dot_general(
+        x, dy, group_sizes, _ROWS_BY_ROWS, preferred_element_type=jnp.float32
+    )
+
+
+@jax.custom_vjp
+def grouped_matmul(x, w, group_sizes):
+    return _grouped_rows(x, w, group_sizes)
+
+
+def _grouped_matmul_fwd(x, w, group_sizes):
+    return _grouped_rows(x, w, group_sizes), (x, w, group_sizes)
+
+
+def _grouped_matmul_bwd(residuals, dy):
+    x, w, group_sizes = residuals
+    # the weights transposed by a copy: handed the contraction over the
+    # weights' last axis instead, the TPU compiler leaves its grouped kernel
+    # for a masked dense product over every group (compiled and read, PR 35)
+    dx = _grouped_rows(dy, jnp.swapaxes(w, 1, 2), group_sizes)
+    dw = _grouped_weights(x, dy, group_sizes).astype(w.dtype)
+    return dx, dw, None
+
+
+grouped_matmul.defvjp(_grouped_matmul_fwd, _grouped_matmul_bwd)
+
+
+@jax.custom_vjp
+def _permute_rows(x, perm, inverse):
+    """``x[perm]`` for a permutation whose inverse is known: the gradient is
+    a gather too (``g[inverse]``), where autodiff would scatter."""
+    return x[perm]
+
+
+_permute_rows.defvjp(
+    lambda x, perm, inverse: (x[perm], (perm, inverse)),
+    lambda res, g: (g[res[1]], None, None),
+)
+
+
+MOE_STATS = ("moe_held", "moe_absent", "moe_tokens", "moe_layer_steps", "moe_peak_load")
+
+
+def zero_stats(spec: ModelSpec) -> Dict[str, jnp.ndarray]:
+    """What :func:`apply_model_stats` counts for ``spec``, at zero: the sums'
+    start. Empty for a spec with no routed layer."""
+    routed = any(
+        isinstance(layer, HybridBlock) and layer.ffn == "routed"
+        for layer in spec.layers
+    )
+    return {key: jnp.zeros((), jnp.int32) for key in MOE_STATS} if routed else {}
+
+
+def routed_ffn(layer: HybridBlock, p, h):
+    """The routed FFN on (N, D) tokens: what the experts held here give.
+    Returns ``(out, stats)``; ``stats`` counts, for this layer and step, the
+    assignments to held and to absent experts, the tokens, and the fullest
+    held expert's assignments."""
+    n, d = h.shape
+    k, held_n = layer.top_k, layer.experts_held
+    with jax.named_scope("moe_router"):
+        # float32 in and out: routing is a decision, not an activation
+        scores = jax.nn.sigmoid(h.astype(jnp.float32) @ p["router"])
+        _, chosen = jax.lax.top_k(
+            jax.lax.stop_gradient(scores) + p["expert_bias"], k
+        )
+        gate = jnp.take_along_axis(scores, chosen, axis=-1)
+        gate = gate / (jnp.sum(gate, axis=-1, keepdims=True) + 1e-6)
+    with jax.named_scope("moe_dispatch"):
+        local = chosen.reshape(-1) - layer.expert_offset  # (N * k,)
+        # an assignment's group: its held expert, or one past them if absent
+        group = jnp.where((local >= 0) & (local < held_n), local, held_n)
+        # held assignments first, by expert; the absent ones after them
+        order = jnp.argsort(group, stable=True)
+        inverse = jnp.argsort(order)
+        group_sizes = jnp.sum(jax.nn.one_hot(group, held_n, dtype=jnp.int32), axis=0)
+        rows = _permute_rows(jnp.repeat(h, k, axis=0), order, inverse)
+    with jax.named_scope("moe_experts"):
+        mid = jax.nn.silu(grouped_matmul(rows, p["w1"], group_sizes))
+        mid = mid * grouped_matmul(rows, p["w3"], group_sizes)
+        rows = grouped_matmul(mid, p["w2"], group_sizes)
+    with jax.named_scope("moe_dispatch"):
+        back = _permute_rows(rows, inverse, order).reshape(n, k, d)
+        out = jnp.sum(back * gate[..., None].astype(back.dtype), axis=1)
+    n_held = jnp.sum(group_sizes)
+    counted = (n_held, n * k - n_held, n, 1, jnp.max(group_sizes))
+    return out, {
+        key: jnp.asarray(value, jnp.int32) for key, value in zip(MOE_STATS, counted)
+    }
+
+
+def _apply_hybrid_block(layer: HybridBlock, p, x):
+    """x: (batch, time, d_model) → ``(x, stats)``; ``stats`` is empty for a
+    dense FFN."""
+    h = _rms_norm(x, p["op_norm"], layer.norm_eps)
+    operator = _gated_conv if layer.operator == "conv" else _gqa_attention
+    x = x + operator(layer, p, h)
+    h = _rms_norm(x, p["ffn_norm"], layer.norm_eps)
+    if layer.ffn == "dense":
+        return x + _swiglu(p, h), {}
+    b, t, d = h.shape
+    ffn, stats = routed_ffn(layer, p, h.reshape(b * t, d))
+    return x + ffn.reshape(b, t, d), stats
+
+
 def _causal_conv1d(x, kernel, dilation: int):
     """Causal dilated conv. x: (..., time, c_in), kernel: (width, c_in, c_out).
 
@@ -585,6 +846,14 @@ def apply_model(spec: ModelSpec, params: Params, x: jnp.ndarray):
     factories/feedforward_autoencoder.py:78-85 — l1(1e-4) on non-first encoder
     layers), normalized by batch size to keep loss scale batch-invariant.
     """
+    out, penalty, _ = apply_model_stats(spec, params, x)
+    return out, penalty
+
+
+def apply_model_stats(spec: ModelSpec, params: Params, x: jnp.ndarray):
+    """:func:`apply_model` and, third, what the layers counted on the way: a
+    dict of int32 scalars summed over the spec's routed layers
+    (:func:`routed_ffn`), empty for a spec that has none."""
     compute_dtype = jnp.dtype(getattr(spec, "compute_dtype", "float32"))
     batch = x.shape[0]
     out = x
@@ -606,7 +875,8 @@ def apply_model(spec: ModelSpec, params: Params, x: jnp.ndarray):
 
         params = [
             {
-                k: (v if k == "router" and isinstance(layer, MoEBlock)
+                k: (v if k in ("router", "expert_bias")
+                    and isinstance(layer, (MoEBlock, HybridBlock))
                     else jax.tree_util.tree_map(_cast, v))
                 for k, v in p.items()
             }
@@ -645,6 +915,7 @@ def apply_model(spec: ModelSpec, params: Params, x: jnp.ndarray):
         return getattr(layer, "fuse_qkv", True) and not tp_active
 
     penalty = jnp.asarray(0.0, jnp.float32)
+    stats: Dict[str, jnp.ndarray] = {}
     for i, (layer, p) in enumerate(zip(spec.layers, params)):
         if pp_blocks and i in pp_blocks:
             if i != pp_blocks[0]:
@@ -692,6 +963,13 @@ def apply_model(spec: ModelSpec, params: Params, x: jnp.ndarray):
                     layer, p, out,
                 )
             penalty = penalty + aux
+        elif isinstance(layer, HybridBlock):
+            out, counted = _seq_layer(_apply_hybrid_block, layer, p, out)
+            stats = {
+                key: stats.get(key, 0) + value for key, value in counted.items()
+            }
+        elif isinstance(layer, RMSNormLayer):
+            out = _rms_norm(out, p["scale"], layer.eps)
         elif isinstance(layer, TCNBlock):
             out = _seq_layer(_apply_tcn_block, layer, p, out)
         elif isinstance(layer, PoolLayer):
@@ -700,4 +978,4 @@ def apply_model(spec: ModelSpec, params: Params, x: jnp.ndarray):
             raise TypeError(f"Unknown layer spec: {layer!r}")
     if out.dtype != jnp.float32:
         out = out.astype(jnp.float32)
-    return out, penalty
+    return out, penalty, stats

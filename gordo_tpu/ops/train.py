@@ -28,7 +28,7 @@ import numpy as np
 import optax
 
 from gordo_tpu.models.spec import ModelSpec, OptimizerSpec
-from .nn import apply_model
+from .nn import apply_model, apply_model_stats, zero_stats
 
 logger = logging.getLogger(__name__)
 
@@ -71,7 +71,9 @@ def make_optimizer(spec: OptimizerSpec) -> optax.GradientTransformation:
 
 
 def _loss_terms(spec: ModelSpec, params, xb, yb, wb):
-    out, penalty = apply_model(spec, params, xb)
+    """``(loss, stats)``: the weighted batch loss, and what the model's layers
+    counted on the way (:func:`gordo_tpu.ops.nn.apply_model_stats`)."""
+    out, penalty, stats = apply_model_stats(spec, params, xb)
     if spec.loss in ("mse", "mean_squared_error"):
         per_sample = jnp.mean((out - yb) ** 2, axis=-1)
     elif spec.loss in ("mae", "mean_absolute_error"):
@@ -79,7 +81,7 @@ def _loss_terms(spec: ModelSpec, params, xb, yb, wb):
     else:
         raise ValueError(f"Unknown loss {spec.loss!r}")
     w_sum = jnp.maximum(jnp.sum(wb), 1.0)
-    return jnp.sum(per_sample * wb) / w_sum + penalty
+    return jnp.sum(per_sample * wb) / w_sum + penalty, stats
 
 
 @jax.named_scope("window_gather")
@@ -139,9 +141,9 @@ def make_epoch_fn(
                 # batch axis split over the `data` mesh: GSPMD partitions
                 # fwd/bwd and all-reduces the grads (params replicated)
                 xb, yb, wb = batch_constraint(spec, xb, yb, wb)
-            loss, grads = jax.value_and_grad(_loss_terms, argnums=1)(
-                spec, params, xb, yb, wb
-            )
+            (loss, _), grads = jax.value_and_grad(
+                _loss_terms, argnums=1, has_aux=True
+            )(spec, params, xb, yb, wb)
             with jax.named_scope("optimizer_update"):
                 updates, opt_state = opt.update(grads, opt_state, params)
                 params = optax.apply_updates(params, updates)
@@ -185,7 +187,7 @@ def _build_eval_fn(spec: ModelSpec, n_samples: int, batch_size: int = 2048) -> C
             idx = jax.lax.dynamic_slice(idx_stream, (i * batch_size,), (batch_size,))
             wb = jax.lax.dynamic_slice(w_stream, (i * batch_size,), (batch_size,))
             xb, yb = _gather_batch(spec, X, y, idx)
-            loss = _loss_terms(spec, params, xb, yb, wb)
+            loss, _ = _loss_terms(spec, params, xb, yb, wb)
             bw = jnp.sum(wb)
             return (loss_sum + loss * bw, w_sum + bw), None
 
@@ -227,10 +229,15 @@ def make_masked_epoch_fn(
     machines, so under the machine vmap every lane ends at the same bound;
     a non-uniform caller still gets correct results (late lanes' steps are
     zero-weight masked no-ops), just max-lane timing.
+
+    Returns ``(params, opt_state, mean_loss, stats)``; ``stats`` is what the
+    model's layers counted (``apply_model_stats``), summed over the live
+    steps: an empty dict for a spec with no routed layer.
     """
     n_steps = max((n_max + batch_size - 1) // batch_size, 1)
     n_pad = n_steps * batch_size
     opt = make_optimizer(spec.optimizer)
+    grad_fn = jax.value_and_grad(_loss_terms, argnums=1, has_aux=True)
 
     def epoch(params, opt_state, X, y, rng, n_valid):
         pos = jnp.arange(n_max)
@@ -262,13 +269,11 @@ def make_masked_epoch_fn(
             return state[0] < n_live_steps
 
         def body(state):
-            i, params, opt_state, loss_sum, w_sum = state
+            i, params, opt_state, loss_sum, w_sum, stats_sum = state
             idx = jax.lax.dynamic_slice(idx_stream, (i * batch_size,), (batch_size,))
             wb = jax.lax.dynamic_slice(w_stream, (i * batch_size,), (batch_size,))
             xb, yb = _gather_batch(spec, X, y, idx)
-            loss, grads = jax.value_and_grad(_loss_terms, argnums=1)(
-                spec, params, xb, yb, wb
-            )
+            (loss, stats), grads = grad_fn(spec, params, xb, yb, wb)
             bw = jnp.sum(wb)
             live = bw > 0
             # the scope holds the live-step select too: XLA fuses the update
@@ -282,16 +287,23 @@ def make_masked_epoch_fn(
                 params = pick(new_params, params)
                 opt_state = pick(new_opt_state, opt_state)
             loss = jnp.where(live, loss, 0.0)
-            return (i + 1, params, opt_state, loss_sum + loss * bw, w_sum + bw)
+            stats_sum = {
+                key: total + jnp.where(live, stats[key], 0)
+                for key, total in stats_sum.items()
+            }
+            return (
+                i + 1, params, opt_state, loss_sum + loss * bw, w_sum + bw,
+                stats_sum,
+            )
 
         init = (
             jnp.asarray(0, n_live_steps.dtype), params, opt_state,
-            jnp.asarray(0.0), jnp.asarray(0.0),
+            jnp.asarray(0.0), jnp.asarray(0.0), zero_stats(spec),
         )
-        _, params, opt_state, loss_sum, w_sum = jax.lax.while_loop(
+        _, params, opt_state, loss_sum, w_sum, stats = jax.lax.while_loop(
             cond, body, init
         )
-        return params, opt_state, loss_sum / jnp.maximum(w_sum, 1.0)
+        return params, opt_state, loss_sum / jnp.maximum(w_sum, 1.0), stats
 
     return epoch
 

@@ -89,7 +89,7 @@ from gordo_tpu.models.anomaly.diff import (
 )
 from gordo_tpu.models.models import BaseJaxEstimator
 from gordo_tpu.models.spec import ModelSpec
-from gordo_tpu.ops.nn import apply_model, init_model_params
+from gordo_tpu.ops.nn import apply_model, init_model_params, zero_stats
 from gordo_tpu.ops.train import (
     make_masked_epoch_fn,
     make_optimizer,
@@ -402,6 +402,21 @@ def _predict_windows(spec: ModelSpec, params, X):
     return out
 
 
+def _note_layer_counts(counted: Dict[str, np.ndarray], rows, n_live: int) -> None:
+    """Add a chunk's routed-layer counts (a value a lane; padding lanes left
+    out) to the build's counters. Nothing to add for a spec that routes
+    nothing."""
+    if not counted:
+        return
+    live = np.asarray(rows) < n_live
+    total = {key: int(np.asarray(value)[live].sum()) for key, value in counted.items()}
+    metric_catalog.MOE_ASSIGNMENTS.labels(where="held").inc(total["moe_held"])
+    metric_catalog.MOE_ASSIGNMENTS.labels(where="absent").inc(total["moe_absent"])
+    metric_catalog.MOE_TOKENS.inc(total["moe_tokens"])
+    metric_catalog.MOE_LAYER_STEPS.inc(total["moe_layer_steps"])
+    metric_catalog.MOE_PEAK_LOAD.inc(total["moe_peak_load"])
+
+
 @functools.lru_cache(maxsize=64)
 def _bucket_program(
     spec: ModelSpec,
@@ -419,8 +434,10 @@ def _bucket_program(
     Compile the full per-machine build for one bucket:
     per-fold (scale → init → train → predict-test), then final fit.
     Returns a function of stacked (X, y, seeds) suitable for vmap, producing
-    ``(final_params, final_losses, fold_preds)`` with fold predictions
-    stacked on a leading fold axis.
+    ``(final_params, final_losses, fold_preds, stats)``: one prediction a
+    fold, and what the model's layers counted over every stage's live steps
+    (``ops/nn.apply_model_stats``; an empty dict for a spec with no routed
+    layer).
 
     The CV folds and the final fit all run through ONE ``lax.scan`` over
     "stages" sharing a single mask-padded fit body
@@ -479,7 +496,7 @@ def _bucket_program(
         warm = extra[len(extra) - 1] if warm_start else None
         rng = jax.random.fold_in(jax.random.PRNGKey(0), seed)
 
-        def stage(_, inp):
+        def stage(last, inp):
             if use_perms:
                 k, tr_row, n_valid, te_start, perm = inp
                 Xk, yk = X[perm], y[perm]
@@ -501,16 +518,20 @@ def _bucket_program(
             opt_state = opt.init(params)
 
             def epoch_body(carry, epoch_rng):
-                p, o = carry
-                p, o, loss = epoch_fn(p, o, Xs, yk, epoch_rng, n_valid)
-                return (p, o), loss
+                p, o, counted = carry
+                p, o, loss, stats = epoch_fn(p, o, Xs, yk, epoch_rng, n_valid)
+                counted = {key: counted[key] + stats[key] for key in counted}
+                return (p, o, counted), loss
 
-            (params, _), losses = jax.lax.scan(
-                epoch_body, (params, opt_state), jax.random.split(k_fit, epochs)
+            (params, _, counted), losses = jax.lax.scan(
+                epoch_body, (params, opt_state, last[2]),
+                jax.random.split(k_fit, epochs),
             )
             Xte = jax.lax.dynamic_slice(Xs, (te_start, 0), (te_len, Xs.shape[1]))
             pred = _predict_windows(spec, params, Xte)
-            return None, (params, losses, pred)
+            # the stage's parameters and losses are the carry: only the last
+            # stage's leave the scan, not a stack of every stage's
+            return (params, losses, counted), pred
 
         stages = (
             jnp.arange(n_folds + 1),
@@ -520,10 +541,17 @@ def _bucket_program(
         )
         if use_perms:
             stages = stages + (perms,)
-        _, (params_all, losses_all, preds_all) = jax.lax.scan(stage, None, stages)
-        p_final = jax.tree_util.tree_map(lambda a: a[-1], params_all)
+        start = (
+            jax.tree_util.tree_map(
+                lambda a: jnp.zeros(a.shape, a.dtype),
+                jax.eval_shape(lambda key: init_model_params(key, spec), rng),
+            ),
+            jnp.zeros((epochs,), jnp.float32),
+            zero_stats(spec),
+        )
+        (p_final, losses, counted), preds_all = jax.lax.scan(stage, start, stages)
         # one prediction a fold; the final stage's is not an output
-        return p_final, losses_all[-1], tuple(preds_all[k] for k in range(n_folds))
+        return p_final, losses, tuple(preds_all[k] for k in range(n_folds)), counted
 
     in_axes: Tuple = (0, 0, 0)
     if use_perms:
@@ -1684,13 +1712,15 @@ class BatchedModelBuilder:
 
         def fetch(group, outputs):
             with _stage("d2h"):
-                params_stack, losses, fold_preds = outputs
+                params_stack, losses, fold_preds, counted = outputs
                 if not multiprocess:
                     # one batched host transfer for the whole tree
                     losses_np = np.asarray(jax.device_get(losses))
+                    rows = np.arange(losses_np.shape[0])
+                    _note_layer_counts(jax.device_get(counted), rows, len(group))
                     return (
                         group,
-                        np.arange(losses_np.shape[0]),
+                        rows,
                         jax.device_get(params_stack),
                         losses_np,
                         [np.asarray(jax.device_get(fp)) for fp in fold_preds],
@@ -1699,6 +1729,10 @@ class BatchedModelBuilder:
                 # output shares the machines sharding, so the rows from `losses`
                 # apply to all leaves
                 rows, losses_np = distributed.local_rows(losses)
+                _note_layer_counts(
+                    {k: distributed.local_rows(v)[1] for k, v in counted.items()},
+                    rows, len(group),
+                )
                 params_np = jax.tree_util.tree_map(
                     lambda a: distributed.local_rows(a)[1], params_stack
                 )

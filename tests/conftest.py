@@ -14,6 +14,40 @@ from gordo_tpu.builder.local_build import local_build
 from gordo_tpu.dataset import SensorTag
 
 
+def _under_xdist(config) -> bool:
+    return getattr(config.option, "dist", "no") != "no"
+
+
+def pytest_configure(config):
+    """Under xdist (``-n 6 --dist loadfile``, the tier-1 command) the files
+    go out in the order :func:`pytest_collection_modifyitems` leaves them
+    in, not in xdist's own."""
+    if _under_xdist(config) and hasattr(config.option, "loadscopereorder"):
+        config.option.loadscopereorder = False
+
+
+def pytest_collection_modifyitems(config, items):
+    """xdist's order, largest file first, with tests/chipbench/ put before
+    the rest, so that it runs on fresh workers. Its
+    ``test_a_stage_starts_beside_nothing`` adds up the whole process's
+    ``jax.live_arrays()`` with 15.9 KB of room, and a worker that has run
+    test_server, test_batcher, test_pipeline_parallel or test_expert_parallel
+    keeps 40-460 KB live (``CrossModelBatcher``'s dispatcher threads never
+    stop, and hold their banks and their last batch): largest-first alone put
+    those ahead of it. A run in one process keeps its collection order."""
+    if not _under_xdist(config):
+        return
+    size = {}
+    for item in items:
+        path = item.nodeid.split("::", 1)[0]
+        size[path] = size.get(path, 0) + 1
+    # a stable sort: files of one size, and a file's tests, stay as collected
+    items.sort(key=lambda item: (
+        "tests/chipbench/" not in item.nodeid,
+        -size[item.nodeid.split("::", 1)[0]],
+    ))
+
+
 @pytest.fixture(scope="session")
 def sensors():
     return [SensorTag(f"tag-{i}", asset="asset") for i in range(4)]

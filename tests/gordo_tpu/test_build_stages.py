@@ -251,6 +251,25 @@ SCOPES = {
                 lookback_window: 4""",
         ("attention", "dense", "window_gather", "optimizer_update", "fold_predict"),
     ),
+    "hybrid": (
+        """gordo_tpu.models.models.TransformerAutoEncoder:
+                kind: hybrid_moe_model
+                d_model: 8
+                operators: [conv, attention]
+                ffns: [dense, routed]
+                ff_dim: 8
+                expert_dim: 8
+                num_heads: 2
+                num_kv_heads: 1
+                head_dim: 4
+                num_experts: 4
+                experts_held: 2
+                top_k: 2
+                lookback_window: 4""",
+        ("gated_conv", "attention", "rms_norm", "moe_router", "moe_dispatch",
+         "moe_experts", "dense", "window_gather", "optimizer_update",
+         "fold_predict"),
+    ),
 }
 
 

@@ -31,8 +31,11 @@ def _spec(est):
         TransformerAutoEncoder(
             kind="moe_transformer_model", lookback_window=16, num_experts=4
         ),
+        TransformerAutoEncoder(
+            kind="hybrid_moe_model", lookback_window=16, experts_held=4
+        ),
     ],
-    ids=["hourglass", "lstm", "transformer", "moe"],
+    ids=["hourglass", "lstm", "transformer", "moe", "hybrid"],
 )
 def test_param_count_matches_initialized_tree(est):
     """The layer-walk parameter count must match the real pytree — the same
@@ -69,6 +72,27 @@ def test_forward_flops_scale_with_window_and_width():
         flops_mod.forward_flops_per_sample(tr64)
         > 4.0 * flops_mod.forward_flops_per_sample(tr16)
     )
+
+
+def test_hybrid_block_flops_are_the_benchmarks_count():
+    """The serving gauge's walk and the benchmark's own count of the same
+    configuration (``chipbench/configs/lfm2_moe.py``) agree to the last
+    operation, and the parameter walk is exact for this block."""
+    import json
+    import os
+
+    from chipbench import flops as bench_flops
+
+    root = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+    with open(os.path.join(root, "chipbench", "rehearsal", "lfm2_tiny.json")) as fh:
+        config = json.load(fh)
+    model = {k: v for k, v in config["model"].items() if k not in ("kind", "batch_size", "epochs", "compute_dtype")}
+    n_tags = config["n_tags"]
+    spec = TransformerAutoEncoder(kind="hybrid_moe_model", **model).build_spec(n_tags, n_tags)
+    assert flops_mod.forward_flops_per_sample(spec) == bench_flops.forward_flops_per_window(config)
+    params = init_model_params(jax.random.PRNGKey(0), spec)
+    actual = sum(int(np.prod(leaf.shape)) for leaf in jax.tree_util.tree_leaves(params))
+    assert flops_mod.spec_param_count(spec) == actual
 
 
 def test_mfu_and_peak_lookup():
