@@ -87,6 +87,11 @@ MOE_PEAK_LOAD = telemetry.counter(
     "Assignments the fullest held expert took, summed over routed layers "
     "and live training steps (over held / experts_held a layer-step: 1 is even)",
 )
+ARTIFACT_BYTES = telemetry.counter(
+    "gordo_build_artifact_bytes_total",
+    "Bytes of model.pkl as the fleet build wrote them (over the serialize "
+    "phase's seconds: the write's rate)",
+)
 COMPILE_SECONDS_SAVED = telemetry.counter(
     "gordo_build_compile_seconds_saved_total",
     "Estimated compile seconds avoided by bucket-program cache hits "

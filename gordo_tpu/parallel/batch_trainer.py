@@ -45,11 +45,13 @@ The pipeline of one ``build()``, in the order of this file:
    (``_fold_geometry``) and the program (``_program_for`` →
    ``_bucket_program``) from the bucket's first arrival, then fixed-width
    chunks, two in flight; a later chunk waits for its own fetches just
-   before it is stacked. ``_build_bucket_guarded`` is the recovery ladder
-   round it.
+   before it is stacked. Behind each chunk's program goes the verdict on
+   its parameters (``_verdict_program``: one bool a lane, on the device).
+   ``_build_bucket_guarded`` is the recovery ladder round it.
 5. **assembly** (``_assemble_and_persist``, on a pool, chunk by chunk under
    the device): a machine's slice of the chunk's outputs and its divergence
-   check, thresholds, scores, metadata with the chunk's own durations, the
+   check (the losses here, the parameters by the device's verdict),
+   thresholds, scores, metadata with the chunk's own durations, the
    artifact's dump and its registry keys. A machine is final when its job
    returns: the bucket's end adds nothing to any artifact.
 """
@@ -655,6 +657,28 @@ def _program_for(
     return program, key, cached
 
 
+@functools.lru_cache(maxsize=8)
+def _verdict_program(out_sharding=None):
+    """The divergence verdict where the parameters are: one bool a lane of a
+    chunk's stacked parameters, true where any floating leaf of the lane
+    holds a NaN or an infinity (what ``faults.params_non_finite`` says of
+    the lane's slice on the host, a pass over every byte of it). A program
+    of its own, so the chunk program's compile-cache entry stays what it
+    was. ``out_sharding`` as in ``_bucket_program``: across processes the
+    flags keep the machines sharding."""
+
+    def lanes_non_finite(params_stack):
+        leaves = jax.tree_util.tree_leaves(params_stack)
+        bad = jnp.zeros((leaves[0].shape[0],), jnp.bool_)
+        for leaf in leaves:
+            if jnp.issubdtype(leaf.dtype, jnp.floating):
+                finite = jnp.isfinite(leaf).reshape(leaf.shape[0], -1)
+                bad = bad | ~jnp.all(finite, axis=1)
+        return bad
+
+    return jax.jit(lanes_non_finite, out_shardings=out_sharding)
+
+
 # ------------------------------------------------- vectorized fold metrics
 def _note_shard_devices(stacked_data, params_stack) -> None:
     """Record over how many devices a chunk's stacked data and trained
@@ -1013,6 +1037,9 @@ class BatchedModelBuilder:
             "serialize", _PHASE_SERIALIZE, machine=machine_out.name
         ):
             serializer.dump(model, model_dir, metadata=machine_out.to_dict())
+        metric_catalog.ARTIFACT_BYTES.inc(
+            os.path.getsize(os.path.join(model_dir, "model.pkl"))
+        )
         # build-to-serve (ISSUE 14): ship the fused serving executables
         # alongside the params so a cold serving node deserializes instead
         # of compiling. Best-effort — a shipping failure costs warmth on
@@ -1627,6 +1654,7 @@ class BatchedModelBuilder:
                 plan0, n_rows, fold_bounds, perms is not None,
                 sharding if multiprocess else None,
             )
+            verdict = _verdict_program(sharding if multiprocess else None)
             perms_d = None
             if perms is not None:
                 from jax.sharding import NamedSharding, PartitionSpec
@@ -1692,8 +1720,12 @@ class BatchedModelBuilder:
                 # returns once the execution is queued (the first call
                 # compiles or loads the program before that)
                 outputs = program(*args)
+                # queued behind this chunk and before the next one's launch:
+                # wait() covers it, and fetch() never waits for it behind a
+                # later chunk's execution
+                non_finite = verdict(outputs[0])
                 _note_shard_devices(X_d, outputs[0])
-            return group, outputs
+            return group, (*outputs, non_finite)
 
         # the completion of the chunk before: the bucket's start for the first
         chunk_done = t0
@@ -1712,7 +1744,7 @@ class BatchedModelBuilder:
 
         def fetch(group, outputs):
             with _stage("d2h"):
-                params_stack, losses, fold_preds, counted = outputs
+                params_stack, losses, fold_preds, counted, non_finite = outputs
                 if not multiprocess:
                     # one batched host transfer for the whole tree
                     losses_np = np.asarray(jax.device_get(losses))
@@ -1724,6 +1756,7 @@ class BatchedModelBuilder:
                         jax.device_get(params_stack),
                         losses_np,
                         [np.asarray(jax.device_get(fp)) for fp in fold_preds],
+                        np.asarray(jax.device_get(non_finite)),
                     )
                 # multi-process: only this host's rows are addressable; every
                 # output shares the machines sharding, so the rows from `losses`
@@ -1737,7 +1770,8 @@ class BatchedModelBuilder:
                     lambda a: distributed.local_rows(a)[1], params_stack
                 )
                 fold_preds_np = [distributed.local_rows(fp)[1] for fp in fold_preds]
-                return group, rows, params_np, losses_np, fold_preds_np
+                non_finite_np = distributed.local_rows(non_finite)[1]
+                return group, rows, params_np, losses_np, fold_preds_np, non_finite_np
 
         # host-side assembly per machine (its slice of the chunk, threshold
         # stats, scores, metadata, the dump: ~25ms each on the chip's host,
@@ -1824,12 +1858,13 @@ class BatchedModelBuilder:
 
     # --------------------------------------------------------- assembly
     def _assemble_and_persist(
-        self, plan: _Plan, j: int, params_stack, losses, fold_preds,
+        self, plan: _Plan, j: int, params_stack, losses, fold_preds, non_finite,
         fold_bounds, per_machine: float, kfold_folds=None,
     ):
         """The pool job that makes one machine final, once: lane ``j`` of its
-        chunk's outputs sliced and checked, the machine assembled and its
-        artifact written. Its durations are its share of its chunk's wall
+        chunk's outputs sliced and checked (its losses here, its parameters
+        by ``non_finite[j]``, the device's verdict), the machine assembled and
+        its artifact written. Its durations are its share of its chunk's wall
         (``per_machine``: the wall over the chunk's live lanes), split by
         fold count, since the fused program interleaves CV-fold training
         with the final fit. Returns ``(model, machine)``; for a lane that
@@ -1845,7 +1880,13 @@ class BatchedModelBuilder:
             # post-build divergence detection: a lane that trained to NaN/Inf
             # params (bad lr, degenerate data) is quarantined — its garbage
             # must not be persisted as a servable artifact
-            bad = faults.params_non_finite(params, losses[j])
+            bad = faults.params_non_finite(None, losses[j])
+            if bad is None and non_finite[j]:
+                # only a lane the device flagged is walked, to name the leaf
+                bad = (
+                    faults.params_non_finite(params)
+                    or "non-finite model parameters"
+                )
             if bad is None and faults.should_fire("diverge", name):
                 bad = "injected divergence"
             if bad is not None:

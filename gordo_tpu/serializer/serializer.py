@@ -6,15 +6,24 @@ Reference parity: gordo/serializer/serializer.py:22-170 — ``dump`` writes
 are the raw-bytes forms used by the /download-model route.
 
 Our JAX estimators implement ``__getstate__``/``__setstate__`` so their
-parameter pytrees serialize as flax msgpack bytes inside the pickle (the
-TPU-native analog of the reference's h5-inside-pickle trick,
+parameter pytrees go into the pickle as host numpy arrays (the TPU-native
+analog of the reference's h5-inside-pickle trick,
 gordo/machine/model/models.py:183-208). Pickle remains the envelope because
 arbitrary fitted sklearn preprocessing steps must round-trip too.
+
+One format, protocol 5: numpy reduces a contiguous array to a
+``PickleBuffer`` there, and the C pickler hands a large in-band buffer
+straight to ``file.write``, where protocol 4 copied every array into a
+fresh ``bytes`` first (``tobytes``). ``pickle.load`` reads either, so an
+artifact from before still loads. A leaf written from a read-only array
+(what ``jax.device_get`` returns) loads read-only.
 """
 
 import os
 import pickle
 from typing import Any, Optional, Union
+
+PICKLE_PROTOCOL = 5
 
 try:
     import simplejson
@@ -24,7 +33,7 @@ except ImportError:  # pragma: no cover - environment-dependent
 
 def dumps(model: Any) -> bytes:
     """Serialize a model/pipeline to bytes (loadable with :func:`loads`)."""
-    return pickle.dumps(model)
+    return pickle.dumps(model, protocol=PICKLE_PROTOCOL)
 
 
 def loads(bytes_object: bytes) -> Any:
@@ -107,7 +116,7 @@ def dump(obj: object, dest_dir: Union[os.PathLike, str], metadata: dict = None):
     os.makedirs(dest_dir, exist_ok=True)
     _atomic_write(
         os.path.join(dest_dir, "model.pkl"),
-        lambda f: pickle.dump(obj, f),
+        lambda f: pickle.dump(obj, f, protocol=PICKLE_PROTOCOL),
         "wb",
     )
     if metadata is not None:

@@ -33,14 +33,14 @@ HOURGLASS = """gordo_tpu.models.models.AutoEncoder:
                 kind: feedforward_hourglass"""
 
 
-def _machines(prefix, estimator=HOURGLASS, n=2 * CHUNKS):
+def _machines(prefix, estimator=HOURGLASS, n=2 * CHUNKS, end="2019-01-02T00:00:00+00:00"):
     blocks = "".join(
         f"""
   - name: {prefix}-{i}
     dataset:
       tags: [{prefix}-{i}-a, {prefix}-{i}-b, {prefix}-{i}-c]
       train_start_date: '2019-01-01T00:00:00+00:00'
-      train_end_date: '2019-01-02T00:00:00+00:00'
+      train_end_date: '{end}'
       data_provider: {{type: RandomDataProvider}}
     model:
       gordo_tpu.models.anomaly.diff.DiffBasedAnomalyDetector:
