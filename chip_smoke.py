@@ -60,7 +60,9 @@ _JAX_LEGS = ("kernel", "windowed")
 
 # ------------------------------------------------------------------ sizes
 # (T, head dim): both ends of the T range and both head dims that
-# ops/attention._flash_ok admits
+# ops/attention._flash_ok admits (one width for q, k and v: a kernel for heads
+# of 192 / 128 was compiled and compared on the chip, lost to the XLA path and
+# was taken out again, so there is no corner for it here; PERF.md section 6)
 FLASH_CORNERS = ((256, 64), (256, 128), (4096, 64), (4096, 128))
 # the config-driven kernel check: one Transformer machine whose attention is
 # the kernel (d_model / num_heads = head dim 64, lookback = T = 256)

@@ -6,6 +6,7 @@ from .feedforward_autoencoder import (
 from .lstm_autoencoder import lstm_model, lstm_symmetric, lstm_hourglass
 from .transformer import transformer_model
 from .hybrid import hybrid_moe_model
+from .latent import latent_moe_model
 from .tcn import tcn_model
 
 __all__ = [
@@ -17,5 +18,6 @@ __all__ = [
     "lstm_hourglass",
     "transformer_model",
     "hybrid_moe_model",
+    "latent_moe_model",
     "tcn_model",
 ]

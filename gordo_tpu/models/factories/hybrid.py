@@ -22,6 +22,23 @@ from gordo_tpu.models.spec import (
 from .feedforward_autoencoder import _optimizer_spec
 
 
+def held_experts(
+    num_experts: int, experts_held: Optional[int], expert_offset: int, top_k: int
+) -> int:
+    """How many experts a routed layer holds (all of them by default), once
+    the share ``expert_offset`` … ``+ experts_held`` and ``top_k`` are checked
+    against the router's ``num_experts``."""
+    held = int(num_experts if experts_held is None else experts_held)
+    if not 0 < held <= num_experts - expert_offset or expert_offset < 0:
+        raise ValueError(
+            f"experts {expert_offset}..{expert_offset + held} are not among "
+            f"the router's {num_experts}"
+        )
+    if top_k > num_experts:
+        raise ValueError(f"top_k {top_k} exceeds num_experts {num_experts}")
+    return held
+
+
 @register_model_builder(type="TransformerAutoEncoder")
 @register_model_builder(type="TransformerForecast")
 def hybrid_moe_model(
@@ -71,14 +88,7 @@ def hybrid_moe_model(
         raise ValueError(
             f"num_heads {num_heads} is not a multiple of num_kv_heads {num_kv_heads}"
         )
-    held = int(num_experts if experts_held is None else experts_held)
-    if not 0 < held <= num_experts - expert_offset or expert_offset < 0:
-        raise ValueError(
-            f"experts {expert_offset}..{expert_offset + held} are not among "
-            f"the router's {num_experts}"
-        )
-    if top_k > num_experts:
-        raise ValueError(f"top_k {top_k} exceeds num_experts {num_experts}")
+    held = held_experts(num_experts, experts_held, expert_offset, top_k)
     for kinds, allowed in ((operators, ("conv", "attention")), (ffns, ("dense", "routed"))):
         unknown = set(kinds) - set(allowed)
         if unknown:

@@ -130,6 +130,73 @@ class HybridBlock:
 
 
 @dataclass(frozen=True)
+class LatentBlock:
+    """
+    The layer of the Xing4.0 / DeepSeek-V2 family over ``streams`` residual
+    streams (equations in ops/nn.py): two sublayers ``F``, latent attention
+    then an FFN, each read and written through manifold-constrained
+    hyper-connections (arXiv:2512.24880): ``X <- H_res X + H_post^T
+    F(norm(H_pre X))`` on a state ``X`` of ``streams x d_model`` a token,
+    ``H_res`` made doubly stochastic by ``sinkhorn_iters`` Sinkhorn
+    iterations. Input and output are (streams, batch, time, d_model): a
+    :class:`StreamLayer` expands before the first block and collapses after
+    the last.
+
+    Latent attention: queries through a rank-``q_lora_rank`` latent, keys and
+    values through a rank-``kv_lora_rank`` latent (an RMSNorm inside each),
+    ``num_heads`` heads whose queries and keys are ``qk_nope_head_dim +
+    qk_rope_head_dim`` wide and whose values are ``v_head_dim`` wide; the
+    rotary part of the keys is one head that all heads share; YaRN
+    frequencies where ``rope_factor`` > 1. ``ffn`` is a SwiGLU of ``ff_dim``
+    (``dense``) or ``routed``: as :class:`HybridBlock`'s (``top_k`` of
+    ``num_experts`` by sigmoid score, ``experts_held`` from ``expert_offset``
+    computed here, none dropped), the weights times ``routed_scale``, beside
+    ``shared_experts`` experts of ``ff_dim`` that every token takes.
+    """
+
+    d_model: int
+    ffn: str = "dense"
+    ff_dim: int = 128
+    num_heads: int = 4
+    q_lora_rank: int = 24
+    kv_lora_rank: int = 16
+    qk_nope_head_dim: int = 16
+    qk_rope_head_dim: int = 8
+    v_head_dim: int = 16
+    rope_theta: float = 10000.0
+    # YaRN (factor 1: plain RoPE, and the five below are not read)
+    rope_factor: float = 1.0
+    rope_original_max: int = 4096
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_mscale: float = 1.0
+    rope_mscale_all_dim: float = 0.0
+    num_experts: int = 8
+    experts_held: int = 8
+    expert_offset: int = 0
+    top_k: int = 2
+    shared_experts: int = 0
+    routed_scale: float = 1.0
+    streams: int = 1
+    sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    hc_clamp: float = 30.0
+    norm_eps: float = 1e-6
+    # attention implementation, as TransformerBlock's: auto | xla | flash
+    attention_impl: str = "auto"
+
+
+@dataclass(frozen=True)
+class StreamLayer:
+    """``expand``: (batch, time, d) copied into ``streams`` residual streams,
+    (streams, batch, time, d); ``collapse``: the streams summed back
+    (hyper-connections, arXiv:2409.19606). No parameters."""
+
+    mode: str = "expand"
+    streams: int = 1
+
+
+@dataclass(frozen=True)
 class RMSNormLayer:
     """``x * rsqrt(mean(x^2) + eps) * scale`` over the feature axis."""
 
@@ -164,6 +231,8 @@ LayerSpec = Union[
     TransformerBlock,
     MoEBlock,
     HybridBlock,
+    LatentBlock,
+    StreamLayer,
     RMSNormLayer,
     TCNBlock,
     PoolLayer,

@@ -87,6 +87,17 @@ MOE_PEAK_LOAD = telemetry.counter(
     "Assignments the fullest held expert took, summed over routed layers "
     "and live training steps (over held / experts_held a layer-step: 1 is even)",
 )
+HC_SUBLAYER_STEPS = telemetry.counter(
+    "gordo_build_hc_sublayer_steps_total",
+    "Sublayers mixed through residual streams (a latent block has two) "
+    "times live training steps",
+)
+HC_STOCHASTIC_GAP = telemetry.counter(
+    "gordo_build_hc_stochastic_gap_total",
+    "Summed over those sublayer-steps: the mean over tokens of the largest "
+    "|row or column sum - 1| of the streams' mixing matrix after its last "
+    "Sinkhorn iteration (over the sublayer-steps: 0 is doubly stochastic)",
+)
 ARTIFACT_BYTES = telemetry.counter(
     "gordo_build_artifact_bytes_total",
     "Bytes of model.pkl as the fleet build wrote them (over the serialize "

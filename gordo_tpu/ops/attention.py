@@ -47,16 +47,20 @@ def merge_heads(x: jnp.ndarray) -> jnp.ndarray:
 
 
 def dot_product_attention_xla(
-    q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, causal: bool = False
+    q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, causal: bool = False,
+    scale: float = None,
 ) -> jnp.ndarray:
     """
-    Reference attention. q, k, v: (..., T, Dh) with any leading batch dims.
+    Reference attention. q, k: (..., T, Dh) and v: (..., T, Dv) with any
+    leading batch dims; the values' width need not be the queries'. ``scale``
+    multiplies the scores: ``Dh ** -0.5`` unless given.
 
     Softmax is computed in float32 regardless of input dtype (bfloat16-safe),
     matching the flash kernel's accumulator precision.
     """
     dh = q.shape[-1]
-    scale = 1.0 / jnp.sqrt(jnp.asarray(dh, jnp.float32))
+    if scale is None:
+        scale = 1.0 / jnp.sqrt(jnp.asarray(dh, jnp.float32))
     logits = jnp.einsum("...qd,...kd->...qk", q, k).astype(jnp.float32) * scale
     if causal:
         t_q, t_k = logits.shape[-2], logits.shape[-1]
@@ -144,11 +148,13 @@ def spec_may_use_ring(spec) -> bool:
     )
 
 
-def _flash_ok(q: jnp.ndarray, k: jnp.ndarray) -> bool:
+def _flash_ok(
+    q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray = None, scale: float = None
+) -> bool:
     """
     Whether ``auto`` sends these shapes to the Pallas flash kernel: on a TPU,
     self-attention (equal Q/K lengths), T a multiple of the kernel's 128-row
-    blocks between 256 and 4096, and a head dim of 64 or 128.
+    blocks between 256 and 4096, and one head dim of 64 or 128 for q, k and v.
 
     Those are the (T, head dim) corners ``chip_smoke.py`` compiles and
     compares on the chip, forward and backward, f32 and bf16. The head dim is
@@ -165,6 +171,15 @@ def _flash_ok(q: jnp.ndarray, k: jnp.ndarray) -> bool:
     heads narrower than 64 lanes is not measured, and until a benchmark cell
     decides it those shapes stay on the XLA path. Longer sequences belong to
     ring attention (parallel/ring_attention.py).
+
+    Heads whose queries and keys are wider than their values (latent
+    attention: 192 / 128, with a softmax scale of its own) stay on the XLA
+    path: the kernel takes one width and the default scale, and
+    ``impl="flash"`` refuses anything else. A kernel widened to the two widths
+    was compiled and compared at (T 256, 192 / 128) on the chip and lost: 3.40
+    ms forward + backward for 16 windows x 32 heads in bfloat16 against the XLA
+    path's 1.81 ms, 3.46 ms with q and k zero-padded to 256 lanes (chip run,
+    PR 37, PERF.md section 6). The loser was taken out again.
     """
     if jax.default_backend() != "tpu":
         return False
@@ -174,6 +189,8 @@ def _flash_ok(q: jnp.ndarray, k: jnp.ndarray) -> bool:
         and t % 128 == 0
         and 256 <= t <= 4096
         and dh in (64, 128)
+        and (v is None or v.shape[-1] == dh)
+        and scale is None
     )
 
 
@@ -183,9 +200,11 @@ def dot_product_attention(
     v: jnp.ndarray,
     causal: bool = False,
     impl: str = None,
+    scale: float = None,
 ) -> jnp.ndarray:
     """
-    Dispatching attention over (..., T, Dh) tensors.
+    Dispatching attention over q, k: (..., T, Dh) and v: (..., T, Dv)
+    tensors; ``scale`` multiplies the scores (``Dh ** -0.5`` unless given).
 
     Deliberately not jitted at this level: the impl choice (including the
     ``GORDO_TPU_ATTENTION_IMPL`` env override) must be re-read per call, not
@@ -205,7 +224,13 @@ def dot_product_attention(
         ):
             impl = "ring"
         else:
-            impl = "flash" if _flash_ok(q, k) else "xla"
+            impl = "flash" if _flash_ok(q, k, v, scale) else "xla"
+    if impl in ("ring", "flash") and (
+        scale is not None or v.shape[-1] != q.shape[-1]
+    ):
+        raise ValueError(
+            f"{impl} attention takes one head width and the default scale"
+        )
     if impl == "ring":
         return ring_attention(q, k, v, causal=causal)
     if impl == "flash":
@@ -213,7 +238,7 @@ def dot_product_attention(
 
         return flash_attention(q, k, v, causal=causal)
     if impl == "xla":
-        return dot_product_attention_xla(q, k, v, causal=causal)
+        return dot_product_attention_xla(q, k, v, causal=causal, scale=scale)
     raise ValueError(f"Unknown attention impl {impl!r}")
 
 

@@ -22,12 +22,14 @@ from typing import Optional
 from gordo_tpu.models.spec import (
     DenseLayer,
     HybridBlock,
+    LatentBlock,
     LSTMLayer,
     ModelSpec,
     MoEBlock,
     PoolLayer,
     PositionalEncoding,
     RMSNormLayer,
+    StreamLayer,
     TCNBlock,
     TransformerBlock,
 )
@@ -86,13 +88,36 @@ def forward_flops_per_sample(spec: ModelSpec) -> float:
                 load = layer.top_k * layer.experts_held / layer.num_experts
                 total += 2.0 * d * layer.num_experts * T
                 total += load * 6.0 * d * layer.ff_dim * T
+        elif isinstance(layer, LatentBlock):
+            d, n, heads = layer.d_model, layer.streams, layer.num_heads
+            qk = layer.qk_nope_head_dim + layer.qk_rope_head_dim
+            up_kv = layer.qk_nope_head_dim + layer.v_head_dim
+            # down- and up-projections of the two latents, and the output's
+            total += 2.0 * T * (
+                d * layer.q_lora_rank + layer.q_lora_rank * heads * qk
+                + d * (layer.kv_lora_rank + layer.qk_rope_head_dim)
+                + layer.kv_lora_rank * heads * up_kv
+                + heads * layer.v_head_dim * d
+            )
+            # causal: half of scores (T x qk x T) + weighted values (T x T x v)
+            total += T * T * heads * (qk + layer.v_head_dim)
+            # the two sublayers' coefficient products; the mixing itself
+            # (H_pre X, H_res X, H_post^T y) is counted as products too
+            total += 2 * 2.0 * n * d * (2 * n + n * n) * T
+            total += 2 * 2.0 * (n + n * n + n) * d * T
+            if layer.ffn == "dense":
+                total += 6.0 * d * layer.ff_dim * T
+            else:
+                load = layer.top_k * layer.experts_held / layer.num_experts
+                total += 2.0 * d * layer.num_experts * T
+                total += (load + layer.shared_experts) * 6.0 * d * layer.ff_dim * T
         elif isinstance(layer, TCNBlock):
             # two causal dilated convs (+ a possible 1x1 residual projection)
             k, f = layer.kernel_size, layer.filters
             total += 2.0 * k * in_dim * f * T + 2.0 * k * f * f * T
             if in_dim != f:
                 total += 2.0 * in_dim * f * T
-        elif isinstance(layer, (PoolLayer, PositionalEncoding, RMSNormLayer)):
+        elif isinstance(layer, (PoolLayer, PositionalEncoding, RMSNormLayer, StreamLayer)):
             if isinstance(layer, PoolLayer):
                 seq = False
         in_dim = layer_out_dim(layer, in_dim)
@@ -178,6 +203,21 @@ def spec_param_count(spec: ModelSpec) -> int:
             else:
                 total += (d + 1) * layer.num_experts + layer.experts_held * 3 * d * f
             total += 2 * d
+        elif isinstance(layer, LatentBlock):
+            d, f, n, heads = layer.d_model, layer.ff_dim, layer.streams, layer.num_heads
+            qk = layer.qk_nope_head_dim + layer.qk_rope_head_dim
+            total += d * layer.q_lora_rank + layer.q_lora_rank * (1 + heads * qk)
+            total += d * (layer.kv_lora_rank + layer.qk_rope_head_dim)
+            total += layer.kv_lora_rank * (
+                1 + heads * (layer.qk_nope_head_dim + layer.v_head_dim)
+            )
+            total += heads * layer.v_head_dim * d
+            if layer.ffn == "dense":
+                total += 3 * d * f
+            else:
+                total += (d + 1) * layer.num_experts
+                total += (layer.experts_held + layer.shared_experts) * 3 * d * f
+            total += 2 * d + 2 * (n * d * (2 * n + n * n) + 3 + 2 * n + n * n)
         elif isinstance(layer, RMSNormLayer):
             total += in_dim
         elif isinstance(layer, TCNBlock):

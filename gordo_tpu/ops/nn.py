@@ -11,21 +11,25 @@ and ``apply_model`` is a pure function of (spec, params, x).
 """
 
 import functools
+import math
 from typing import Any, Dict, List
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.custom_batching import sequential_vmap
 
 from gordo_tpu.models.spec import (
     DenseLayer,
     HybridBlock,
+    LatentBlock,
     LSTMLayer,
     ModelSpec,
     MoEBlock,
     PoolLayer,
     PositionalEncoding,
     RMSNormLayer,
+    StreamLayer,
     TCNBlock,
     TransformerBlock,
 )
@@ -152,6 +156,30 @@ def _normal(rng, shape, std=0.02):
     return std * jax.random.normal(rng, shape, jnp.float32)
 
 
+def _init_ffn(k_ffn, layer, p) -> None:
+    """The FFN's leaves of a hybrid or latent block, into ``p``: SwiGLU of
+    ``ff_dim`` (``dense``), or the router, the zero selection bias and the
+    held experts' stacked SwiGLUs (``routed``)."""
+    d, f = layer.d_model, layer.ff_dim
+    if layer.ffn == "dense":
+        ks = jax.random.split(k_ffn, 3)
+        p["w1"] = _normal(ks[0], (d, f))
+        p["w3"] = _normal(ks[1], (d, f))
+        p["w2"] = _normal(ks[2], (f, d))
+    elif layer.ffn == "routed":
+        ks = jax.random.split(k_ffn, 4)
+        held = layer.experts_held
+        p["router"] = _normal(ks[0], (d, layer.num_experts))
+        # enters the selection only: no gradient reaches it, and its update
+        # rule (not in the published config) is not implemented
+        p["expert_bias"] = jnp.zeros((layer.num_experts,), jnp.float32)
+        p["w1"] = _normal(ks[1], (held, d, f))
+        p["w3"] = _normal(ks[2], (held, d, f))
+        p["w2"] = _normal(ks[3], (held, f, d))
+    else:
+        raise ValueError(f"Unknown {type(layer).__name__} ffn {layer.ffn!r}")
+
+
 def init_hybrid_block(rng, in_dim: int, layer: HybridBlock):
     """normal(0, 0.02) matrices and taps (the LFM2 family's
     ``initializer_range``), unit gains, a zero selection bias; one key for
@@ -161,7 +189,7 @@ def init_hybrid_block(rng, in_dim: int, layer: HybridBlock):
             f"HybridBlock d_model={layer.d_model} but incoming dim is "
             f"{in_dim}; insert a Dense projection first"
         )
-    d, f = layer.d_model, layer.ff_dim
+    d = layer.d_model
     k_op, k_ffn = jax.random.split(rng)
     p = {
         "op_norm": jnp.ones((d,), jnp.float32),
@@ -184,23 +212,60 @@ def init_hybrid_block(rng, in_dim: int, layer: HybridBlock):
         p["k_norm"] = jnp.ones((layer.head_dim,), jnp.float32)
     else:
         raise ValueError(f"Unknown HybridBlock operator {layer.operator!r}")
-    if layer.ffn == "dense":
-        ks = jax.random.split(k_ffn, 3)
-        p["w1"] = _normal(ks[0], (d, f))
-        p["w3"] = _normal(ks[1], (d, f))
-        p["w2"] = _normal(ks[2], (f, d))
-    elif layer.ffn == "routed":
-        ks = jax.random.split(k_ffn, 4)
-        held = layer.experts_held
-        p["router"] = _normal(ks[0], (d, layer.num_experts))
-        # enters the selection only: no gradient reaches it, and its update
-        # rule (not in the published config) is not implemented
-        p["expert_bias"] = jnp.zeros((layer.num_experts,), jnp.float32)
-        p["w1"] = _normal(ks[1], (held, d, f))
-        p["w3"] = _normal(ks[2], (held, d, f))
-        p["w2"] = _normal(ks[3], (held, f, d))
-    else:
-        raise ValueError(f"Unknown HybridBlock ffn {layer.ffn!r}")
+    _init_ffn(k_ffn, layer, p)
+    return p
+
+
+# b_res at the start: 0 on the diagonal and this much under it elsewhere, so
+# that Sinkhorn(exp(b_res)) is the identity to 3e-4 (the mixing starts as the
+# plain residual, arXiv:2512.24880 section 4)
+_HC_RES_OFF_DIAGONAL = -8.0
+
+
+def init_latent_block(rng, in_dim: int, layer: LatentBlock):
+    """normal(0, 0.02) matrices (the family's ``initializer_range``), unit
+    gains, a zero selection bias; the streams' coefficients start as the
+    plain residual: alpha 0.01, ``b_pre`` = ``b_post`` = 0 (``H_pre`` a half
+    a stream, ``H_post`` 1), ``b_res`` the identity's logits. One key for the
+    attention, one for the FFN, one for the two sublayers' mixing."""
+    if in_dim != layer.d_model:
+        raise ValueError(
+            f"LatentBlock d_model={layer.d_model} but incoming dim is "
+            f"{in_dim}; insert a Dense projection first"
+        )
+    d, n, heads = layer.d_model, layer.streams, layer.num_heads
+    rank_q, rank_kv = layer.q_lora_rank, layer.kv_lora_rank
+    nope, rope, dv = layer.qk_nope_head_dim, layer.qk_rope_head_dim, layer.v_head_dim
+    k_op, k_ffn, k_hc = jax.random.split(rng, 3)
+    ks = jax.random.split(k_op, 5)
+    p = {
+        "op_norm": jnp.ones((d,), jnp.float32),
+        "ffn_norm": jnp.ones((d,), jnp.float32),
+        "w_dq": _normal(ks[0], (d, rank_q)),
+        "q_norm": jnp.ones((rank_q,), jnp.float32),
+        "w_uq": _normal(ks[1], (rank_q, heads * (nope + rope))),
+        "w_dkv": _normal(ks[2], (d, rank_kv + rope)),
+        "kv_norm": jnp.ones((rank_kv,), jnp.float32),
+        "w_ukv": _normal(ks[3], (rank_kv, heads * (nope + dv))),
+        "wo": _normal(ks[4], (heads * dv, d)),
+    }
+    k_routed, k_shared = jax.random.split(k_ffn)
+    _init_ffn(k_routed, layer, p)
+    if layer.ffn == "routed" and layer.shared_experts:
+        ks = jax.random.split(k_shared, 3)
+        f = layer.shared_experts * layer.ff_dim
+        p["shared_w1"] = _normal(ks[0], (d, f))
+        p["shared_w3"] = _normal(ks[1], (d, f))
+        p["shared_w2"] = _normal(ks[2], (f, d))
+    for prefix, key in zip(("hc_op_", "hc_ffn_"), jax.random.split(k_hc)):
+        # columns [pre (n) | post (n) | res (n x n, row-major)]
+        p[prefix + "phi"] = _normal(key, (n, d, 2 * n + n * n))
+        p[prefix + "alpha"] = jnp.full((3,), 0.01, jnp.float32)
+        p[prefix + "b_pre"] = jnp.zeros((n,), jnp.float32)
+        p[prefix + "b_post"] = jnp.zeros((n,), jnp.float32)
+        p[prefix + "b_res"] = _HC_RES_OFF_DIAGONAL * (
+            1.0 - jnp.eye(n, dtype=jnp.float32)
+        )
     return p
 
 
@@ -223,11 +288,11 @@ def layer_out_dim(layer, in_dim: int) -> int:
     """Feature dimension a layer produces given its input dimension."""
     if isinstance(layer, (DenseLayer, LSTMLayer)):
         return layer.units
-    if isinstance(layer, (TransformerBlock, MoEBlock, HybridBlock)):
+    if isinstance(layer, (TransformerBlock, MoEBlock, HybridBlock, LatentBlock)):
         return layer.d_model
     if isinstance(layer, TCNBlock):
         return layer.filters
-    if isinstance(layer, (PositionalEncoding, PoolLayer, RMSNormLayer)):
+    if isinstance(layer, (PositionalEncoding, PoolLayer, RMSNormLayer, StreamLayer)):
         return in_dim
     raise TypeError(f"Unknown layer spec: {layer!r}")
 
@@ -248,11 +313,13 @@ def init_model_params(rng: jax.Array, spec: ModelSpec) -> Params:
             params.append(init_moe_block(layer_rng, in_dim, layer))
         elif isinstance(layer, HybridBlock):
             params.append(init_hybrid_block(layer_rng, in_dim, layer))
+        elif isinstance(layer, LatentBlock):
+            params.append(init_latent_block(layer_rng, in_dim, layer))
         elif isinstance(layer, RMSNormLayer):
             params.append({"scale": jnp.ones((in_dim,), jnp.float32)})
         elif isinstance(layer, TCNBlock):
             params.append(init_tcn_block(layer_rng, in_dim, layer))
-        elif isinstance(layer, (PositionalEncoding, PoolLayer)):
+        elif isinstance(layer, (PositionalEncoding, PoolLayer, StreamLayer)):
             params.append({})
         else:
             raise TypeError(f"Unknown layer spec: {layer!r}")
@@ -583,8 +650,7 @@ def _apply_moe_block(
     return out, aux
 
 
-@jax.named_scope("rms_norm")
-def _rms_norm(x, scale, eps: float):
+def _rms(x, scale, eps: float):
     """``x * rsqrt(mean(x^2) + eps) * scale`` over the last axis, computed in
     float32 whatever the compute dtype."""
     x32 = x.astype(jnp.float32)
@@ -592,11 +658,18 @@ def _rms_norm(x, scale, eps: float):
     return (x32 * jax.lax.rsqrt(ms + eps) * scale.astype(jnp.float32)).astype(x.dtype)
 
 
-def _rope(x, theta: float):
+# a block's own norms; a norm inside another scope (the latent projections')
+# calls _rms and is counted there
+_rms_norm = jax.named_scope("rms_norm")(_rms)
+
+
+def _rope(x, theta: float, inv_freq=None):
     """Rotary position embedding, rotate-half convention, on (..., T, Dh):
-    pair ``i`` of the two halves turns by ``t * theta**(-2i/Dh)``."""
+    pair ``i`` of the two halves turns by ``t * inv_freq[i]``,
+    ``theta**(-2i/Dh)`` unless the caller brings its own frequencies."""
     t, dh = x.shape[-2], x.shape[-1]
-    inv_freq = theta ** (-jnp.arange(0, dh, 2, dtype=jnp.float32) / dh)
+    if inv_freq is None:
+        inv_freq = theta ** (-jnp.arange(0, dh, 2, dtype=jnp.float32) / dh)
     angles = jnp.arange(t, dtype=jnp.float32)[:, None] * inv_freq[None, :]
     cos = jnp.concatenate([jnp.cos(angles)] * 2, axis=-1)
     sin = jnp.concatenate([jnp.sin(angles)] * 2, axis=-1)
@@ -642,8 +715,152 @@ def _gqa_attention(layer: HybridBlock, p, h):
     return out.transpose(0, 2, 1, 3).reshape(bsz, t, layer.num_heads * dh) @ p["wo"]
 
 
-def _swiglu(p, h):
-    return (jax.nn.silu(h @ p["w1"]) * (h @ p["w3"])) @ p["w2"]
+def _swiglu(p, h, prefix: str = ""):
+    w1, w3, w2 = (p[prefix + name] for name in ("w1", "w3", "w2"))
+    return (jax.nn.silu(h @ w1) * (h @ w3)) @ w2
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention temperature: ``0.1 * mscale * ln(factor) + 1`` past a
+    factor of 1."""
+    return 1.0 if factor <= 1.0 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_inv_freq(layer: LatentBlock) -> np.ndarray:
+    """The ``qk_rope_head_dim / 2`` rotary frequencies (arXiv:2309.00071 as
+    DeepSeek-V2 applies it): ``theta**(-2i/D)`` where pair ``i`` turns more
+    than ``beta_fast`` times within the original context, that over
+    ``factor`` where it turns fewer than ``beta_slow`` times, and a linear
+    ramp over the pair index between the two correction dims."""
+    dim = layer.qk_rope_head_dim
+    extra = layer.rope_theta ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
+    if layer.rope_factor <= 1.0:
+        return extra.astype(np.float32)
+
+    def correction_dim(rotations: float) -> float:
+        return (
+            dim * math.log(layer.rope_original_max / (rotations * 2 * math.pi))
+        ) / (2 * math.log(layer.rope_theta))
+
+    low = max(math.floor(correction_dim(layer.rope_beta_fast)), 0)
+    high = min(math.ceil(correction_dim(layer.rope_beta_slow)), dim - 1)
+    ramp = np.clip(
+        (np.arange(dim // 2, dtype=np.float64) - low) / max(high - low, 0.001), 0, 1
+    )
+    return (extra / layer.rope_factor * ramp + extra * (1 - ramp)).astype(np.float32)
+
+
+def _latent_attention(layer: LatentBlock, p, h):
+    """Multi-head latent attention (DeepSeek-V2 section 2.1) on (B, T, d):
+    ``c_q = RMSNorm(h W_dq)``; ``[q_nope | q_rope] = c_q W_uq`` a head;
+    ``[c_kv | k_r] = h W_dkv``, ``c_kv = RMSNorm(c_kv)``; ``[k_nope | v] =
+    c_kv W_ukv`` a head; ``q = [q_nope | RoPE(q_rope)]``, ``k = [k_nope |
+    RoPE(k_r)]`` with the one rotary key head shared by every head; causal
+    ``softmax(q k^T s) v`` with ``s = (nope + rope)^-1/2 m^2``, ``m`` YaRN's
+    ``0.1 mscale_all_dim ln(factor) + 1``; out ``concat(o) W_o``. No bias.
+    RoPE's cos and sin are scaled by ``mscale / mscale_all_dim``'s ratio of
+    temperatures (1 in the published config)."""
+    bsz, t, _ = h.shape
+    heads, rank_kv = layer.num_heads, layer.kv_lora_rank
+    nope, rope, dv = layer.qk_nope_head_dim, layer.qk_rope_head_dim, layer.v_head_dim
+
+    def split_heads(a):
+        return a.reshape(bsz, t, heads, -1).transpose(0, 2, 1, 3)
+
+    with jax.named_scope("mla_down"):
+        c_q = _rms(h @ p["w_dq"], p["q_norm"], layer.norm_eps)
+        down = h @ p["w_dkv"]
+        c_kv = _rms(down[..., :rank_kv], p["kv_norm"], layer.norm_eps)
+        k_rope = down[..., rank_kv:]
+    with jax.named_scope("mla_up"):
+        q = split_heads(c_q @ p["w_uq"])
+        kv = split_heads(c_kv @ p["w_ukv"])
+        inv_freq = jnp.asarray(yarn_inv_freq(layer))
+        factor = layer.rope_factor
+        turn = yarn_mscale(factor, layer.rope_mscale) / yarn_mscale(
+            factor, layer.rope_mscale_all_dim
+        )
+
+        def rotary(a):
+            a = _rope(a, layer.rope_theta, inv_freq)
+            return a if turn == 1.0 else (a * turn).astype(a.dtype)
+
+        q = jnp.concatenate([q[..., :nope], rotary(q[..., nope:])], axis=-1)
+        k_rope = jnp.broadcast_to(
+            rotary(k_rope[:, None]), (bsz, heads, t, rope)
+        )
+        k = jnp.concatenate([kv[..., :nope], k_rope], axis=-1)
+        v = kv[..., nope:]
+    scale = (nope + rope) ** -0.5 * yarn_mscale(factor, layer.rope_mscale_all_dim) ** 2
+    impl = layer.attention_impl
+    with jax.named_scope("attention"):
+        out = dot_product_attention(
+            q, k, v, causal=True, impl=None if impl == "auto" else impl, scale=scale
+        )
+    with jax.named_scope("mla_out"):
+        return out.transpose(0, 2, 1, 3).reshape(bsz, t, heads * dv) @ p["wo"]
+
+
+def hc_coefficients(layer: LatentBlock, p, prefix: str, x):
+    """The mixing coefficients of one sublayer (arXiv:2512.24880 section 3;
+    its leaves are ``p[prefix + ...]``) from the streams ``x``: (n, B, T, d),
+    in float32. With ``x~ =
+    RMSNorm(vec(X))`` over a token's ``n x d`` values (no gain):
+    ``H_pre = sigmoid(a_pre x~ phi_pre + b_pre)`` (n), ``H_post = 2
+    sigmoid(a_post x~ phi_post + b_post)`` (n), ``H_res =
+    Sinkhorn(exp(clip(a_res mat(x~ phi_res) + b_res, -c, c)))`` (n x n):
+    ``sinkhorn_iters`` times, every row then every column divided by its sum
+    plus ``hc_eps``. Returns ``(H_pre (n, B, T), H_post (n, B, T), H_res (n,
+    n, B, T), gap)``; ``gap`` is the mean over tokens of the largest |row or
+    column sum - 1| of ``H_res``. The coefficients sit with the tokens on the
+    minor axes, so a row's or a column's sum is an add of n slabs."""
+    n = layer.streams
+    phi, alpha = p[prefix + "phi"], p[prefix + "alpha"]
+    with jax.named_scope("hc_coeff"):
+        x32 = x.astype(jnp.float32)
+        ms = jnp.sum(x32 * x32, axis=(0, -1)) / (n * x.shape[-1])
+        # the norm has no gain, so it is one factor a token behind the
+        # product (vec(X) phi)^T; by dot_general, not einsum, which names a
+        # scope of its own (the innermost scope is what a trace is reduced by)
+        raw = jax.lax.dot_general(
+            phi, x32, (((0, 1), (0, 3)), ((), ()))
+        ) * jax.lax.rsqrt(ms + layer.norm_eps)
+
+        def logits(part, a, name):
+            b = p[prefix + name]
+            return a * part.reshape(b.shape + part.shape[1:]) + b[..., None, None]
+
+        pre = jax.nn.sigmoid(logits(raw[:n], alpha[0], "b_pre"))
+        post = 2.0 * jax.nn.sigmoid(logits(raw[n : 2 * n], alpha[1], "b_post"))
+        res = logits(raw[2 * n :], alpha[2], "b_res")
+        res = jnp.exp(jnp.clip(res, -layer.hc_clamp, layer.hc_clamp))
+        for _ in range(layer.sinkhorn_iters):
+            res = res / (jnp.sum(res, axis=1, keepdims=True) + layer.hc_eps)
+            res = res / (jnp.sum(res, axis=0, keepdims=True) + layer.hc_eps)
+        off = jnp.maximum(
+            jnp.max(jnp.abs(jnp.sum(res, axis=1) - 1.0), axis=0),
+            jnp.max(jnp.abs(jnp.sum(res, axis=0) - 1.0), axis=0),
+        )
+        return pre, post, res, jax.lax.stop_gradient(jnp.mean(off))
+
+
+@jax.named_scope("hc_mix")
+def _hc_read(pre, x):
+    """``H_pre X``: the sublayer's input, (B, T, d)."""
+    mixed = sum(pre[j][..., None] * x[j].astype(jnp.float32) for j in range(x.shape[0]))
+    return mixed.astype(x.dtype)
+
+
+@jax.named_scope("hc_mix")
+def _hc_write(res, post, x, y):
+    """``H_res X + H_post^T y``: the streams after the sublayer."""
+    n = x.shape[0]
+    x32, y32 = x.astype(jnp.float32), y.astype(jnp.float32)
+    rows = [
+        sum(res[i, j][..., None] * x32[j] for j in range(n)) + post[i][..., None] * y32
+        for i in range(n)
+    ]
+    return jnp.stack(rows).astype(x.dtype)
 
 
 # ---- grouped products over the experts held. Rows are sorted by group; group
@@ -715,21 +932,36 @@ _permute_rows.defvjp(
 )
 
 
+def _stays_float32(leaf: str) -> bool:
+    """A block's leaves that are not cast to a bfloat16 compute dtype: a
+    router's (routing is a decision) and the streams' mixing coefficients'."""
+    return leaf in ("router", "expert_bias") or leaf.startswith("hc_")
+
+
 MOE_STATS = ("moe_held", "moe_absent", "moe_tokens", "moe_layer_steps", "moe_peak_load")
 
 
 def zero_stats(spec: ModelSpec) -> Dict[str, jnp.ndarray]:
     """What :func:`apply_model_stats` counts for ``spec``, at zero: the sums'
-    start. Empty for a spec with no routed layer."""
-    routed = any(
-        isinstance(layer, HybridBlock) and layer.ffn == "routed"
-        for layer in spec.layers
-    )
-    return {key: jnp.zeros((), jnp.int32) for key in MOE_STATS} if routed else {}
+    start: int32 counts of the routed layers, and of a latent block's mixing
+    its sublayer-steps (int32) and their summed stochastic gap (float32).
+    Empty for a spec with neither."""
+    stats = {}
+    for layer in spec.layers:
+        if isinstance(layer, (HybridBlock, LatentBlock)) and layer.ffn == "routed":
+            stats.update({key: jnp.zeros((), jnp.int32) for key in MOE_STATS})
+        if isinstance(layer, LatentBlock):
+            stats["hc_sublayer_steps"] = jnp.zeros((), jnp.int32)
+            stats["hc_stochastic_gap"] = jnp.zeros((), jnp.float32)
+    return stats
 
 
-def routed_ffn(layer: HybridBlock, p, h):
+def routed_ffn(layer, p, h, scale: float = 1.0, gate_eps: float = 1e-6):
     """The routed FFN on (N, D) tokens: what the experts held here give.
+    ``layer`` (a hybrid or a latent block) says which: ``top_k`` of the
+    router's outputs by ``sigmoid(h W_r)`` plus the selection bias, weights
+    the scores at the selection over (their sum + ``gate_eps``) times
+    ``scale``, experts ``expert_offset`` … ``+ experts_held`` computed.
     Returns ``(out, stats)``; ``stats`` counts, for this layer and step, the
     assignments to held and to absent experts, the tokens, and the fullest
     held expert's assignments."""
@@ -742,7 +974,9 @@ def routed_ffn(layer: HybridBlock, p, h):
             jax.lax.stop_gradient(scores) + p["expert_bias"], k
         )
         gate = jnp.take_along_axis(scores, chosen, axis=-1)
-        gate = gate / (jnp.sum(gate, axis=-1, keepdims=True) + 1e-6)
+        gate = gate / (jnp.sum(gate, axis=-1, keepdims=True) + gate_eps)
+        if scale != 1.0:
+            gate = gate * scale
     with jax.named_scope("moe_dispatch"):
         local = chosen.reshape(-1) - layer.expert_offset  # (N * k,)
         # an assignment's group: its held expert, or one past them if absent
@@ -778,6 +1012,58 @@ def _apply_hybrid_block(layer: HybridBlock, p, x):
     b, t, d = h.shape
     ffn, stats = routed_ffn(layer, p, h.reshape(b * t, d))
     return x + ffn.reshape(b, t, d), stats
+
+
+# a jit of its own inside the caller's: layers of one spec and one shape (the
+# routed layers after the leading dense ones) are traced, differentiated and
+# batched once and not once each, and XLA inlines the calls again: a model of
+# 1 + 4 layers traces and lowers in 12 s where it took 25 (the chunk
+# program of xing4_0_29b_a4b on a sandbox's CPU, PERF.md section 6, PR 37)
+@functools.partial(jax.jit, static_argnums=0)
+def _apply_latent_block(layer: LatentBlock, p, x):
+    """x: (streams, batch, time, d_model) → ``(x, stats)``. For each of the
+    two sublayers ``F`` (latent attention, then the FFN): ``X <- H_res X +
+    H_post^T F(RMSNorm(H_pre X))`` (:func:`hc_coefficients`). The routed FFN
+    is ``Shared(h) + sum over picked and held experts of w_e Expert_e(h)``:
+    the shared expert is computed for every token on every share of the
+    layer, the routed part as :func:`routed_ffn` gives it."""
+    b, t, d = x.shape[1:]
+
+    def ffn(h):
+        if layer.ffn == "dense":
+            return _swiglu(p, h), {}
+        # the family's gate adds 1e-20 to the picked scores' sum, not 1e-6
+        out, stats = routed_ffn(
+            layer, p, h.reshape(b * t, d), scale=layer.routed_scale, gate_eps=1e-20
+        )
+        out = out.reshape(b, t, d)
+        if layer.shared_experts:
+            with jax.named_scope("moe_shared"):
+                out = out + _swiglu(p, h, "shared_")
+        return out, stats
+
+    sublayers = (
+        ("hc_op_", "op_norm", lambda h: (_latent_attention(layer, p, h), {})),
+        ("hc_ffn_", "ffn_norm", ffn),
+    )
+    stats = {"hc_sublayer_steps": jnp.asarray(len(sublayers), jnp.int32)}
+    gaps = jnp.zeros((), jnp.float32)
+    for mixing, norm, sublayer in sublayers:
+        pre, post, res, gap = hc_coefficients(layer, p, mixing, x)
+        y, counted = sublayer(_rms_norm(_hc_read(pre, x), p[norm], layer.norm_eps))
+        x = _hc_write(res, post, x, y)
+        gaps = gaps + gap
+        stats.update(counted)
+    stats["hc_stochastic_gap"] = gaps
+    return x, stats
+
+
+def _apply_stream_layer(layer: StreamLayer, x):
+    if layer.mode == "expand":
+        return jnp.broadcast_to(x[None], (layer.streams,) + x.shape)
+    if layer.mode == "collapse":
+        return jnp.sum(x.astype(jnp.float32), axis=0).astype(x.dtype)
+    raise ValueError(f"Unknown stream mode {layer.mode!r}")
 
 
 def _causal_conv1d(x, kernel, dilation: int):
@@ -875,8 +1161,8 @@ def apply_model_stats(spec: ModelSpec, params: Params, x: jnp.ndarray):
 
         params = [
             {
-                k: (v if k in ("router", "expert_bias")
-                    and isinstance(layer, (MoEBlock, HybridBlock))
+                k: (v if _stays_float32(k)
+                    and isinstance(layer, (MoEBlock, HybridBlock, LatentBlock))
                     else jax.tree_util.tree_map(_cast, v))
                 for k, v in p.items()
             }
@@ -963,11 +1249,17 @@ def apply_model_stats(spec: ModelSpec, params: Params, x: jnp.ndarray):
                     layer, p, out,
                 )
             penalty = penalty + aux
-        elif isinstance(layer, HybridBlock):
-            out, counted = _seq_layer(_apply_hybrid_block, layer, p, out)
-            stats = {
-                key: stats.get(key, 0) + value for key, value in counted.items()
-            }
+        elif isinstance(layer, (HybridBlock, LatentBlock)):
+            block = (
+                _apply_hybrid_block if isinstance(layer, HybridBlock)
+                else _apply_latent_block
+            )
+            out, counted = _seq_layer(block, layer, p, out)
+            stats.update(
+                {key: stats.get(key, 0) + value for key, value in counted.items()}
+            )
+        elif isinstance(layer, StreamLayer):
+            out = _apply_stream_layer(layer, out)
         elif isinstance(layer, RMSNormLayer):
             out = _rms_norm(out, p["scale"], layer.eps)
         elif isinstance(layer, TCNBlock):

@@ -296,9 +296,19 @@ def make_masked_epoch_fn(
                 stats_sum,
             )
 
+        # the sums and the optimizer's zeros start as one value for every
+        # machine; under the builder's vmap an unbatched carry that the body
+        # returns batched makes JAX trace the body (the model and its
+        # gradient) a second time to find that out. Tied to the machine's own
+        # key they are batched from the start: a third of a large model's
+        # trace (37 s to 24 s at 0.64 billion parameters); XLA folds the zero
+        lane = jax.random.key_data(rng).ravel()[0] * 0
+        per_machine = functools.partial(
+            jax.tree_util.tree_map, lambda a: a + lane.astype(a.dtype)
+        )
         init = (
-            jnp.asarray(0, n_live_steps.dtype), params, opt_state,
-            jnp.asarray(0.0), jnp.asarray(0.0), zero_stats(spec),
+            jnp.asarray(0, n_live_steps.dtype), params,
+            *per_machine((opt_state, jnp.asarray(0.0), jnp.asarray(0.0), zero_stats(spec))),
         )
         _, params, opt_state, loss_sum, w_sum, stats = jax.lax.while_loop(
             cond, body, init

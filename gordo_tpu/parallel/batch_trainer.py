@@ -405,18 +405,22 @@ def _predict_windows(spec: ModelSpec, params, X):
 
 
 def _note_layer_counts(counted: Dict[str, np.ndarray], rows, n_live: int) -> None:
-    """Add a chunk's routed-layer counts (a value a lane; padding lanes left
-    out) to the build's counters. Nothing to add for a spec that routes
-    nothing."""
+    """Add a chunk's layer counts (a value a lane; padding lanes left out) to
+    the build's counters: the routed layers', and a latent block's mixing.
+    Nothing to add for a spec that has neither."""
     if not counted:
         return
     live = np.asarray(rows) < n_live
-    total = {key: int(np.asarray(value)[live].sum()) for key, value in counted.items()}
-    metric_catalog.MOE_ASSIGNMENTS.labels(where="held").inc(total["moe_held"])
-    metric_catalog.MOE_ASSIGNMENTS.labels(where="absent").inc(total["moe_absent"])
-    metric_catalog.MOE_TOKENS.inc(total["moe_tokens"])
-    metric_catalog.MOE_LAYER_STEPS.inc(total["moe_layer_steps"])
-    metric_catalog.MOE_PEAK_LOAD.inc(total["moe_peak_load"])
+    total = {key: np.asarray(value)[live].sum() for key, value in counted.items()}
+    if "moe_tokens" in total:
+        metric_catalog.MOE_ASSIGNMENTS.labels(where="held").inc(int(total["moe_held"]))
+        metric_catalog.MOE_ASSIGNMENTS.labels(where="absent").inc(int(total["moe_absent"]))
+        metric_catalog.MOE_TOKENS.inc(int(total["moe_tokens"]))
+        metric_catalog.MOE_LAYER_STEPS.inc(int(total["moe_layer_steps"]))
+        metric_catalog.MOE_PEAK_LOAD.inc(int(total["moe_peak_load"]))
+    if "hc_sublayer_steps" in total:
+        metric_catalog.HC_SUBLAYER_STEPS.inc(int(total["hc_sublayer_steps"]))
+        metric_catalog.HC_STOCHASTIC_GAP.inc(float(total["hc_stochastic_gap"]))
 
 
 @functools.lru_cache(maxsize=64)

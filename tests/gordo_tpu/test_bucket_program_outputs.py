@@ -9,6 +9,7 @@ import numpy as np
 import pytest
 
 from gordo_tpu.models.factories.hybrid import hybrid_moe_model
+from gordo_tpu.models.factories.latent import latent_moe_model
 from gordo_tpu.models.factories.lstm_autoencoder import lstm_symmetric
 from gordo_tpu.observability import metrics as metric_catalog
 from gordo_tpu.ops import nn
@@ -31,13 +32,22 @@ def _hybrid_spec():
     )
 
 
+def _latent_spec():
+    return latent_moe_model(
+        N_TAGS, lookback_window=LOOKBACK, d_model=8, ffns=["dense", "routed"], ff_dim=8,
+        expert_dim=8, num_heads=2, q_lora_rank=6, kv_lora_rank=4, qk_nope_head_dim=4,
+        qk_rope_head_dim=2, v_head_dim=4, num_experts=8, experts_held=2, expert_offset=2,
+        top_k=4, streams=2, attention="xla",
+    )
+
+
 def _data(machines=2):
     rng = np.random.default_rng(3)
     X = rng.normal(size=(machines, N_ROWS, N_TAGS)).astype(np.float32)
     return X, np.arange(7, 7 + machines, dtype=np.uint32)
 
 
-@pytest.mark.parametrize("make_spec", [_lstm_spec, _hybrid_spec])
+@pytest.mark.parametrize("make_spec", [_lstm_spec, _hybrid_spec, _latent_spec])
 def test_outputs_hold_one_copy_of_the_parameters(make_spec):
     spec = make_spec()
     program = batch_trainer._bucket_program(spec, N_ROWS, FOLDS, 1, BATCH, True, True)
@@ -48,7 +58,11 @@ def test_outputs_hold_one_copy_of_the_parameters(make_spec):
     n_out = sum(int(np.prod(a.shape)) for a in jax.tree_util.tree_leaves(params))
     assert n_out == 2 * n_one  # two machines, one stage each: not four
     assert losses.shape == (2, 1) and len(preds) == len(FOLDS)
-    assert set(counted) == (set(nn.MOE_STATS) if make_spec is _hybrid_spec else set())
+    mixing = {"hc_sublayer_steps", "hc_stochastic_gap"}
+    assert set(counted) == {
+        _lstm_spec: set(), _hybrid_spec: set(nn.MOE_STATS),
+        _latent_spec: set(nn.MOE_STATS) | mixing,
+    }[make_spec]
 
 
 def test_lstm_stages_are_what_stand_alone_fits_give():

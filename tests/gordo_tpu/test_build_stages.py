@@ -270,6 +270,28 @@ SCOPES = {
          "moe_experts", "dense", "window_gather", "optimizer_update",
          "fold_predict"),
     ),
+    "latent": (
+        """gordo_tpu.models.models.TransformerAutoEncoder:
+                kind: latent_moe_model
+                d_model: 8
+                ffns: [dense, routed]
+                ff_dim: 8
+                expert_dim: 8
+                num_heads: 2
+                q_lora_rank: 6
+                kv_lora_rank: 4
+                qk_nope_head_dim: 4
+                qk_rope_head_dim: 2
+                v_head_dim: 4
+                num_experts: 4
+                experts_held: 2
+                top_k: 2
+                streams: 2
+                lookback_window: 4""",
+        ("mla_down", "mla_up", "attention", "mla_out", "hc_coeff", "hc_mix",
+         "moe_shared", "rms_norm", "moe_router", "moe_dispatch", "moe_experts",
+         "dense", "window_gather", "optimizer_update", "fold_predict"),
+    ),
 }
 
 

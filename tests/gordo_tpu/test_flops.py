@@ -34,8 +34,11 @@ def _spec(est):
         TransformerAutoEncoder(
             kind="hybrid_moe_model", lookback_window=16, experts_held=4
         ),
+        TransformerAutoEncoder(
+            kind="latent_moe_model", lookback_window=16, experts_held=4
+        ),
     ],
-    ids=["hourglass", "lstm", "transformer", "moe", "hybrid"],
+    ids=["hourglass", "lstm", "transformer", "moe", "hybrid", "latent"],
 )
 def test_param_count_matches_initialized_tree(est):
     """The layer-walk parameter count must match the real pytree — the same

@@ -81,3 +81,46 @@ def test_grouped_products_and_their_gradients_are_grouped_kernels(one_chip, no_c
     flops = compiled.cost_analysis().get("flops")
     if flops:
         assert flops < 1.5 * 3 * 2 * rows * d * f
+
+
+def test_latent_block_compiles_at_the_cells_widths(one_chip, no_compile_cache):
+    """One routed layer of ``xing4_0_29b_a4b`` (latent attention with heads of
+    192 / 128 on the XLA path, four residual streams, 8 held of 64 experts
+    beside the shared one; 16 windows of 256 rows, bfloat16 operands),
+    differentiated: the chip's compiler takes it, the grouped products stay the
+    compiler's grouped kernels, and the forty normalisations a sublayer are
+    unrolled into the program, not a loop of launches."""
+    import dataclasses
+    import json
+
+    import jax
+    import jax.numpy as jnp
+
+    from gordo_tpu.models.factories.latent import latent_moe_model
+    from gordo_tpu.ops import nn
+
+    root = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+    with open(os.path.join(root, "chipbench", "configs", "xing4_0_29b_a4b.json")) as fh:
+        model = dict(json.load(fh)["model"], ffns=["routed"], attention="xla")
+    for key in ("kind", "batch_size", "epochs", "compute_dtype"):
+        model.pop(key)
+    spec = dataclasses.replace(latent_moe_model(8, **model), compute_dtype="bfloat16")
+    layer = spec.layers[2]
+    assert (layer.qk_nope_head_dim + layer.qk_rope_head_dim, layer.v_head_dim) == (192, 128)
+
+    def loss(params, x):
+        return jnp.sum(nn.apply_model(spec, params, x)[0] ** 2)
+
+    shapes = jax.eval_shape(lambda key: nn.init_model_params(key, spec), jax.random.PRNGKey(0))
+    shapes = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), shapes
+    )
+    x = jax.ShapeDtypeStruct((16, 256, 8), jnp.float32, sharding=one_chip)
+    compiled = jax.jit(jax.grad(loss)).lower(shapes, x).compile()
+    text = compiled.as_text()
+    kernels = re.findall(r"= \S+ custom-call\([^\n]*ragged[^\n]*", text)
+    products = [line for line in kernels if "metadata" not in line.split("custom-call")[0]]
+    assert len(products) == 9, len(products)  # three a pass: forward, rows' and weights' gradients
+    assert " while(" not in text  # Sinkhorn's twenty iterations are unrolled
+    # a layer's gradient step beside its 128 M parameters fits a chip several times over
+    assert compiled.memory_analysis().temp_size_in_bytes < 6e9
