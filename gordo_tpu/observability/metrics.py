@@ -98,6 +98,12 @@ HC_STOCHASTIC_GAP = telemetry.counter(
     "|row or column sum - 1| of the streams' mixing matrix after its last "
     "Sinkhorn iteration (over the sublayer-steps: 0 is doubly stochastic)",
 )
+LAST_POSITION_BLOCK_STEPS = telemetry.counter(
+    "gordo_build_last_position_block_steps_total",
+    "Sequence blocks run at the last position alone (the model's last block "
+    "before a last-position pool: keys and values from every position, the "
+    "rest at the last) times live training steps",
+)
 ARTIFACT_BYTES = telemetry.counter(
     "gordo_build_artifact_bytes_total",
     "Bytes of model.pkl as the fleet build wrote them (over the serialize "

@@ -663,14 +663,15 @@ def _rms(x, scale, eps: float):
 _rms_norm = jax.named_scope("rms_norm")(_rms)
 
 
-def _rope(x, theta: float, inv_freq=None):
-    """Rotary position embedding, rotate-half convention, on (..., T, Dh):
-    pair ``i`` of the two halves turns by ``t * inv_freq[i]``,
-    ``theta**(-2i/Dh)`` unless the caller brings its own frequencies."""
+def _rope(x, theta: float, inv_freq=None, first: int = 0):
+    """Rotary position embedding, rotate-half convention, on (..., T, Dh)
+    whose rows are the positions from ``first`` on: pair ``i`` of the two
+    halves turns by ``t * inv_freq[i]``, ``theta**(-2i/Dh)`` unless the
+    caller brings its own frequencies."""
     t, dh = x.shape[-2], x.shape[-1]
     if inv_freq is None:
         inv_freq = theta ** (-jnp.arange(0, dh, 2, dtype=jnp.float32) / dh)
-    angles = jnp.arange(t, dtype=jnp.float32)[:, None] * inv_freq[None, :]
+    angles = jnp.arange(first, first + t, dtype=jnp.float32)[:, None] * inv_freq[None, :]
     cos = jnp.concatenate([jnp.cos(angles)] * 2, axis=-1)
     sin = jnp.concatenate([jnp.sin(angles)] * 2, axis=-1)
     x32 = x.astype(jnp.float32)
@@ -691,28 +692,44 @@ def _gated_conv(layer: HybridBlock, p, h):
     return (c * conv) @ p["conv_out"]
 
 
+def _attention_impl(layer, last_only: bool):
+    """The dispatcher's ``impl`` for a block's attention. The last position's
+    single query row goes to the XLA path whatever the spec asks: the flash
+    kernel and the ring take equal query and key lengths."""
+    if last_only:
+        return "xla"
+    return None if layer.attention_impl == "auto" else layer.attention_impl
+
+
 @jax.named_scope("attention")
-def _gqa_attention(layer: HybridBlock, p, h):
+def _gqa_attention(layer: HybridBlock, p, h, last_only: bool = False):
     """Grouped-query causal attention: RMSNorm a head on q and k, RoPE, each
     key/value head serving ``num_heads / num_kv_heads`` consecutive query
-    heads; through the dispatcher of ops/attention.py."""
+    heads; through the dispatcher of ops/attention.py. With ``last_only`` the
+    queries are the last position's alone, against every position's keys
+    and values: (B, 1, d) out."""
     bsz, t, _ = h.shape
     dh, rep = layer.head_dim, layer.num_heads // layer.num_kv_heads
+    h_q = h[:, -1:] if last_only else h
 
     def heads(a, n):
-        return a.reshape(bsz, t, n, dh).transpose(0, 2, 1, 3)
+        return a.reshape(bsz, a.shape[1], n, dh).transpose(0, 2, 1, 3)
 
-    q = heads(h @ p["wq"], layer.num_heads)
+    q = heads(h_q @ p["wq"], layer.num_heads)
     k = heads(h @ p["wk"], layer.num_kv_heads)
     v = heads(h @ p["wv"], layer.num_kv_heads)
-    q = _rope(_rms_norm(q, p["q_norm"], layer.norm_eps), layer.rope_theta)
+    q = _rope(
+        _rms_norm(q, p["q_norm"], layer.norm_eps), layer.rope_theta,
+        first=t - h_q.shape[1],
+    )
     k = _rope(_rms_norm(k, p["k_norm"], layer.norm_eps), layer.rope_theta)
     k, v = jnp.repeat(k, rep, axis=1), jnp.repeat(v, rep, axis=1)
-    impl = layer.attention_impl
     out = dot_product_attention(
-        q, k, v, causal=True, impl=None if impl == "auto" else impl
+        q, k, v, causal=True, impl=_attention_impl(layer, last_only)
     )
-    return out.transpose(0, 2, 1, 3).reshape(bsz, t, layer.num_heads * dh) @ p["wo"]
+    return out.transpose(0, 2, 1, 3).reshape(
+        bsz, h_q.shape[1], layer.num_heads * dh
+    ) @ p["wo"]
 
 
 def _swiglu(p, h, prefix: str = ""):
@@ -750,7 +767,7 @@ def yarn_inv_freq(layer: LatentBlock) -> np.ndarray:
     return (extra / layer.rope_factor * ramp + extra * (1 - ramp)).astype(np.float32)
 
 
-def _latent_attention(layer: LatentBlock, p, h):
+def _latent_attention(layer: LatentBlock, p, h, last_only: bool = False):
     """Multi-head latent attention (DeepSeek-V2 section 2.1) on (B, T, d):
     ``c_q = RMSNorm(h W_dq)``; ``[q_nope | q_rope] = c_q W_uq`` a head;
     ``[c_kv | k_r] = h W_dkv``, ``c_kv = RMSNorm(c_kv)``; ``[k_nope | v] =
@@ -759,16 +776,21 @@ def _latent_attention(layer: LatentBlock, p, h):
     ``softmax(q k^T s) v`` with ``s = (nope + rope)^-1/2 m^2``, ``m`` YaRN's
     ``0.1 mscale_all_dim ln(factor) + 1``; out ``concat(o) W_o``. No bias.
     RoPE's cos and sin are scaled by ``mscale / mscale_all_dim``'s ratio of
-    temperatures (1 in the published config)."""
+    temperatures (1 in the published config). With ``last_only`` the query
+    side (``W_dq``, ``W_uq``, the scores, ``W_o``) is the last position's
+    alone, its RoPE turned by ``T - 1``, and the keys and values every
+    position's: (B, 1, d) out."""
     bsz, t, _ = h.shape
     heads, rank_kv = layer.num_heads, layer.kv_lora_rank
     nope, rope, dv = layer.qk_nope_head_dim, layer.qk_rope_head_dim, layer.v_head_dim
+    h_q = h[:, -1:] if last_only else h
+    t_q = h_q.shape[1]
 
     def split_heads(a):
-        return a.reshape(bsz, t, heads, -1).transpose(0, 2, 1, 3)
+        return a.reshape(bsz, a.shape[1], heads, -1).transpose(0, 2, 1, 3)
 
     with jax.named_scope("mla_down"):
-        c_q = _rms(h @ p["w_dq"], p["q_norm"], layer.norm_eps)
+        c_q = _rms(h_q @ p["w_dq"], p["q_norm"], layer.norm_eps)
         down = h @ p["w_dkv"]
         c_kv = _rms(down[..., :rank_kv], p["kv_norm"], layer.norm_eps)
         k_rope = down[..., rank_kv:]
@@ -781,24 +803,23 @@ def _latent_attention(layer: LatentBlock, p, h):
             factor, layer.rope_mscale_all_dim
         )
 
-        def rotary(a):
-            a = _rope(a, layer.rope_theta, inv_freq)
+        def rotary(a, first=0):
+            a = _rope(a, layer.rope_theta, inv_freq, first)
             return a if turn == 1.0 else (a * turn).astype(a.dtype)
 
-        q = jnp.concatenate([q[..., :nope], rotary(q[..., nope:])], axis=-1)
+        q = jnp.concatenate([q[..., :nope], rotary(q[..., nope:], t - t_q)], axis=-1)
         k_rope = jnp.broadcast_to(
             rotary(k_rope[:, None]), (bsz, heads, t, rope)
         )
         k = jnp.concatenate([kv[..., :nope], k_rope], axis=-1)
         v = kv[..., nope:]
     scale = (nope + rope) ** -0.5 * yarn_mscale(factor, layer.rope_mscale_all_dim) ** 2
-    impl = layer.attention_impl
     with jax.named_scope("attention"):
         out = dot_product_attention(
-            q, k, v, causal=True, impl=None if impl == "auto" else impl, scale=scale
+            q, k, v, causal=True, impl=_attention_impl(layer, last_only), scale=scale
         )
     with jax.named_scope("mla_out"):
-        return out.transpose(0, 2, 1, 3).reshape(bsz, t, heads * dv) @ p["wo"]
+        return out.transpose(0, 2, 1, 3).reshape(bsz, t_q, heads * dv) @ p["wo"]
 
 
 def hc_coefficients(layer: LatentBlock, p, prefix: str, x):
@@ -953,6 +974,8 @@ def zero_stats(spec: ModelSpec) -> Dict[str, jnp.ndarray]:
         if isinstance(layer, LatentBlock):
             stats["hc_sublayer_steps"] = jnp.zeros((), jnp.int32)
             stats["hc_stochastic_gap"] = jnp.zeros((), jnp.float32)
+    if _last_only_block(spec) is not None:
+        stats["last_position_steps"] = jnp.zeros((), jnp.int32)
     return stats
 
 
@@ -1000,12 +1023,21 @@ def routed_ffn(layer, p, h, scale: float = 1.0, gate_eps: float = 1e-6):
     }
 
 
-def _apply_hybrid_block(layer: HybridBlock, p, x):
+def _apply_hybrid_block(layer: HybridBlock, p, x, last_only: bool = False):
     """x: (batch, time, d_model) → ``(x, stats)``; ``stats`` is empty for a
-    dense FFN."""
-    h = _rms_norm(x, p["op_norm"], layer.norm_eps)
-    operator = _gated_conv if layer.operator == "conv" else _gqa_attention
-    x = x + operator(layer, p, h)
+    dense FFN. With ``last_only`` → (batch, 1, d_model), the last position's
+    state alone (:func:`_last_only_block`): a convolution reads the last
+    ``conv_kernel`` positions, attention every position's keys and values,
+    and the FFN sees the last position's tokens."""
+    if layer.operator == "conv":
+        # the last row of the causal convolution over the last kw rows is the
+        # whole sequence's last row: it reads no row before them
+        src = x[:, -layer.conv_kernel :] if last_only else x
+        y = _gated_conv(layer, p, _rms_norm(src, p["op_norm"], layer.norm_eps))
+        y = y[:, -1:] if last_only else y
+    else:
+        y = _gqa_attention(layer, p, _rms_norm(x, p["op_norm"], layer.norm_eps), last_only)
+    x = (x[:, -1:] if last_only else x) + y
     h = _rms_norm(x, p["ffn_norm"], layer.norm_eps)
     if layer.ffn == "dense":
         return x + _swiglu(p, h), {}
@@ -1019,17 +1051,21 @@ def _apply_hybrid_block(layer: HybridBlock, p, x):
 # batched once and not once each, and XLA inlines the calls again: a model of
 # 1 + 4 layers traces and lowers in 12 s where it took 25 (the chunk
 # program of xing4_0_29b_a4b on a sandbox's CPU, PERF.md section 6, PR 37)
-@functools.partial(jax.jit, static_argnums=0)
-def _apply_latent_block(layer: LatentBlock, p, x):
+@functools.partial(jax.jit, static_argnums=(0, 3))
+def _apply_latent_block(layer: LatentBlock, p, x, last_only: bool = False):
     """x: (streams, batch, time, d_model) → ``(x, stats)``. For each of the
     two sublayers ``F`` (latent attention, then the FFN): ``X <- H_res X +
     H_post^T F(RMSNorm(H_pre X))`` (:func:`hc_coefficients`). The routed FFN
     is ``Shared(h) + sum over picked and held experts of w_e Expert_e(h)``:
     the shared expert is computed for every token on every share of the
-    layer, the routed part as :func:`routed_ffn` gives it."""
-    b, t, d = x.shape[1:]
+    layer, the routed part as :func:`routed_ffn` gives it. With
+    ``last_only`` → (streams, batch, 1, d_model), the last position's state
+    alone (:func:`_last_only_block`): the attention sublayer's coefficients,
+    ``H_pre X`` and its keys and values at every position, its queries and
+    its write at the last; the FFN sublayer wholly at the last position."""
 
     def ffn(h):
+        b, t, d = h.shape
         if layer.ffn == "dense":
             return _swiglu(p, h), {}
         # the family's gate adds 1e-20 to the picked scores' sum, not 1e-6
@@ -1043,7 +1079,7 @@ def _apply_latent_block(layer: LatentBlock, p, x):
         return out, stats
 
     sublayers = (
-        ("hc_op_", "op_norm", lambda h: (_latent_attention(layer, p, h), {})),
+        ("hc_op_", "op_norm", lambda h: (_latent_attention(layer, p, h, last_only), {})),
         ("hc_ffn_", "ffn_norm", ffn),
     )
     stats = {"hc_sublayer_steps": jnp.asarray(len(sublayers), jnp.int32)}
@@ -1051,6 +1087,8 @@ def _apply_latent_block(layer: LatentBlock, p, x):
     for mixing, norm, sublayer in sublayers:
         pre, post, res, gap = hc_coefficients(layer, p, mixing, x)
         y, counted = sublayer(_rms_norm(_hc_read(pre, x), p[norm], layer.norm_eps))
+        if last_only:
+            post, res, x = post[..., -1:], res[..., -1:], x[:, :, -1:]
         x = _hc_write(res, post, x, y)
         gaps = gaps + gap
         stats.update(counted)
@@ -1121,6 +1159,34 @@ def _apply_pool(layer: PoolLayer, x):
     if layer.mode == "max":
         return jnp.max(x, axis=1)
     raise ValueError(f"Unknown pool mode {layer.mode!r}")
+
+
+def _last_only_block(spec: ModelSpec):
+    """The index of the layer that runs ``last_only``, or None: the spec's
+    last hybrid or latent block, where every layer after it up to a
+    ``PoolLayer(mode="last")`` is position-wise (``RMSNormLayer``,
+    ``StreamLayer(mode="collapse")``). Nothing reads that block's other
+    positions: no later block asks for their keys and values, and the pool
+    keeps one. A ``DenseLayer`` in between does not qualify (its
+    ``l1_activity`` sums over positions), nor a mean or max pool.
+    ``TransformerBlock``, ``MoEBlock``, ``TCNBlock`` and ``LSTMLayer`` specs
+    keep every position: no benchmark cell runs them, and the
+    pipeline-parallel path consumes ``TransformerBlock``s whole."""
+    blocks = [
+        i for i, layer in enumerate(spec.layers)
+        if isinstance(layer, (HybridBlock, LatentBlock))
+    ]
+    if not blocks:
+        return None
+    for layer in spec.layers[blocks[-1] + 1 :]:
+        if isinstance(layer, PoolLayer):
+            return blocks[-1] if layer.mode == "last" else None
+        position_wise = isinstance(layer, RMSNormLayer) or (
+            isinstance(layer, StreamLayer) and layer.mode == "collapse"
+        )
+        if not position_wise:
+            return None
+    return None
 
 
 def apply_model(spec: ModelSpec, params: Params, x: jnp.ndarray):
@@ -1200,6 +1266,7 @@ def apply_model_stats(spec: ModelSpec, params: Params, x: jnp.ndarray):
     def _fuse(layer):
         return getattr(layer, "fuse_qkv", True) and not tp_active
 
+    trimmed = _last_only_block(spec)
     penalty = jnp.asarray(0.0, jnp.float32)
     stats: Dict[str, jnp.ndarray] = {}
     for i, (layer, p) in enumerate(zip(spec.layers, params)):
@@ -1254,7 +1321,11 @@ def apply_model_stats(spec: ModelSpec, params: Params, x: jnp.ndarray):
                 _apply_hybrid_block if isinstance(layer, HybridBlock)
                 else _apply_latent_block
             )
+            if i == trimmed:
+                block = functools.partial(block, last_only=True)
             out, counted = _seq_layer(block, layer, p, out)
+            if i == trimmed:
+                counted = dict(counted, last_position_steps=jnp.asarray(1, jnp.int32))
             stats.update(
                 {key: stats.get(key, 0) + value for key, value in counted.items()}
             )

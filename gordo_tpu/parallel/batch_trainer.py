@@ -406,8 +406,9 @@ def _predict_windows(spec: ModelSpec, params, X):
 
 def _note_layer_counts(counted: Dict[str, np.ndarray], rows, n_live: int) -> None:
     """Add a chunk's layer counts (a value a lane; padding lanes left out) to
-    the build's counters: the routed layers', and a latent block's mixing.
-    Nothing to add for a spec that has neither."""
+    the build's counters: the routed layers', a latent block's mixing, and
+    the blocks run at the last position alone. Nothing to add for a spec that
+    has none of them."""
     if not counted:
         return
     live = np.asarray(rows) < n_live
@@ -421,6 +422,8 @@ def _note_layer_counts(counted: Dict[str, np.ndarray], rows, n_live: int) -> Non
     if "hc_sublayer_steps" in total:
         metric_catalog.HC_SUBLAYER_STEPS.inc(int(total["hc_sublayer_steps"]))
         metric_catalog.HC_STOCHASTIC_GAP.inc(float(total["hc_stochastic_gap"]))
+    if "last_position_steps" in total:
+        metric_catalog.LAST_POSITION_BLOCK_STEPS.inc(int(total["last_position_steps"]))
 
 
 @functools.lru_cache(maxsize=64)

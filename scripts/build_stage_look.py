@@ -5,8 +5,9 @@ The benchmark's traced run prints the stages only as sums (``persist`` is
 builds as the harness does (``chipbench.run.Fleet``: the same machines from
 the seed, the same ``BatchedModelBuilder`` call) with spans on and no
 profiler, and prints one JSON line a build: its wall, every
-``gordo_build_phase_seconds`` label's seconds in that build, and the bytes of
-``model.pkl`` it wrote where the program counts them. Each build's artifacts
+``gordo_build_phase_seconds`` label's seconds in that build, the bytes of
+``model.pkl`` it wrote and the blocks it ran at the last position alone (a
+live training step each) where the program counts them. Each build's artifacts
 are removed as soon as it is read; with ``--keep`` they stay until the end, as
 they do in the benchmark's window (and in a deployment), so that a build's
 write meets the write-back of the artifacts before it.
@@ -28,6 +29,7 @@ import sys
 import tempfile
 
 ARTIFACT_BYTES = "gordo_build_artifact_bytes_total"
+LAST_POSITION_STEPS = "gordo_build_last_position_block_steps_total"
 
 
 def main() -> int:
@@ -80,6 +82,10 @@ def main() -> int:
             }
             if ARTIFACT_BYTES in after:
                 line["artifact_bytes"] = after[ARTIFACT_BYTES] - before.get(ARTIFACT_BYTES, 0)
+            if LAST_POSITION_STEPS in after:
+                line["last_position_block_steps"] = after[LAST_POSITION_STEPS] - before.get(
+                    LAST_POSITION_STEPS, 0
+                )
             print(json.dumps(line), flush=True)
     finally:
         shutil.rmtree(out_root, ignore_errors=True)

@@ -59,9 +59,10 @@ def test_outputs_hold_one_copy_of_the_parameters(make_spec):
     assert n_out == 2 * n_one  # two machines, one stage each: not four
     assert losses.shape == (2, 1) and len(preds) == len(FOLDS)
     mixing = {"hc_sublayer_steps", "hc_stochastic_gap"}
+    trimmed = {"last_position_steps"}
     assert set(counted) == {
-        _lstm_spec: set(), _hybrid_spec: set(nn.MOE_STATS),
-        _latent_spec: set(nn.MOE_STATS) | mixing,
+        _lstm_spec: set(), _hybrid_spec: set(nn.MOE_STATS) | trimmed,
+        _latent_spec: set(nn.MOE_STATS) | mixing | trimmed,
     }[make_spec]
 
 
@@ -112,14 +113,16 @@ def test_routed_counts_leave_the_program_and_reach_the_counters():
     X, seeds = _data()
     *_, counted = program(X, X, seeds)
     counted = jax.device_get(counted)
-    # live steps a machine: ceil(windows / batch) of each stage; two routed layers
+    # live steps a machine: ceil(windows / batch) of each stage; two routed
+    # layers, the last of them at each window's last position alone
     steps = sum(-(-n_train_samples(spec, rows) // BATCH) for rows in (16, 32, 48, 64))
-    tokens = BATCH * LOOKBACK
+    tokens = BATCH * LOOKBACK + BATCH
     for lane in range(2):
         assert counted["moe_layer_steps"][lane] == 2 * steps
-        assert counted["moe_tokens"][lane] == 2 * steps * tokens
+        assert counted["last_position_steps"][lane] == steps
+        assert counted["moe_tokens"][lane] == steps * tokens
         held, absent = counted["moe_held"][lane], counted["moe_absent"][lane]
-        assert held + absent == 2 * steps * tokens * 4 and held > 0 and absent > 0
+        assert held + absent == steps * tokens * 4 and held > 0 and absent > 0
         # the fullest of two held experts took at least half of what was held
         assert held / 2 <= counted["moe_peak_load"][lane] <= held
 
@@ -130,6 +133,7 @@ def test_routed_counts_leave_the_program_and_reach_the_counters():
             "tokens": metric_catalog.MOE_TOKENS.value(),
             "layer_steps": metric_catalog.MOE_LAYER_STEPS.value(),
             "peak": metric_catalog.MOE_PEAK_LOAD.value(),
+            "last_position": metric_catalog.LAST_POSITION_BLOCK_STEPS.value(),
         }
 
     before = read()
@@ -141,5 +145,6 @@ def test_routed_counts_leave_the_program_and_reach_the_counters():
     assert after["tokens"] - before["tokens"] == counted["moe_tokens"][0]
     assert after["layer_steps"] - before["layer_steps"] == 2 * steps
     assert after["peak"] - before["peak"] == counted["moe_peak_load"][0]
+    assert after["last_position"] - before["last_position"] == steps
     batch_trainer._note_layer_counts({}, np.arange(2), 2)  # nothing routed: nothing added
     assert read() == after

@@ -13,7 +13,7 @@ import pytest
 from chipbench import reference
 from chipbench.configs import lfm2_moe
 from gordo_tpu.models.factories.hybrid import hybrid_moe_model
-from gordo_tpu.models.spec import HybridBlock
+from gordo_tpu.models.spec import HybridBlock, PoolLayer, RMSNormLayer
 from gordo_tpu.ops import nn
 
 MODEL = {
@@ -188,3 +188,70 @@ def test_factory_refuses_a_share_outside_the_router():
         _spec(_model(["conv"], ["routed"], experts_held=8, expert_offset=4))
     with pytest.raises(ValueError, match="one entry a layer"):
         _spec(_model(["conv", "conv"], ["routed"]))
+
+
+def _whole(spec, params, x):
+    """The model with every block at every position (the block's own
+    ``last_only=False``), then the last position: what the trimmed model
+    must equal."""
+    out = x
+    for layer, p in zip(spec.layers, params):
+        if isinstance(layer, HybridBlock):
+            out = nn._apply_hybrid_block(layer, p, out, False)[0]
+        elif isinstance(layer, RMSNormLayer):
+            out = nn._rms_norm(out, p["scale"], layer.eps)
+        elif isinstance(layer, PoolLayer):
+            out = nn._apply_pool(layer, out)
+        else:
+            out = nn._apply_dense(layer, p, out)
+    return out
+
+
+@pytest.mark.parametrize(
+    "operators,ffns,over,remat",
+    [
+        (["attention", "conv"], ["dense", "routed"], {}, False),
+        (["conv", "attention"], ["routed", "dense"], {}, False),
+        (["attention", "conv"], ["dense", "routed"], {}, True),
+        (["attention", "conv"], ["routed", "routed"], {"lookback_window": 2}, False),
+    ],
+    ids=["conv-routed-last", "attention-dense-last", "remat", "lookback-under-kernel"],
+)
+def test_the_trimmed_last_block_is_the_whole_blocks_last_position(operators, ffns, over, remat):
+    """The model's last block at the last position alone gives what the
+    whole block gives there, in value and in every leaf's gradient, to the
+    rounding of a reordered sum (float32)."""
+    model = _model(operators, ffns, **over)
+    spec = dataclasses.replace(_spec(model), remat=remat)
+    assert nn._last_only_block(spec) == 2  # the tag projection, two blocks
+    params = jax.tree_util.tree_map(
+        lambda a: a + 0.3 * jax.random.normal(jax.random.PRNGKey(a.size), a.shape),
+        nn.init_model_params(jax.random.PRNGKey(5), spec),
+    )
+    t = model["lookback_window"]
+    x = jax.random.normal(jax.random.PRNGKey(6), (3, t, N_TAGS))
+    y = jax.random.normal(jax.random.PRNGKey(7), (3, N_TAGS))
+
+    def loss(forward):
+        return jax.value_and_grad(lambda p: jnp.mean((forward(p) - y) ** 2))(params)
+
+    out, _, counted = nn.apply_model_stats(spec, params, x)
+    want = _whole(spec, params, x)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want), rtol=1e-5, atol=1e-6)
+    (l_cut, g_cut), (l_whole, g_whole) = (
+        loss(lambda p: nn.apply_model_stats(spec, p, x)[0]),
+        loss(lambda p: _whole(spec, p, x)),
+    )
+    np.testing.assert_allclose(float(l_cut), float(l_whole), rtol=1e-5)
+    for layer_cut, layer_whole in zip(g_cut, g_whole):
+        # a leaf whose gradient nearly cancels (a mixing bias) is held to
+        # the rounding of its layer's largest terms, not to its own size
+        scale = max((float(np.abs(np.asarray(g)).max()) for g in layer_whole.values()), default=0)
+        for name in layer_cut:
+            a, b = np.asarray(layer_cut[name]), np.asarray(layer_whole[name])
+            np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6 * scale, err_msg=name)
+    # one block ran at the last position; a routed one routed 3 tokens there
+    assert int(counted["last_position_steps"]) == 1
+    if ffns[-1] == "routed":
+        tokens = 3 * t * (ffns[0] == "routed") + 3
+        assert int(counted["moe_tokens"]) == tokens

@@ -13,7 +13,7 @@ import pytest
 from chipbench import reference
 from chipbench.configs import xing_moe
 from gordo_tpu.models.factories.latent import latent_moe_model
-from gordo_tpu.models.spec import LatentBlock
+from gordo_tpu.models.spec import LatentBlock, PoolLayer, RMSNormLayer, StreamLayer
 from gordo_tpu.ops import attention, flops, nn
 
 YARN = {
@@ -280,8 +280,8 @@ def test_the_shares_add_up_with_the_shared_expert_counted_once():
 
 
 def test_counters_come_out_of_the_compiled_epoch():
-    """The mixing's two counters and the routed layers' five, summed over the
-    live steps of a compiled epoch."""
+    """The mixing's two counters, the routed layers' five and the trimmed
+    block's one, summed over the live steps of a compiled epoch."""
     from gordo_tpu.ops.train import make_masked_epoch_fn
     from gordo_tpu.ops.train import make_optimizer
 
@@ -300,8 +300,67 @@ def test_counters_come_out_of_the_compiled_epoch():
     assert int(stats["hc_sublayer_steps"]) == steps * 2 * 2  # two blocks, two sublayers
     assert 0.0 <= float(stats["hc_stochastic_gap"]) < 1e-2
     assert int(stats["moe_layer_steps"]) == steps
-    assert int(stats["moe_tokens"]) == steps * batch * 12
-    assert int(stats["moe_held"]) + int(stats["moe_absent"]) == steps * batch * 12 * 2
+    # the routed block is the last: it routes each window's last position alone
+    assert int(stats["last_position_steps"]) == steps
+    assert int(stats["moe_tokens"]) == steps * batch
+    assert int(stats["moe_held"]) + int(stats["moe_absent"]) == steps * batch * 2
+
+
+def _whole(spec, params, x):
+    """The model with every block at every position (the block's own
+    ``last_only=False``), then the last position: what the trimmed model
+    must equal."""
+    out = x
+    for layer, p in zip(spec.layers, params):
+        if isinstance(layer, LatentBlock):
+            out = nn._apply_latent_block(layer, p, out, False)[0]
+        elif isinstance(layer, StreamLayer):
+            out = nn._apply_stream_layer(layer, out)
+        elif isinstance(layer, RMSNormLayer):
+            out = nn._rms_norm(out, p["scale"], layer.eps)
+        elif isinstance(layer, PoolLayer):
+            out = nn._apply_pool(layer, out)
+        else:
+            out = nn._apply_dense(layer, p, out)
+    return out
+
+
+@pytest.mark.parametrize("remat", [False, True], ids=["plain", "remat"])
+def test_the_trimmed_last_block_is_the_whole_blocks_last_position(remat):
+    """The last latent block (routed, with the shared expert) at the last
+    position alone gives what the whole block gives there, in value and in
+    every leaf's gradient, to the rounding of a reordered sum (float32)."""
+    model = _model(["dense", "routed"])
+    spec = dataclasses.replace(_spec(model), remat=remat)
+    assert nn._last_only_block(spec) == 3  # projection, expansion, two blocks
+    params = _stirred(nn.init_model_params(jax.random.PRNGKey(5), spec))
+    x = jax.random.normal(jax.random.PRNGKey(6), (3, 12, N_TAGS))
+    y = jax.random.normal(jax.random.PRNGKey(7), (3, N_TAGS))
+
+    def loss(forward):
+        return jax.value_and_grad(lambda p: jnp.mean((forward(p) - y) ** 2))(params)
+
+    out, _, counted = nn.apply_model_stats(spec, params, x)
+    want = _whole(spec, params, x)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want), rtol=1e-5, atol=1e-6)
+    (l_cut, g_cut), (l_whole, g_whole) = (
+        loss(lambda p: nn.apply_model_stats(spec, p, x)[0]),
+        loss(lambda p: _whole(spec, p, x)),
+    )
+    np.testing.assert_allclose(float(l_cut), float(l_whole), rtol=1e-5)
+    for layer_cut, layer_whole in zip(g_cut, g_whole):
+        # a leaf whose gradient nearly cancels (a mixing bias) is held to
+        # the rounding of its layer's largest terms, not to its own size
+        scale = max((float(np.abs(np.asarray(g)).max()) for g in layer_whole.values()), default=0)
+        for name in layer_cut:
+            a, b = np.asarray(layer_cut[name]), np.asarray(layer_whole[name])
+            np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6 * scale, err_msg=name)
+    # every leaf of the trimmed block still takes a gradient
+    for name, grad in g_cut[3].items():
+        assert name == "expert_bias" or np.asarray(grad).any(), name
+    # the routed FFN saw the last position's 3 tokens; both sublayers mixed
+    assert int(counted["last_position_steps"]) == 1 and int(counted["moe_tokens"]) == 3
+    assert int(counted["hc_sublayer_steps"]) == 4
 
 
 def test_flops_count_is_the_plain_models():
