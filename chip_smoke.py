@@ -120,6 +120,9 @@ def require_tpu() -> dict:
 
 # report key -> ``source`` label of gordo_build_xla_compiles_total
 _COMPILE_SOURCES = {"compiles": "compiled", "cache_hits": "persistent_cache"}
+# ``stage`` labels of gordo_build_xla_compile_seconds_total that the backend
+# spent: compiling a program, or loading it from the persistent cache
+_BACKEND_STAGES = ("compile", "load")
 
 
 def _compile_counts() -> dict:
@@ -132,7 +135,10 @@ def _compile_counts() -> dict:
             key: int(metric_catalog.XLA_COMPILES.value(source=source))
             for key, source in _COMPILE_SOURCES.items()
         },
-        "compile_wall_s": round(metric_catalog.XLA_COMPILE_SECONDS.value(), 2),
+        "compile_wall_s": round(
+            sum(metric_catalog.XLA_COMPILE_SECONDS.value(stage=s) for s in _BACKEND_STAGES),
+            2,
+        ),
     }
 
 
@@ -492,7 +498,11 @@ def build_leg(workdir: str, sizes=BUILD) -> dict:
             for key, source in _COMPILE_SOURCES.items()
         },
         "compile_wall_s": round(
-            _prom_sum(metrics, "gordo_build_xla_compile_seconds_total"), 2
+            sum(
+                _prom_sum(metrics, "gordo_build_xla_compile_seconds_total", f'stage="{s}"')
+                for s in _BACKEND_STAGES
+            ),
+            2,
         ),
         **checks,
     }
@@ -687,7 +697,11 @@ def serve_leg(workdir: str, sizes=SERVE, tags: int = BUILD["tags"]) -> dict:
             for key, source in _COMPILE_SOURCES.items()
         },
         "compile_wall_s": round(
-            _metric(after, "gordo_build_xla_compile_seconds_total"), 2
+            sum(
+                _metric(after, "gordo_build_xla_compile_seconds_total", s)
+                for s in _BACKEND_STAGES
+            ),
+            2,
         ),
         "workers": len(workers),
         "devices_per_worker": answering["count"],

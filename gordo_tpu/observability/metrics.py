@@ -138,8 +138,27 @@ XLA_COMPILES = telemetry.counter(
 )
 XLA_COMPILE_SECONDS = telemetry.counter(
     "gordo_build_xla_compile_seconds_total",
-    "Wall seconds this process spent waiting for XLA to compile programs or "
-    "load them from the persistent cache",
+    "Wall seconds this process spent getting programs ready, by stage: trace "
+    "(Python to jaxpr), lower (jaxpr to MLIR), compile (the backend compiled "
+    "it) or load (read back from the persistent cache); each stage the union "
+    "of its intervals on a thread, so a nested trace counts once",
+    ("stage",),
+)
+TRANSFER_BYTES = telemetry.counter(
+    "gordo_build_transfer_bytes_total",
+    "Bytes the fleet build moved across the host link, by direction: h2d "
+    "(a chunk's stacked data, seeds, warm parameters and a bucket's fold "
+    "permutations) or d2h (a chunk's parameters, losses, fold predictions, "
+    "verdict flags and statistics); over the d2h phase's seconds: the "
+    "pull's rate",
+    ("direction",),
+)
+FETCH_STARVED_CHUNKS = telemetry.counter(
+    "gordo_build_fetch_starved_chunks_total",
+    "Chunks after a bucket's first that waited for their own fetches while "
+    "the chunk before them had already finished on the device: the device "
+    "idled for the host's fetch (0 a chunk whose wait ran under the chunk "
+    "before)",
 )
 FLEET_COMPILE_FAILURES = telemetry.counter(
     "gordo_build_fleet_compile_failures_total",

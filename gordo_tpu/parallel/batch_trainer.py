@@ -426,6 +426,15 @@ def _note_layer_counts(counted: Dict[str, np.ndarray], rows, n_live: int) -> Non
         metric_catalog.LAST_POSITION_BLOCK_STEPS.inc(int(total["last_position_steps"]))
 
 
+_H2D = metric_catalog.TRANSFER_BYTES.labels(direction="h2d")
+_D2H = metric_catalog.TRANSFER_BYTES.labels(direction="d2h")
+
+
+def _nbytes(tree) -> int:
+    """Bytes of a tree of host arrays."""
+    return sum(leaf.nbytes for leaf in jax.tree_util.tree_leaves(tree))
+
+
 @functools.lru_cache(maxsize=64)
 def _bucket_program(
     spec: ModelSpec,
@@ -1673,20 +1682,26 @@ class BatchedModelBuilder:
                 perms_d = distributed.make_global_stacked(
                     NamedSharding(self.mesh, PartitionSpec()), perms
                 )
+                _H2D.inc(perms.nbytes)
 
         t0 = time.time()
 
-        def take_late(start: int):
+        def take_late(start: int, before):
             """A later chunk's machines: their fetches ran under the chunks
             before it, and whatever is left of them the device now waits
-            for."""
+            for. Where it waited and the chunk before (``before``, its
+            outputs) had finished by the end of the wait, the device idled
+            for the fetch: that chunk starved."""
             late = sum(
                 1
                 for plan, _ in members[start : start + chunk]
                 if plan.fetch is not None and not plan.fetch.done()
             )
             with _stage("fetch_wait", chunk_start=start, machines=late):
-                return take(start)
+                group = take(start)
+            starved = late > 0 and jax.tree_util.tree_leaves(before)[0].is_ready()
+            metric_catalog.FETCH_STARVED_CHUNKS.inc(int(starved))
+            return group
 
         def dispatch(start: int, group):
             with _stage("stack_h2d", chunk_start=start):
@@ -1705,6 +1720,7 @@ class BatchedModelBuilder:
                 X_d = distributed.make_global_stacked(sharding, X)
                 y_d = distributed.make_global_stacked(sharding, y)
                 seeds_d = distributed.make_global_stacked(sharding, seeds)
+                _H2D.inc(X.nbytes + y.nbytes + seeds.nbytes)
                 args = (X_d, y_d, seeds_d)
                 if perms_d is not None:
                     args = args + (perms_d,)
@@ -1722,6 +1738,7 @@ class BatchedModelBuilder:
                         lambda a: distributed.make_global_stacked(sharding, a),
                         stacked,
                     )
+                    _H2D.inc(_nbytes(stacked))
                     args = args + (warm_d,)
             with _stage("launch", chunk_start=start):
                 # returns once the execution is queued (the first call
@@ -1756,28 +1773,27 @@ class BatchedModelBuilder:
                     # one batched host transfer for the whole tree
                     losses_np = np.asarray(jax.device_get(losses))
                     rows = np.arange(losses_np.shape[0])
-                    _note_layer_counts(jax.device_get(counted), rows, len(group))
-                    return (
-                        group,
-                        rows,
-                        jax.device_get(params_stack),
-                        losses_np,
-                        [np.asarray(jax.device_get(fp)) for fp in fold_preds],
-                        np.asarray(jax.device_get(non_finite)),
+                    counted_np = jax.device_get(counted)
+                    params_np = jax.device_get(params_stack)
+                    fold_preds_np = [np.asarray(jax.device_get(fp)) for fp in fold_preds]
+                    non_finite_np = np.asarray(jax.device_get(non_finite))
+                else:
+                    # multi-process: only this host's rows are addressable;
+                    # every output shares the machines sharding, so the rows
+                    # from `losses` apply to all leaves
+                    rows, losses_np = distributed.local_rows(losses)
+                    counted_np = {
+                        k: distributed.local_rows(v)[1] for k, v in counted.items()
+                    }
+                    params_np = jax.tree_util.tree_map(
+                        lambda a: distributed.local_rows(a)[1], params_stack
                     )
-                # multi-process: only this host's rows are addressable; every
-                # output shares the machines sharding, so the rows from `losses`
-                # apply to all leaves
-                rows, losses_np = distributed.local_rows(losses)
-                _note_layer_counts(
-                    {k: distributed.local_rows(v)[1] for k, v in counted.items()},
-                    rows, len(group),
-                )
-                params_np = jax.tree_util.tree_map(
-                    lambda a: distributed.local_rows(a)[1], params_stack
-                )
-                fold_preds_np = [distributed.local_rows(fp)[1] for fp in fold_preds]
-                non_finite_np = distributed.local_rows(non_finite)[1]
+                    fold_preds_np = [distributed.local_rows(fp)[1] for fp in fold_preds]
+                    non_finite_np = distributed.local_rows(non_finite)[1]
+                _D2H.inc(_nbytes(
+                    (params_np, losses_np, fold_preds_np, non_finite_np, counted_np)
+                ))
+                _note_layer_counts(counted_np, rows, len(group))
                 return group, rows, params_np, losses_np, fold_preds_np, non_finite_np
 
         # host-side assembly per machine (its slice of the chunk, threshold
@@ -1831,7 +1847,7 @@ class BatchedModelBuilder:
                 chunk=chunk,
             ):
                 for start in starts[1:]:
-                    group = take_late(start)
+                    group = take_late(start, in_flight[1])
                     if not group:
                         continue  # every machine of the chunk was lost
                     next_in_flight = dispatch(start, group)

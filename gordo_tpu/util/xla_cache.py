@@ -213,13 +213,31 @@ DEFAULT_CACHE_DIR = os.path.join(
 
 _listening = False
 
+# jax's compile events (jax/_src/dispatch.py) → the ``stage`` label of
+# gordo_build_xla_compile_seconds_total; a backend compile that a cache hit
+# announced is a ``load``
+_BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
+_STAGES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    _BACKEND_COMPILE: "compile",
+}
+
 
 def _count_compiles() -> None:
     """Feed jax's own compile events into the telemetry registry (once per
     process), so every process that compiles can say how many programs it
     compiled, how many it loaded from the persistent cache, and how long it
-    waited for both — read by ``chip_smoke.py`` from the metrics file of a
-    build and from ``/debug/vars`` of a server."""
+    spent tracing, lowering, compiling and loading them — read by
+    ``chip_smoke.py`` from the metrics file of a build and from
+    ``/debug/vars`` of a server, and by the benchmark's ``setup_*`` readers.
+
+    jax announces each stage's start (a scalar event carrying its start
+    time) and its end (a time span on the ``time.time()`` clock), on the
+    thread that does the work. Stages nest: a ``jit`` traced inside another
+    function's trace has a trace event of its own, inside the outer one. A
+    stage is counted when its outermost event on that thread ends, whole,
+    so the counter adds up the union of the stage's intervals a thread."""
     global _listening
     if _listening:
         return
@@ -228,23 +246,43 @@ def _count_compiles() -> None:
 
     from gordo_tpu.observability import metrics as metric_catalog
 
-    # a cache hit is announced inside the compile-or-load call whose
-    # duration event follows on the same thread
-    hit = threading.local()
+    # per thread: whether a cache hit was announced inside the
+    # compile-or-load call that ends next, and each stage's open events
+    state = threading.local()
+
+    def open_events() -> dict:
+        if not hasattr(state, "open"):
+            state.open = dict.fromkeys(_STAGES, 0)
+        return state.open
 
     def on_event(event: str, **_kwargs) -> None:
         if event == "/jax/compilation_cache/cache_hits":
-            hit.pending = True
+            state.hit = True
 
-    def on_duration(event: str, seconds: float, **_kwargs) -> None:
-        if event != "/jax/core/compile/backend_compile_duration":
+    def on_start(event: str, _value, **_kwargs) -> None:
+        if event in _STAGES:
+            open_events()[event] += 1
+
+    def on_span(event: str, start: float, end: float, **_kwargs) -> None:
+        stage = _STAGES.get(event)
+        if stage is None:
             return
-        source = "persistent_cache" if getattr(hit, "pending", False) else "compiled"
-        hit.pending = False
-        metric_catalog.XLA_COMPILES.labels(source=source).inc()
-        metric_catalog.XLA_COMPILE_SECONDS.inc(seconds)
+        events = open_events()
+        events[event] = max(events[event] - 1, 0)
+        if event == _BACKEND_COMPILE:
+            loaded = getattr(state, "hit", False)
+            state.hit = False
+            metric_catalog.XLA_COMPILES.labels(
+                source="persistent_cache" if loaded else "compiled"
+            ).inc()
+            stage = "load" if loaded else "compile"
+        if events[event] == 0:
+            metric_catalog.XLA_COMPILE_SECONDS.labels(stage=stage).inc(
+                max(end - start, 0.0)
+            )
 
-    jax.monitoring.register_event_duration_secs_listener(on_duration)
+    jax.monitoring.register_event_time_span_listener(on_span)
+    jax.monitoring.register_scalar_listener(on_start)
     jax.monitoring.register_event_listener(on_event)
 
 

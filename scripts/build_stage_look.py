@@ -6,8 +6,10 @@ builds as the harness does (``chipbench.run.Fleet``: the same machines from
 the seed, the same ``BatchedModelBuilder`` call) with spans on and no
 profiler, and prints one JSON line a build: its wall, every
 ``gordo_build_phase_seconds`` label's seconds in that build, the bytes of
-``model.pkl`` it wrote and the blocks it ran at the last position alone (a
-live training step each) where the program counts them. Each build's artifacts
+``model.pkl`` it wrote, the blocks it ran at the last position alone (a
+live training step each), the bytes it moved each way across the host link
+(with the pull's rate over the ``d2h`` stage) and its chunks that starved
+for their fetch, each where the program counts it. Each build's artifacts
 are removed as soon as it is read; with ``--keep`` they stay until the end, as
 they do in the benchmark's window (and in a deployment), so that a build's
 write meets the write-back of the artifacts before it.
@@ -30,6 +32,12 @@ import tempfile
 
 ARTIFACT_BYTES = "gordo_build_artifact_bytes_total"
 LAST_POSITION_STEPS = "gordo_build_last_position_block_steps_total"
+# counters a line carries a build where the program has them, under its key
+COUNTERS = {
+    "h2d_bytes": "gordo_build_transfer_bytes_total{direction=h2d}",
+    "d2h_bytes": "gordo_build_transfer_bytes_total{direction=d2h}",
+    "fetch_starved_chunks": "gordo_build_fetch_starved_chunks_total",
+}
 
 
 def main() -> int:
@@ -86,6 +94,11 @@ def main() -> int:
                 line["last_position_block_steps"] = after[LAST_POSITION_STEPS] - before.get(
                     LAST_POSITION_STEPS, 0
                 )
+            for key, series in COUNTERS.items():
+                if series in after:
+                    line[key] = after[series] - before.get(series, 0)
+            if "d2h_bytes" in line and line["phase_s"].get("d2h"):
+                line["d2h_gbps"] = line["d2h_bytes"] / line["phase_s"]["d2h"] / 1e9
             print(json.dumps(line), flush=True)
     finally:
         shutil.rmtree(out_root, ignore_errors=True)
