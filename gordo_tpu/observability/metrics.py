@@ -98,6 +98,11 @@ HC_STOCHASTIC_GAP = telemetry.counter(
     "|row or column sum - 1| of the streams' mixing matrix after its last "
     "Sinkhorn iteration (over the sublayer-steps: 0 is doubly stochastic)",
 )
+HC_WRITE_KERNEL_STEPS = telemetry.counter(
+    "gordo_build_hc_write_kernel_steps_total",
+    "Of those sublayer-steps, the ones whose write of the streams (H_res X + "
+    "H_post^T y) ran the Pallas kernel pair: on a TPU, where the shapes tile",
+)
 LAST_POSITION_BLOCK_STEPS = telemetry.counter(
     "gordo_build_last_position_block_steps_total",
     "Sequence blocks run at the last position alone (the model's last block "

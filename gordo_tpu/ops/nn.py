@@ -872,9 +872,30 @@ def _hc_read(pre, x):
     return mixed.astype(x.dtype)
 
 
+def _hc_write_kernel_ok(x) -> bool:
+    """Whether the write of streams ``x`` (n, B, T, d) takes the Pallas
+    kernel pair (:mod:`gordo_tpu.ops.pallas_kernels.hc_write`): on a TPU,
+    with ``d`` whole 128-lane vectors and ``B * T`` whole token tiles. The
+    shapes decide, as ``ops/attention.py _flash_ok`` does for attention; the
+    last block's write at the last position alone (B tokens) keeps the jnp
+    body. Pallas is imported here, on a TPU, and not with this module."""
+    if jax.default_backend() != "tpu":
+        return False
+    from gordo_tpu.ops.pallas_kernels.hc_write import tiles
+
+    _, b, t, d = x.shape
+    return tiles(b * t, d)
+
+
 @jax.named_scope("hc_mix")
-def _hc_write(res, post, x, y):
-    """``H_res X + H_post^T y``: the streams after the sublayer."""
+def _hc_write(res, post, x, y, kernel: bool = False):
+    """``H_res X + H_post^T y``: the streams after the sublayer. With
+    ``kernel`` (:func:`_hc_write_kernel_ok`) one pass of the Pallas kernel
+    pair; else the jnp body below, which is the kernel's reference."""
+    if kernel:
+        from gordo_tpu.ops.pallas_kernels.hc_write import hc_write
+
+        return hc_write(res, post, x, y)
     n = x.shape[0]
     x32, y32 = x.astype(jnp.float32), y.astype(jnp.float32)
     rows = [
@@ -965,8 +986,9 @@ MOE_STATS = ("moe_held", "moe_absent", "moe_tokens", "moe_layer_steps", "moe_pea
 def zero_stats(spec: ModelSpec) -> Dict[str, jnp.ndarray]:
     """What :func:`apply_model_stats` counts for ``spec``, at zero: the sums'
     start: int32 counts of the routed layers, and of a latent block's mixing
-    its sublayer-steps (int32) and their summed stochastic gap (float32).
-    Empty for a spec with neither."""
+    its sublayer-steps (int32), their summed stochastic gap (float32) and the
+    writes that took the Pallas kernel pair (int32). Empty for a spec with
+    neither."""
     stats = {}
     for layer in spec.layers:
         if isinstance(layer, (HybridBlock, LatentBlock)) and layer.ffn == "routed":
@@ -974,6 +996,7 @@ def zero_stats(spec: ModelSpec) -> Dict[str, jnp.ndarray]:
         if isinstance(layer, LatentBlock):
             stats["hc_sublayer_steps"] = jnp.zeros((), jnp.int32)
             stats["hc_stochastic_gap"] = jnp.zeros((), jnp.float32)
+            stats["hc_write_kernel_steps"] = jnp.zeros((), jnp.int32)
     if _last_only_block(spec) is not None:
         stats["last_position_steps"] = jnp.zeros((), jnp.int32)
     return stats
@@ -1084,15 +1107,19 @@ def _apply_latent_block(layer: LatentBlock, p, x, last_only: bool = False):
     )
     stats = {"hc_sublayer_steps": jnp.asarray(len(sublayers), jnp.int32)}
     gaps = jnp.zeros((), jnp.float32)
+    kernel_writes = 0
     for mixing, norm, sublayer in sublayers:
         pre, post, res, gap = hc_coefficients(layer, p, mixing, x)
         y, counted = sublayer(_rms_norm(_hc_read(pre, x), p[norm], layer.norm_eps))
         if last_only:
             post, res, x = post[..., -1:], res[..., -1:], x[:, :, -1:]
-        x = _hc_write(res, post, x, y)
+        kernel = _hc_write_kernel_ok(x)
+        x = _hc_write(res, post, x, y, kernel)
+        kernel_writes += kernel
         gaps = gaps + gap
         stats.update(counted)
     stats["hc_stochastic_gap"] = gaps
+    stats["hc_write_kernel_steps"] = jnp.asarray(kernel_writes, jnp.int32)
     return x, stats
 
 

@@ -422,6 +422,7 @@ def _note_layer_counts(counted: Dict[str, np.ndarray], rows, n_live: int) -> Non
     if "hc_sublayer_steps" in total:
         metric_catalog.HC_SUBLAYER_STEPS.inc(int(total["hc_sublayer_steps"]))
         metric_catalog.HC_STOCHASTIC_GAP.inc(float(total["hc_stochastic_gap"]))
+        metric_catalog.HC_WRITE_KERNEL_STEPS.inc(int(total["hc_write_kernel_steps"]))
     if "last_position_steps" in total:
         metric_catalog.LAST_POSITION_BLOCK_STEPS.inc(int(total["last_position_steps"]))
 

@@ -8,8 +8,9 @@ profiler, and prints one JSON line a build: its wall, every
 ``gordo_build_phase_seconds`` label's seconds in that build, the bytes of
 ``model.pkl`` it wrote, the blocks it ran at the last position alone (a
 live training step each), the bytes it moved each way across the host link
-(with the pull's rate over the ``d2h`` stage) and its chunks that starved
-for their fetch, each where the program counts it. Each build's artifacts
+(with the pull's rate over the ``d2h`` stage), its chunks that starved
+for their fetch and its writes of the residual streams that ran the Pallas
+kernel pair, each where the program counts it. Each build's artifacts
 are removed as soon as it is read; with ``--keep`` they stay until the end, as
 they do in the benchmark's window (and in a deployment), so that a build's
 write meets the write-back of the artifacts before it.
@@ -37,6 +38,7 @@ COUNTERS = {
     "h2d_bytes": "gordo_build_transfer_bytes_total{direction=h2d}",
     "d2h_bytes": "gordo_build_transfer_bytes_total{direction=d2h}",
     "fetch_starved_chunks": "gordo_build_fetch_starved_chunks_total",
+    "hc_write_kernel_steps": "gordo_build_hc_write_kernel_steps_total",
 }
 
 

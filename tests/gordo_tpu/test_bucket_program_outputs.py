@@ -58,7 +58,7 @@ def test_outputs_hold_one_copy_of_the_parameters(make_spec):
     n_out = sum(int(np.prod(a.shape)) for a in jax.tree_util.tree_leaves(params))
     assert n_out == 2 * n_one  # two machines, one stage each: not four
     assert losses.shape == (2, 1) and len(preds) == len(FOLDS)
-    mixing = {"hc_sublayer_steps", "hc_stochastic_gap"}
+    mixing = {"hc_sublayer_steps", "hc_stochastic_gap", "hc_write_kernel_steps"}
     trimmed = {"last_position_steps"}
     assert set(counted) == {
         _lstm_spec: set(), _hybrid_spec: set(nn.MOE_STATS) | trimmed,

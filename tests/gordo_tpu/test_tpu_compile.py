@@ -124,3 +124,45 @@ def test_latent_block_compiles_at_the_cells_widths(one_chip, no_compile_cache):
     assert " while(" not in text  # Sinkhorn's twenty iterations are unrolled
     # a layer's gradient step beside its 128 M parameters fits a chip several times over
     assert compiled.memory_analysis().temp_size_in_bytes < 6e9
+
+
+def test_the_streams_write_is_one_mosaic_call_each_way(one_chip, no_compile_cache):
+    """``H_res X + H_post^T y`` at ``xing4_0_29b_a4b``'s widths (four streams
+    of 16 windows x 256 rows x 3,584, bfloat16), its value and the gradients
+    of all four inputs in one program: the Pallas pair compiles to two Mosaic
+    calls (forward, backward) with under 0.1 GB of temporaries, where the jnp
+    body holds float32 intermediates of the streams' size (about 1 GB;
+    PERF.md section 5)."""
+    import jax
+    import jax.numpy as jnp
+
+    from gordo_tpu.ops import nn
+    from gordo_tpu.ops.pallas_kernels.hc_write import hc_write
+
+    n, b, t, d = 4, 16, 256, 3_584
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    args = (
+        shape((n, n, b, t), jnp.float32), shape((n, b, t), jnp.float32),
+        shape((n, b, t, d), jnp.bfloat16), shape((b, t, d), jnp.bfloat16),
+    )
+
+    def value_and_grads(write):
+        def both(res, post, x, y, g):
+            out, pull = jax.vjp(write, res, post, x, y)
+            return out, pull(g)
+
+        return both
+
+    temp = {}
+    for name, write in (("kernel", hc_write), ("jnp", nn._hc_write)):
+        compiled = jax.jit(value_and_grads(write)).lower(
+            *args, shape((n, b, t, d), jnp.bfloat16)
+        ).compile()
+        temp[name] = compiled.memory_analysis().temp_size_in_bytes
+        calls = re.findall(r"custom_call_target=\"tpu_custom_call\"", compiled.as_text())
+        assert len(calls) == {"kernel": 2, "jnp": 0}[name], name
+    # 0 and 0.999 GB when written (compiler, libtpu 0.0.34)
+    assert temp["kernel"] < 0.1e9 and temp["jnp"] > 0.9e9, temp
