@@ -29,7 +29,7 @@ import sys
 import tempfile
 import time
 
-from chipbench import check, reference
+from chipbench import check, reference, traffic
 from chipbench.run import ROOT, Fleet, load_cell, program_gaps, release_program, sample, set_up
 
 
@@ -65,7 +65,8 @@ def main(argv=None) -> int:
                 # before among them, so reference_s holds their reload
                 release_program(devices)
                 t0 = time.time()
-                refs = reference.build_machines(config, names, frames, seed)
+                content = traffic.content_seed(tr, seed)
+                refs = reference.build_machines(config, names, frames, content)
                 t_ref = time.time() - t0
                 line = {
                     "workload": args.workload, "seed": seed, "build_s": t_build,
@@ -87,7 +88,7 @@ def main(argv=None) -> int:
                 record("program", program_gaps(paths, frames, refs, probe))
                 for mode in filter(None, args.modes.split(",")):
                     record(mode, in_place(
-                        reference.build_machines(config, names, frames, seed, **modes[mode])
+                        reference.build_machines(config, names, frames, content, **modes[mode])
                     ))
                 shifted = in_place(refs[1:] + refs[:1])
                 sound = in_place(refs)

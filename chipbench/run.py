@@ -21,6 +21,7 @@ import os
 import shutil
 import sys
 import tempfile
+from concurrent.futures import ThreadPoolExecutor
 from typing import Dict, List, Optional
 
 import numpy as np
@@ -137,13 +138,18 @@ class Fleet:
 
         with jax.profiler.TraceAnnotation("chipbench.make_machines"):
             names = [
-                traffic.machine_name(self.config["name"], self.seed, self.next_index + i)
+                traffic.machine_name(
+                    self.config["name"], self.seed, self.next_index + i, self.traffic
+                )
                 for i in range(n_machines)
             ]
             self.next_index += n_machines
             machines = [
                 Machine.from_config(
-                    traffic.machine_config(self.config, self.traffic, self.seed, name),
+                    traffic.machine_config(
+                        self.config, self.traffic,
+                        traffic.content_seed(self.traffic, self.seed), name,
+                    ),
                     project_name="chipbench",
                 )
                 for name in names
@@ -369,7 +375,8 @@ def sample(cell: dict, seed: int, builds: List[dict]):
         for b, name in picks
     ]
     frames = [
-        traffic.machine_frame(seed, name, int(config["n_tags"]), tr) for name in names
+        traffic.machine_frame(traffic.content_seed(tr, seed), name, int(config["n_tags"]), tr)
+        for name in names
     ]
     return names, paths, frames, slice(*reference.probe_rows(tr.rows, int(config["cv_splits"])))
 
@@ -388,7 +395,9 @@ def compare(cell: dict, seed: int, builds: List[dict]) -> Dict[str, Dict[str, fl
     """Decide ``correct``: the sampled machines against the plain reference."""
     names, paths, frames, probe = sample(cell, seed, builds)
     t0 = time.time()
-    refs = reference.build_machines(cell["config"], names, frames, seed)
+    refs = reference.build_machines(
+        cell["config"], names, frames, traffic.content_seed(cell["traffic"], seed)
+    )
     print(f"chipbench: reference_s {time.time() - t0:.3f}", file=sys.stderr)
     return check.verdict(
         check.typical(program_gaps(paths, frames, refs, probe)), cell["file"]["limits"]
@@ -399,18 +408,35 @@ def run_window(fleet: Fleet, seconds: float, trace_dir: Optional[str]):
     """Build after build until ``seconds`` have passed; the build in flight
     finishes. With ``trace_dir`` one profiler session covers every build.
     Returns the builds and the seconds spent starting and stopping the
-    profiler, which are not the window's."""
+    profiler, which are not the window's.
+
+    Where the check compares the window's last machine alone, a build's
+    artifacts are removed as soon as another build starts, on a thread of
+    their own: written, stat'ed and never read again, gigabytes of them would
+    otherwise pile up on the file system while each later write of the
+    window grew slower, by an amount that varies from run to run
+    (PERF.md)."""
     import jax
 
     builds: List[dict] = []
+    remover = ThreadPoolExecutor(1) if fleet.traffic.check_machines == 1 else None
+    removals = []
     start, unclocked = time.time(), 0.0
     if trace_dir:
         jax.profiler.start_trace(
             trace_dir, profiler_options=profiler_options(WINDOW_TRACE_MODE)
         )
         unclocked = time.time() - start
-    while time.time() - start - unclocked < seconds:
-        builds.append(fleet.build(fleet.traffic.machines_per_build))
+    try:
+        while time.time() - start - unclocked < seconds:
+            if builds and remover:
+                removals.append(remover.submit(shutil.rmtree, builds[-1]["out_dir"], True))
+            builds.append(fleet.build(fleet.traffic.machines_per_build))
+    finally:
+        if remover:
+            remover.shutdown(wait=True)
+            for removal in removals:
+                removal.result()
     if trace_dir:
         t = time.time()
         jax.profiler.stop_trace()

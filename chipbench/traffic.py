@@ -41,6 +41,10 @@ class Traffic:
     train_start_date: str
     signal: Dict[str, object]
     check_machines: int
+    # where nonzero, every run builds machines drawn from one pool of this
+    # many, made from ``pool_seed``; the run's seed orders them
+    distinct_machines: int = 0
+    pool_seed: int = 0
 
     @classmethod
     @functools.lru_cache(maxsize=None)
@@ -55,6 +59,8 @@ class Traffic:
             train_start_date=str(doc["train_start_date"]),
             signal=dict(doc["signal"]),
             check_machines=int(doc["check_machines"]),
+            distinct_machines=int(doc.get("distinct_machines", 0)),
+            pool_seed=int(doc.get("pool_seed", 0)),
         )
 
     @property
@@ -78,10 +84,26 @@ def tag_series(seed: int, tag: str, rows: int, signal: Dict[str, object]) -> np.
     return base + noise + offset
 
 
-def machine_name(config: str, seed: int, index: int) -> str:
+def machine_name(config: str, seed: int, index: int, traffic: Traffic = None) -> str:
     """Names carry the seed and a running index: no two slices and no two
-    seeds share a name, a tag, a weight stream or a registry entry."""
-    return f"cb-{config.replace('_', '-')}-s{int(seed)}-{index:05d}"
+    seeds share a name, a tag, a weight stream or a registry entry. Where
+    the mix has a pool (``distinct_machines``), the name is the pool
+    machine's, taken in an order drawn from the seed: every seed builds the
+    same machines, and so the same work, in another order. A machine's
+    training work varies with its data, where experts are routed by it
+    (PERF.md)."""
+    stem = f"cb-{config.replace('_', '-')}"
+    if traffic is None or not traffic.distinct_machines:
+        return f"{stem}-s{int(seed)}-{index:05d}"
+    n = traffic.distinct_machines
+    order = np.random.default_rng([int(seed), n]).permutation(n)
+    return f"{stem}-pool{int(traffic.pool_seed)}-{int(order[index % n]):02d}"
+
+
+def content_seed(traffic: Traffic, seed: int) -> int:
+    """The seed a machine's rows and initial weights are drawn from: the
+    run's own, or the pool's where the mix has one."""
+    return traffic.pool_seed if traffic.distinct_machines else int(seed)
 
 
 def machine_tags(name: str, n_tags: int) -> List[str]:

@@ -96,9 +96,15 @@ def test_forward_flops_by_hand(config):
     assert quarter / 1e9 == pytest.approx(68.33, abs=0.005)
 
 
+TWIN = "lfm2_tiny.rehearsal"
+LSTM, XING = "lstm_ae_144.fleet_build", "xing4_0_29b_a4b.single_build"
+# each reader this configuration brought, and the cells BENCHMARK.json lists
+# it for: those where a traced run finds something to read
 READERS = {
-    "moe_expert_share", "moe_route_share", "gated_conv_share", "optimizer_share",
-    "moe_held_load", "moe_load_imbalance", "attention_fwd_roofline", "fleet_step_mfu_routed",
+    "moe_expert_share": [CELL, XING], "moe_route_share": [CELL, XING],
+    "gated_conv_share": [CELL], "optimizer_share": [LSTM, CELL, XING],
+    "moe_held_load": [CELL, XING], "moe_load_imbalance": [CELL, XING],
+    "attention_fwd_roofline": [CELL], "fleet_step_mfu_routed": [CELL, XING],
 }
 
 
@@ -110,36 +116,31 @@ def test_the_cell_is_in_the_manifest_with_its_files():
     assert {"fleet_step_ms_per_machine", "fleet_step_mfu", "device_idle_share",
             "persist_ms_per_machine", "build_tail_ms_per_machine"} <= listed
     assert "fetch_wait_ms_per_machine" not in listed  # one chunk a build: nothing to read
+    assert set(READERS) <= listed
     assert {m["name"] for m in cell["end_to_end"]} == {
         "machines_per_min", "machine_ready_p95_s", "setup_s"
     }
-    # ``BENCHMARK.json`` cannot take the readers: a PR that is not a
-    # ``benchmark`` PR appends to ``per_layer``, and the accepted
-    # ``test_chipbench_fetch_wait_metric.py`` holds ``fetch_wait_ms_per_machine``
-    # to the list's end. So the tests' manifest declares them, for the cell and
-    # for its tiny twin: ``--manifest tests/chipbench/lfm2_manifest.json`` reads
-    # them on the chip, and a ``benchmark`` PR moves the entries over.
+    # the readers are BENCHMARK.json's entries, each on the cells it reads
     with open(os.path.join(ROOT, "BENCHMARK.json")) as fh:
         accepted = json.load(fh)
+    by_name = {m["name"]: m for m in accepted["per_layer"]}
+    for name, cells in READERS.items():
+        assert by_name[name]["moves"] == "machines_per_min", name
+        assert by_name[name]["workloads"] == cells, name
+        importlib.import_module(f"chipbench.metrics.{name}")
+    # the tests' manifest holds the tiny twin alone, with BENCHMARK.json's
+    # entries on the twin
     with open(MANIFEST) as fh:
         own = json.load(fh)
-    assert not READERS & {m["name"] for m in accepted["per_layer"]}
-    # the cell and its configuration are the accepted manifest's, key for key
-    for key in ("command", "paths", "run_seconds", "end_to_end"):
-        assert own[key] == accepted[key], key
-    assert own["configs"][0] == next(c for c in accepted["configs"] if c["name"] == "lfm2_8b_a1b")
-    assert own["workloads"][0] == next(w for w in accepted["workloads"] if w["name"] == CELL)
-    by_name = {m["name"]: m for m in accepted["per_layer"]}
-    for metric in own["per_layer"]:
-        assert metric["workloads"] == [CELL, "lfm2_tiny.rehearsal"]
-        if metric["name"] in READERS:
-            assert metric["moves"] == "machines_per_min"
-            importlib.import_module(f"chipbench.metrics.{metric['name']}")
-        else:
-            rest = {k: v for k, v in metric.items() if k != "workloads"}
-            assert rest == {k: v for k, v in by_name[metric["name"]].items() if k != "workloads"}
-    for name in (CELL, "lfm2_tiny.rehearsal"):
-        assert READERS <= {m["name"] for m in run.load_cell(MANIFEST, name)["per_layer"]}
+    assert [c["name"] for c in own["configs"]] == ["lfm2_tiny"]
+    assert [w["name"] for w in own["workloads"]] == [TWIN]
+    accepted_e2e = {m["name"]: m for m in accepted["end_to_end"]}
+    for metric in own["end_to_end"] + own["per_layer"]:
+        assert metric["workloads"] == [TWIN]
+        rest = {k: v for k, v in metric.items() if k != "workloads"}
+        same = by_name.get(metric["name"]) or accepted_e2e[metric["name"]]
+        assert rest == {k: v for k, v in same.items() if k != "workloads"}
+    assert set(READERS) <= {m["name"] for m in run.load_cell(MANIFEST, TWIN)["per_layer"]}
 
 
 def _ctx(scope_s, op_s, op_scope, counters=None, model=None):

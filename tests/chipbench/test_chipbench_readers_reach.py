@@ -53,6 +53,29 @@ def test_a_series_registered_anywhere_reaches_a_reader():
     assert all(isinstance(v, (int, float)) for v in ctx["after"].values())
 
 
+def test_every_reader_is_an_entry_and_every_entry_reads_an_accepted_cell():
+    """No reader under ``chipbench/metrics/`` is stranded: ``BENCHMARK.json``
+    names it, or a manifest of a tiny twin or of the rehearsals does. And
+    ``BENCHMARK.json`` lists a metric only on its own cells, none a twin's."""
+    def load(*parts):
+        with open(os.path.join(ROOT, *parts)) as fh:
+            return json.load(fh)
+
+    accepted = load("BENCHMARK.json")
+    manifests = [accepted, load("chipbench", "rehearsal", "manifest.json")] + [
+        load("tests", "chipbench", name) for name in ("lfm2_manifest.json", "xing_manifest.json")
+    ]
+    named = {m["name"] for own in manifests for m in own["end_to_end"] + own["per_layer"]}
+    files = {
+        name[: -len(".py")] for name in os.listdir(os.path.join(ROOT, "chipbench", "metrics"))
+        if name.endswith(".py") and name != "__init__.py"
+    }
+    assert files <= named, sorted(files - named)
+    cells = {w["name"] for w in accepted["workloads"]}
+    for metric in accepted["end_to_end"] + accepted["per_layer"]:
+        assert set(metric.get("workloads", cells)) <= cells, metric["name"]
+
+
 def test_example_readers_read_the_rehearsals_ctx(capsys, monkeypatch):
     seen = {}
     real = run.read_metrics

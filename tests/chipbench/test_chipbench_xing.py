@@ -133,42 +133,45 @@ SEVEN = {
     "fetch_ms_per_machine", "window_compiles", "fleet_step_ms_per_machine", "fleet_step_mfu",
     "persist_ms_per_machine", "device_idle_share", "build_tail_ms_per_machine",
 }
+# the set-up's compile stages and the pull's rate: read in every cell
+SETUP_AND_PULL = {"setup_trace_s", "setup_lower_s", "setup_backend_s", "pull_gbps"}
 
 
 def test_the_cell_is_in_the_manifest_with_its_files():
     cell = run.load_cell(os.path.join(ROOT, "BENCHMARK.json"), CELL)
     assert cell["chips"] == 1 and cell["traffic"].chunk_machines == 1
     assert cell["traffic"].rows == 1152 and cell["traffic"].check_machines == 1
-    assert {m["name"] for m in cell["per_layer"]} == SEVEN
+    assert {m["name"] for m in cell["per_layer"]} == (
+        SEVEN | NEW_READERS | SHARED_READERS | SETUP_AND_PULL
+    )
     assert {m["name"] for m in cell["end_to_end"]} == {
         "machines_per_min", "machine_ready_p95_s", "setup_s"
     }
     assert set(cell["file"]["limits"]) and cell["file"]["trace"]["detail_seconds"] > 0
-    # ``BENCHMARK.json``'s ``per_layer`` takes nothing at its end (PERF.md
-    # section 3): the readers are declared in the tests' manifest, for the
-    # cell and for its tiny twin, until a ``benchmark`` PR moves them over
+    # the readers are BENCHMARK.json's entries: this configuration's own on
+    # this cell alone, the routed layer's and the optimizer's beside other cells
     with open(os.path.join(ROOT, "BENCHMARK.json")) as fh:
         accepted = json.load(fh)
-    with open(MANIFEST) as fh:
-        own = json.load(fh)
-    assert not NEW_READERS & {m["name"] for m in accepted["per_layer"]}
-    for key in ("command", "paths", "run_seconds", "end_to_end"):
-        assert own[key] == accepted[key], key
-    assert own["configs"][0] == next(c for c in accepted["configs"] if c["name"] == "xing4_0_29b_a4b")
-    assert own["workloads"][0] == next(w for w in accepted["workloads"] if w["name"] == CELL)
     assert sum(w["chips"] == 4 for w in accepted["workloads"]) == 0
     by_name = {m["name"]: m for m in accepted["per_layer"]}
-    for metric in own["per_layer"]:
-        assert metric["workloads"] == [CELL, TINY]
-        if metric["name"] in NEW_READERS | SHARED_READERS:
-            assert metric["moves"] == "machines_per_min"
-            importlib.import_module(f"chipbench.metrics.{metric['name']}")
-        else:
-            rest = {k: v for k, v in metric.items() if k != "workloads"}
-            assert rest == {k: v for k, v in by_name[metric["name"]].items() if k != "workloads"}
-    for name in (CELL, TINY):
-        listed = {m["name"] for m in run.load_cell(MANIFEST, name)["per_layer"]}
-        assert listed == SEVEN | NEW_READERS | SHARED_READERS
+    for name in NEW_READERS | SHARED_READERS:
+        assert by_name[name]["moves"] == "machines_per_min", name
+        assert (by_name[name]["workloads"] == [CELL]) == (name in NEW_READERS), name
+        importlib.import_module(f"chipbench.metrics.{name}")
+    # the tests' manifest holds the tiny twin alone, with BENCHMARK.json's
+    # entries on the twin
+    with open(MANIFEST) as fh:
+        own = json.load(fh)
+    assert [c["name"] for c in own["configs"]] == ["xing_tiny"]
+    assert [w["name"] for w in own["workloads"]] == [TINY]
+    accepted_e2e = {m["name"]: m for m in accepted["end_to_end"]}
+    for metric in own["end_to_end"] + own["per_layer"]:
+        assert metric["workloads"] == [TINY]
+        rest = {k: v for k, v in metric.items() if k != "workloads"}
+        same = by_name.get(metric["name"]) or accepted_e2e[metric["name"]]
+        assert rest == {k: v for k, v in same.items() if k != "workloads"}
+    listed = {m["name"] for m in run.load_cell(MANIFEST, TINY)["per_layer"]}
+    assert listed == SEVEN | NEW_READERS | SHARED_READERS
 
 
 def _ctx(scope_s, op_s=None, counters=None, config=None):
