@@ -7,6 +7,7 @@ from .lstm_autoencoder import lstm_model, lstm_symmetric, lstm_hourglass
 from .transformer import transformer_model
 from .hybrid import hybrid_moe_model
 from .latent import latent_moe_model
+from .sambay import sambay_model
 from .tcn import tcn_model
 
 __all__ = [
@@ -19,5 +20,6 @@ __all__ = [
     "transformer_model",
     "hybrid_moe_model",
     "latent_moe_model",
+    "sambay_model",
     "tcn_model",
 ]

@@ -187,6 +187,84 @@ class LatentBlock:
 
 
 @dataclass(frozen=True)
+class SambaYBlock:
+    """
+    A layer of the SambaY decoder-hybrid-decoder (Phi-4-mini-flash,
+    arXiv:2507.06607; equations in ops/nn.py): pre-LayerNorm, ``x + mixer(LN(x))``
+    then ``x + SwiGLU(LN(x))`` of width ``ff_dim``, LayerNorm with a bias at
+    ``norm_eps``. ``mixer`` is one of
+
+    - ``mamba``: a selective state-space scan (Mamba-1) of ``d_inner``
+      channels and ``d_state`` states a channel, a depthwise causal
+      convolution of ``d_conv`` taps before it, Δ through a rank-``dt_rank``
+      projection;
+    - ``attention``: differential attention (arXiv:2410.05258) of
+      ``num_heads`` query heads on ``num_kv_heads`` key/value heads of
+      ``head_dim``, taken in pairs; causal, and a band of ``window`` rows
+      where ``window`` > 0; no positional encoding;
+    - ``cross_attention``: the same with queries of its own against the keys
+      and values an earlier ``attention`` block keeps;
+    - ``gmu``: a gated memory unit, ``(SiLU(h W_1) * m) W_2`` over the memory
+      ``m`` an earlier ``mamba`` block keeps.
+
+    ``keeps`` names what the block hands to later blocks: ``memory`` (a
+    ``mamba`` block's gated scan output, before its out-projection) or ``kv``
+    (an ``attention`` block's keys and values); ``""`` for nothing.
+    ``layer_index`` is the published depth, which sets differential
+    attention's ``lambda_init``.
+    """
+
+    d_model: int
+    mixer: str = "mamba"
+    ff_dim: int = 128
+    num_heads: int = 4
+    num_kv_heads: int = 2
+    head_dim: int = 8
+    window: int = 0
+    d_inner: int = 64
+    d_state: int = 4
+    d_conv: int = 4
+    dt_rank: int = 4
+    keeps: str = ""
+    layer_index: int = 0
+    norm_eps: float = 1e-5
+
+    @property
+    def reads(self) -> str:
+        """What the block reads of an earlier block's: ``memory`` (a gated
+        memory unit), ``kv`` (cross-attention) or nothing (``""``)."""
+        return {"gmu": "memory", "cross_attention": "kv"}.get(self.mixer, "")
+
+
+def check_shared_state(layers) -> None:
+    """Refuse layers whose gated memory units or cross-attention blocks have
+    no earlier block that keeps what they read (a ``mamba`` block that keeps
+    ``memory``, an ``attention`` block that keeps ``kv``), or whose blocks keep
+    what their mixer does not make."""
+    kept = set()
+    for layer in layers:
+        if not isinstance(layer, SambaYBlock):
+            continue
+        if layer.reads and layer.reads not in kept:
+            raise ValueError(
+                f"SambaYBlock {layer.mixer} reads the {layer.reads} of an "
+                f"earlier block, and none keeps it"
+            )
+        makes = {"mamba": "memory", "attention": "kv"}.get(layer.mixer)
+        if layer.keeps and layer.keeps != makes:
+            raise ValueError(f"a {layer.mixer} block cannot keep {layer.keeps!r}")
+        if layer.keeps:
+            kept.add(layer.keeps)
+
+
+@dataclass(frozen=True)
+class LayerNormLayer:
+    """``(x - mean) * rsqrt(var + eps) * scale + bias`` over the feature axis."""
+
+    eps: float = 1e-5
+
+
+@dataclass(frozen=True)
 class StreamLayer:
     """``expand``: (batch, time, d) copied into ``streams`` residual streams,
     (streams, batch, time, d); ``collapse``: the streams summed back
@@ -232,8 +310,10 @@ LayerSpec = Union[
     MoEBlock,
     HybridBlock,
     LatentBlock,
+    SambaYBlock,
     StreamLayer,
     RMSNormLayer,
+    LayerNormLayer,
     TCNBlock,
     PoolLayer,
 ]

@@ -109,6 +109,22 @@ LAST_POSITION_BLOCK_STEPS = telemetry.counter(
     "before a last-position pool: keys and values from every position, the "
     "rest at the last) times live training steps",
 )
+SSM_LAYER_STEPS = telemetry.counter(
+    "gordo_build_ssm_layer_steps_total",
+    "Selective-scan (Mamba) layers times live training steps",
+)
+SSM_POSITIONS = telemetry.counter(
+    "gordo_build_ssm_positions_total",
+    "Positions the selective scans ran over in live training steps (batch "
+    "rows x time steps, summed over Mamba layers)",
+)
+SHARED_STATE_READS = telemetry.counter(
+    "gordo_build_shared_state_reads_total",
+    "Blocks that read another block's state in live training steps, by what "
+    "they read: kv (cross-attention over an earlier layer's keys and values) "
+    "or memory (a gated memory unit over an earlier Mamba layer's output)",
+    ("kind",),
+)
 ARTIFACT_BYTES = telemetry.counter(
     "gordo_build_artifact_bytes_total",
     "Bytes of model.pkl as the fleet build wrote them (over the serialize "

@@ -48,12 +48,14 @@ def merge_heads(x: jnp.ndarray) -> jnp.ndarray:
 
 def dot_product_attention_xla(
     q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, causal: bool = False,
-    scale: float = None,
+    scale: float = None, window: int = None,
 ) -> jnp.ndarray:
     """
     Reference attention. q, k: (..., T, Dh) and v: (..., T, Dv) with any
     leading batch dims; the values' width need not be the queries'. ``scale``
-    multiplies the scores: ``Dh ** -0.5`` unless given.
+    multiplies the scores: ``Dh ** -0.5`` unless given. The queries are the
+    last ``Tq`` of the ``Tk`` positions. With ``window`` (causal only), key
+    ``s`` is seen from query position ``t`` iff ``t - window < s <= t``.
 
     Softmax is computed in float32 regardless of input dtype (bfloat16-safe),
     matching the flash kernel's accumulator precision.
@@ -65,6 +67,8 @@ def dot_product_attention_xla(
     if causal:
         t_q, t_k = logits.shape[-2], logits.shape[-1]
         mask = jnp.tril(jnp.ones((t_q, t_k), bool), t_k - t_q)
+        if window is not None and window < t_k:
+            mask = mask & ~jnp.tril(jnp.ones((t_q, t_k), bool), t_k - t_q - window)
         logits = jnp.where(mask, logits, NEG_INF)
     weights = jax.nn.softmax(logits, axis=-1).astype(v.dtype)
     return jnp.einsum("...qk,...kd->...qd", weights, v)

@@ -23,12 +23,14 @@ from gordo_tpu.models.spec import (
     DenseLayer,
     HybridBlock,
     LatentBlock,
+    LayerNormLayer,
     LSTMLayer,
     ModelSpec,
     MoEBlock,
     PoolLayer,
     PositionalEncoding,
     RMSNormLayer,
+    SambaYBlock,
     StreamLayer,
     TCNBlock,
     TransformerBlock,
@@ -111,13 +113,32 @@ def forward_flops_per_sample(spec: ModelSpec) -> float:
                 load = layer.top_k * layer.experts_held / layer.num_experts
                 total += 2.0 * d * layer.num_experts * T
                 total += (load + layer.shared_experts) * 6.0 * d * layer.ff_dim * T
+        elif isinstance(layer, SambaYBlock):
+            d, dh, di = layer.d_model, layer.head_dim, layer.d_inner
+            hq, hkv = layer.num_heads * dh, layer.num_kv_heads * dh
+            total += 6.0 * d * layer.ff_dim * T
+            if layer.mixer == "mamba":
+                # W_in, W_x, W_dt, W_out (the scan and the taps are elementwise)
+                rank = layer.dt_rank
+                total += 2.0 * T * (2 * d * di + di * (rank + 2 * layer.d_state) + rank * di + di * d)
+            elif layer.mixer == "gmu":
+                total += 4.0 * d * di * T
+            else:
+                total += 2.0 * d * (2 * hq + (2 * hkv if layer.mixer == "attention" else 0)) * T
+                # two maps' scores (Dh) and weighted values (2 Dh) a query
+                # pair and key: half of T x T causal, less where a window cuts
+                w = layer.window
+                seen = 0.5 * T * T if not w or w >= T else T * w - 0.5 * w * w
+                total += 6.0 * hq * seen
         elif isinstance(layer, TCNBlock):
             # two causal dilated convs (+ a possible 1x1 residual projection)
             k, f = layer.kernel_size, layer.filters
             total += 2.0 * k * in_dim * f * T + 2.0 * k * f * f * T
             if in_dim != f:
                 total += 2.0 * in_dim * f * T
-        elif isinstance(layer, (PoolLayer, PositionalEncoding, RMSNormLayer, StreamLayer)):
+        elif isinstance(
+            layer, (PoolLayer, PositionalEncoding, RMSNormLayer, LayerNormLayer, StreamLayer)
+        ):
             if isinstance(layer, PoolLayer):
                 seq = False
         in_dim = layer_out_dim(layer, in_dim)
@@ -218,8 +239,22 @@ def spec_param_count(spec: ModelSpec) -> int:
                 total += (d + 1) * layer.num_experts
                 total += (layer.experts_held + layer.shared_experts) * 3 * d * f
             total += 2 * d + 2 * (n * d * (2 * n + n * n) + 3 + 2 * n + n * n)
+        elif isinstance(layer, SambaYBlock):
+            d, dh, di = layer.d_model, layer.head_dim, layer.d_inner
+            total += 4 * d + 3 * d * layer.ff_dim
+            if layer.mixer == "mamba":
+                rank, ds = layer.dt_rank, layer.d_state
+                total += d * 2 * di + di * (layer.d_conv + 1)
+                total += di * (rank + 2 * ds) + (rank + 1) * di + di * ds + di + di * d
+            elif layer.mixer == "gmu":
+                total += 2 * d * di
+            else:
+                kv = 2 * d * layer.num_kv_heads * dh if layer.mixer == "attention" else 0
+                total += 2 * d * layer.num_heads * dh + kv + 4 * dh + 2 * dh
         elif isinstance(layer, RMSNormLayer):
             total += in_dim
+        elif isinstance(layer, LayerNormLayer):
+            total += 2 * in_dim
         elif isinstance(layer, TCNBlock):
             k, f = layer.kernel_size, layer.filters
             total += k * in_dim * f + k * f * f

@@ -23,17 +23,24 @@ from gordo_tpu.models.spec import (
     DenseLayer,
     HybridBlock,
     LatentBlock,
+    LayerNormLayer,
     LSTMLayer,
     ModelSpec,
     MoEBlock,
     PoolLayer,
     PositionalEncoding,
     RMSNormLayer,
+    SambaYBlock,
     StreamLayer,
     TCNBlock,
     TransformerBlock,
+    check_shared_state,
 )
-from gordo_tpu.ops.attention import dot_product_attention, multihead_attention
+from gordo_tpu.ops.attention import (
+    dot_product_attention,
+    dot_product_attention_xla,
+    multihead_attention,
+)
 
 Params = List[Dict[str, Any]]
 
@@ -156,6 +163,10 @@ def _normal(rng, shape, std=0.02):
     return std * jax.random.normal(rng, shape, jnp.float32)
 
 
+# differential attention's four λ vectors, in the order their keys are drawn
+_DIFF_LAMBDAS = ("lambda_q1", "lambda_k1", "lambda_q2", "lambda_k2")
+
+
 def _init_ffn(k_ffn, layer, p) -> None:
     """The FFN's leaves of a hybrid or latent block, into ``p``: SwiGLU of
     ``ff_dim`` (``dense``), or the router, the zero selection bias and the
@@ -269,6 +280,66 @@ def init_latent_block(rng, in_dim: int, layer: LatentBlock):
     return p
 
 
+def init_sambay_block(rng, in_dim: int, layer: SambaYBlock):
+    """normal(0, 0.02) matrices, unit gains and zero biases of the two
+    LayerNorms, a zero convolution bias; Mamba's own start for the scan
+    (``A_log = log(1..d_state)`` a channel, ``D = 1``, Δ's bias the inverse
+    softplus of a log-uniform draw in [1e-3, 1e-1], floored at 1e-4) and
+    differential attention's (its four λ vectors normal(0, 0.1), a unit gain
+    on the head norm). One key for the mixer and one for the MLP, each split
+    over its matrices."""
+    if in_dim != layer.d_model:
+        raise ValueError(
+            f"SambaYBlock d_model={layer.d_model} but incoming dim is "
+            f"{in_dim}; insert a Dense projection first"
+        )
+    d, di, ds, dh = layer.d_model, layer.d_inner, layer.d_state, layer.head_dim
+    k_mix, k_ffn = jax.random.split(rng)
+    p = {
+        "ln1_scale": jnp.ones((d,), jnp.float32),
+        "ln1_bias": jnp.zeros((d,), jnp.float32),
+        "ln2_scale": jnp.ones((d,), jnp.float32),
+        "ln2_bias": jnp.zeros((d,), jnp.float32),
+    }
+    if layer.mixer == "mamba":
+        ks = jax.random.split(k_mix, 6)
+        lo, hi = math.log(1e-3), math.log(1e-1)
+        dt = jnp.exp(jax.random.uniform(ks[4], (di,), jnp.float32) * (hi - lo) + lo)
+        dt = jnp.maximum(dt, 1e-4)
+        p["w_in"] = _normal(ks[0], (d, 2 * di))
+        p["conv_taps"] = _normal(ks[1], (di, layer.d_conv))
+        p["conv_bias"] = jnp.zeros((di,), jnp.float32)
+        p["w_x"] = _normal(ks[2], (di, layer.dt_rank + 2 * ds))
+        p["w_dt"] = _normal(ks[3], (layer.dt_rank, di))
+        p["dt_bias"] = dt + jnp.log(-jnp.expm1(-dt))
+        p["a_log"] = jnp.log(
+            jnp.broadcast_to(jnp.arange(1, ds + 1, dtype=jnp.float32), (di, ds))
+        )
+        p["d_skip"] = jnp.ones((di,), jnp.float32)
+        p["w_out"] = _normal(ks[5], (di, d))
+    elif layer.mixer in ("attention", "cross_attention"):
+        ks = jax.random.split(k_mix, 8)
+        p["wq"] = _normal(ks[0], (d, layer.num_heads * dh))
+        if layer.mixer == "attention":
+            p["wk"] = _normal(ks[1], (d, layer.num_kv_heads * dh))
+            p["wv"] = _normal(ks[2], (d, layer.num_kv_heads * dh))
+        p["wo"] = _normal(ks[3], (layer.num_heads * dh, d))
+        for name, key in zip(_DIFF_LAMBDAS, ks[4:]):
+            p[name] = _normal(key, (dh,), std=0.1)
+        p["subln"] = jnp.ones((2 * dh,), jnp.float32)
+    elif layer.mixer == "gmu":
+        ks = jax.random.split(k_mix, 2)
+        p["w_gate"] = _normal(ks[0], (d, di))
+        p["w_gmu_out"] = _normal(ks[1], (di, d))
+    else:
+        raise ValueError(f"Unknown SambaYBlock mixer {layer.mixer!r}")
+    ks = jax.random.split(k_ffn, 3)
+    p["w1"] = _normal(ks[0], (d, layer.ff_dim))
+    p["w3"] = _normal(ks[1], (d, layer.ff_dim))
+    p["w2"] = _normal(ks[2], (layer.ff_dim, d))
+    return p
+
+
 def init_tcn_block(rng, in_dim: int, layer: TCNBlock):
     k1, k2, k3 = jax.random.split(rng, 3)
     filters, ksize = layer.filters, layer.kernel_size
@@ -288,11 +359,15 @@ def layer_out_dim(layer, in_dim: int) -> int:
     """Feature dimension a layer produces given its input dimension."""
     if isinstance(layer, (DenseLayer, LSTMLayer)):
         return layer.units
-    if isinstance(layer, (TransformerBlock, MoEBlock, HybridBlock, LatentBlock)):
+    if isinstance(
+        layer, (TransformerBlock, MoEBlock, HybridBlock, LatentBlock, SambaYBlock)
+    ):
         return layer.d_model
     if isinstance(layer, TCNBlock):
         return layer.filters
-    if isinstance(layer, (PositionalEncoding, PoolLayer, RMSNormLayer, StreamLayer)):
+    if isinstance(
+        layer, (PositionalEncoding, PoolLayer, RMSNormLayer, LayerNormLayer, StreamLayer)
+    ):
         return in_dim
     raise TypeError(f"Unknown layer spec: {layer!r}")
 
@@ -315,8 +390,17 @@ def init_model_params(rng: jax.Array, spec: ModelSpec) -> Params:
             params.append(init_hybrid_block(layer_rng, in_dim, layer))
         elif isinstance(layer, LatentBlock):
             params.append(init_latent_block(layer_rng, in_dim, layer))
+        elif isinstance(layer, SambaYBlock):
+            params.append(init_sambay_block(layer_rng, in_dim, layer))
         elif isinstance(layer, RMSNormLayer):
             params.append({"scale": jnp.ones((in_dim,), jnp.float32)})
+        elif isinstance(layer, LayerNormLayer):
+            params.append(
+                {
+                    "scale": jnp.ones((in_dim,), jnp.float32),
+                    "bias": jnp.zeros((in_dim,), jnp.float32),
+                }
+            )
         elif isinstance(layer, TCNBlock):
             params.append(init_tcn_block(layer_rng, in_dim, layer))
         elif isinstance(layer, (PositionalEncoding, PoolLayer, StreamLayer)):
@@ -679,17 +763,22 @@ def _rope(x, theta: float, inv_freq=None, first: int = 0):
     return (x32 * cos + rotated * sin).astype(x.dtype)
 
 
+def _causal_depthwise_conv(z, taps):
+    """A depthwise causal convolution over time of (B, T, C) by (C, K) taps:
+    ``conv(z)[t] = sum_j taps[:, j] * z[t - (K-1) + j]``, zeros before the
+    start."""
+    kw, t = taps.shape[-1], z.shape[1]
+    zp = jnp.pad(z, ((0, 0), (kw - 1, 0), (0, 0)))
+    return sum(zp[:, j : j + t, :] * taps[:, j] for j in range(kw))
+
+
 @jax.named_scope("gated_conv")
 def _gated_conv(layer: HybridBlock, p, h):
     """``[b, c, u] = split(h @ W_in)``; ``y = c * conv(b * u)`` with a
-    depthwise causal convolution over time, ``conv(z)[t] = sum_j taps[:, j] *
-    z[t - (K-1) + j]`` and zeros before the start; out ``y @ W_out``."""
+    depthwise causal convolution over time (:func:`_causal_depthwise_conv`
+    of ``conv_kernel`` taps); out ``y @ W_out``."""
     b, c, u = jnp.split(h @ p["conv_in"], 3, axis=-1)
-    z = b * u
-    kw, t = layer.conv_kernel, z.shape[1]
-    zp = jnp.pad(z, ((0, 0), (kw - 1, 0), (0, 0)))
-    conv = sum(zp[:, j : j + t, :] * p["conv_taps"][:, j] for j in range(kw))
-    return (c * conv) @ p["conv_out"]
+    return (c * _causal_depthwise_conv(b * u, p["conv_taps"])) @ p["conv_out"]
 
 
 def _attention_impl(layer, last_only: bool):
@@ -976,8 +1065,14 @@ _permute_rows.defvjp(
 
 def _stays_float32(leaf: str) -> bool:
     """A block's leaves that are not cast to a bfloat16 compute dtype: a
-    router's (routing is a decision) and the streams' mixing coefficients'."""
-    return leaf in ("router", "expert_bias") or leaf.startswith("hc_")
+    router's (routing is a decision), the streams' mixing coefficients', and
+    the scan's and differential attention's scalars a channel (``A_log``,
+    ``D``, Δ's bias, the λ vectors)."""
+    return (
+        leaf in ("router", "expert_bias", "a_log", "d_skip", "dt_bias")
+        or leaf.startswith("hc_")
+        or leaf in _DIFF_LAMBDAS
+    )
 
 
 MOE_STATS = ("moe_held", "moe_absent", "moe_tokens", "moe_layer_steps", "moe_peak_load")
@@ -987,10 +1082,19 @@ def zero_stats(spec: ModelSpec) -> Dict[str, jnp.ndarray]:
     """What :func:`apply_model_stats` counts for ``spec``, at zero: the sums'
     start: int32 counts of the routed layers, and of a latent block's mixing
     its sublayer-steps (int32), their summed stochastic gap (float32) and the
-    writes that took the Pallas kernel pair (int32). Empty for a spec with
-    neither."""
+    writes that took the Pallas kernel pair (int32); a SambaY spec's Mamba
+    layer-steps and the positions they scanned, and its reads of another
+    block's keys and values or memory (int32). Empty for a spec with none of
+    them."""
     stats = {}
     for layer in spec.layers:
+        if isinstance(layer, SambaYBlock):
+            keys = {
+                "mamba": ("ssm_layer_steps", "ssm_positions"),
+                "cross_attention": ("shared_kv_reads",),
+                "gmu": ("shared_memory_reads",),
+            }.get(layer.mixer, ())
+            stats.update({key: jnp.zeros((), jnp.int32) for key in keys})
         if isinstance(layer, (HybridBlock, LatentBlock)) and layer.ffn == "routed":
             stats.update({key: jnp.zeros((), jnp.int32) for key in MOE_STATS})
         if isinstance(layer, LatentBlock):
@@ -1123,6 +1227,194 @@ def _apply_latent_block(layer: LatentBlock, p, x, last_only: bool = False):
     return x, stats
 
 
+# positions a chunk of the selective scan (PERF.md section 6)
+SCAN_CHUNK = 2
+
+
+@jax.named_scope("ssm_scan")
+def selective_scan(u, delta, a, b, c, d_skip, chunk: int = None):
+    """The selective state-space scan of Mamba-1 over (B, T, C) channels of
+    ``d_state`` states each: ``h_t = exp(delta_t a) * h_{t-1} + (delta_t u_t)
+    b_t`` and ``y_t = h_t c_t + d_skip u_t``, from ``h_0 = 0``. ``u``,
+    ``delta``: (B, T, C); ``a``: (C, S); ``b``, ``c``: (B, T, S); ``d_skip``:
+    (C,). Returns ``y`` in ``u``'s dtype.
+
+    Chunked: a ``lax.scan`` over chunks of ``chunk`` positions
+    (:data:`SCAN_CHUNK` unless given) carries the state in float32, and inside
+    a chunk an associative scan runs over the pairs ``(exp(delta a), delta u
+    b)``. The chunk's body is recomputed in the backward pass
+    (``jax.checkpoint``), so what is kept is a state a chunk, never one a
+    position. The state is held (B, S, C), the channels minor: on a TPU the
+    minor axis fills the 128 lanes, which ``d_state`` would fill an eighth
+    of. T need not be a multiple of ``chunk``: the tail is padded with
+    ``delta = 0`` and ``u = 0``, a step that leaves the state as it is."""
+    bsz, t, channels = u.shape
+    q = min(chunk or SCAN_CHUNK, t)
+    n = -(-t // q)
+    f32 = jnp.float32
+    a_t = a.astype(f32).T  # (S, C)
+
+    def chunks(x):
+        # (B, T, ...) -> (n, B, q, ...), the tail padded with zeros
+        x = jnp.pad(x.astype(f32), ((0, 0), (0, n * q - t)) + ((0, 0),) * (x.ndim - 2))
+        return jnp.moveaxis(x.reshape((bsz, n, q) + x.shape[2:]), 1, 0)
+
+    def combine(first, then):
+        return first[0] * then[0], then[0] * first[1] + then[1]
+
+    @jax.checkpoint
+    def one_chunk(h, xs):
+        dt, dtu, bq, cq = xs  # (B, q, C), (B, q, C), (B, q, S), (B, q, S)
+        decay = jnp.exp(dt[:, :, None, :] * a_t)
+        drive = dtu[:, :, None, :] * bq[..., None]
+        decay, drive = jax.lax.associative_scan(combine, (decay, drive), axis=1)
+        states = decay * h[:, None] + drive
+        # an elementwise product and a sum over the states: float32 throughout
+        return states[:, -1], jnp.sum(states * cq[..., None], axis=2)
+
+    delta = delta.astype(f32)
+    xs = (chunks(delta), chunks(delta * u.astype(f32)), chunks(b), chunks(c))
+    _, ys = jax.lax.scan(one_chunk, jnp.zeros((bsz, a.shape[-1], channels), f32), xs)
+    y = jnp.moveaxis(ys, 0, 1).reshape(bsz, n * q, channels)[:, :t]
+    return (y + u.astype(f32) * d_skip.astype(f32)).astype(u.dtype)
+
+
+def _mamba_mixer(layer: SambaYBlock, p, h):
+    """Mamba-1 on (B, T, d): ``[u, z] = h W_in``; ``u = SiLU(conv(u) +
+    b_conv)`` (:func:`_causal_depthwise_conv` of ``d_conv`` taps); ``[δ, B, C]
+    = u W_x``; ``Δ = softplus(δ W_dt + b_dt)`` in float32; ``A = -exp(A_log)``;
+    ``y`` the :func:`selective_scan`; ``m = y * SiLU(z)``. Returns ``(m W_out,
+    m)``: the mixer's output and the gated scan output, the memory a later
+    gated memory unit reads."""
+    r, ds = layer.dt_rank, layer.d_state
+    with jax.named_scope("ssm_in"):
+        u, z = jnp.split(h @ p["w_in"], 2, axis=-1)
+        u = jax.nn.silu(_causal_depthwise_conv(u, p["conv_taps"]) + p["conv_bias"])
+        dbc = u @ p["w_x"]
+        delta = (dbc[..., :r] @ p["w_dt"]).astype(jnp.float32) + p["dt_bias"]
+        delta = jax.nn.softplus(delta)
+        a = -jnp.exp(p["a_log"])
+    y = selective_scan(u, delta, a, dbc[..., r : r + ds], dbc[..., r + ds :], p["d_skip"])
+    with jax.named_scope("ssm_out"):
+        m = y * jax.nn.silu(z)
+        return m @ p["w_out"], m
+
+
+def diff_lambda(layer: SambaYBlock, p):
+    """``(λ, λ_init)`` of a differential attention layer, float32:
+    ``λ = exp(λ_q1 · λ_k1) - exp(λ_q2 · λ_k2) + λ_init`` and ``λ_init = 0.8 -
+    0.6 exp(-0.3 layer_index)`` at the published depth."""
+    init = 0.8 - 0.6 * math.exp(-0.3 * layer.layer_index)
+    q1, k1, q2, k2 = (p[name].astype(jnp.float32) for name in _DIFF_LAMBDAS)
+    return jnp.exp(jnp.sum(q1 * k1)) - jnp.exp(jnp.sum(q2 * k2)) + init, init
+
+
+def _diff_attention(layer: SambaYBlock, p, h, kv=None, last_only: bool = False):
+    """Differential attention (arXiv:2410.05258) on (B, T, d), no positional
+    encoding: the ``num_heads`` query heads of ``head_dim`` taken in pairs
+    ``(q1, q2)``, the ``num_kv_heads`` key heads in pairs ``(k1, k2)``, each key
+    pair serving ``num_heads / num_kv_heads`` consecutive query pairs, the
+    values as ``num_kv_heads / 2`` heads of ``2 head_dim``. ``A_i =
+    softmax(q_i k_i^T / sqrt(head_dim) + mask)``, ``o = A_1 v - λ A_2 v``
+    (:func:`diff_lambda`), ``o <- RMSNorm(o) (1 - λ_init)`` a pair, then
+    ``concat(o) W_o``. The mask is causal, and a band of ``window`` rows where
+    ``window`` > 0. ``kv`` is an earlier block's ``(k, v)``: then the block
+    projects queries alone. With ``last_only`` the queries are the last
+    position's, against every position's keys and values: (B, 1, d) out.
+    Returns ``(out, (k, v))``.
+
+    The maps take the XLA path: the values are twice the queries' width, and
+    the flash kernel a slice of the values at a time, four calls a layer,
+    took 11.95 ms forward + backward for 20 query pairs and 16 windows of 256
+    rows in bfloat16 against the XLA path's 6.86 ms (chip run, PERF.md
+    section 6)."""
+    bsz, t, _ = h.shape
+    dh, pairs = layer.head_dim, layer.num_heads // 2
+    h_q = h[:, -1:] if last_only and kv is None else h
+    tq = h_q.shape[1]
+    q = (h_q @ p["wq"]).reshape(bsz, tq, pairs, 2, dh)
+    if kv is None:
+        kv_pairs = layer.num_kv_heads // 2
+        k = (h @ p["wk"]).reshape(bsz, t, kv_pairs, 2, dh)
+        v = (h @ p["wv"]).reshape(bsz, t, kv_pairs, 2 * dh)
+    else:
+        k, v = kv
+    with jax.named_scope("diff_attention"):
+        rep = pairs // k.shape[2]
+
+        def heads(a):
+            # (B, T, pairs, width) -> (B, pairs, T, width)
+            return jnp.repeat(a, rep, axis=2).transpose(0, 2, 1, 3)
+
+        vh = heads(v)
+        window = layer.window if layer.window > 0 else None
+        maps = [
+            dot_product_attention_xla(
+                q[..., i, :].transpose(0, 2, 1, 3), heads(k[..., i, :]), vh,
+                causal=True, window=window,
+            ).astype(jnp.float32)
+            for i in (0, 1)
+        ]
+        lam, init = diff_lambda(layer, p)
+        o = _rms(maps[0] - lam * maps[1], p["subln"], 1e-5) * (1.0 - init)
+        o = o.transpose(0, 2, 1, 3).reshape(bsz, tq, pairs * 2 * dh).astype(h.dtype)
+    return o @ p["wo"], (k, v)
+
+
+@jax.named_scope("gmu")
+def _gmu(p, h, memory):
+    """The gated memory unit: ``(SiLU(h W_1) * m) W_2`` on (B, T, d), ``m``
+    an earlier Mamba layer's gated scan output at the same positions."""
+    return (jax.nn.silu(h @ p["w_gate"]) * memory) @ p["w_gmu_out"]
+
+
+def _ln(x, scale, bias, eps: float):
+    """LayerNorm over the last axis computed in float32 whatever the compute
+    dtype."""
+    out = _layer_norm(
+        x.astype(jnp.float32), scale.astype(jnp.float32), bias.astype(jnp.float32), eps
+    )
+    return out.astype(x.dtype)
+
+
+def _apply_sambay_block(layer: SambaYBlock, p, x, shared, last_only: bool = False):
+    """x: (batch, time, d_model) → ``(x, kept, stats)``: ``x + mixer(LN(x))``,
+    then ``x + SwiGLU(LN(x))``. ``shared`` holds what earlier blocks kept
+    (``memory``, ``kv``); ``kept`` is what this one keeps for later blocks;
+    ``stats`` counts a Mamba layer's step and positions, and a read of
+    another block's state. With ``last_only`` → (batch, 1, d_model), the last
+    position's state alone (:func:`_last_only_block`): a Mamba layer scans
+    every position and keeps the last, differential attention takes every
+    position's keys and values and the last position's queries, and a gated
+    memory unit, cross-attention and the MLP see the last position alone."""
+    eps = layer.norm_eps
+    position_wise = last_only and bool(layer.reads)
+    src = x[:, -1:] if position_wise else x
+    h = _ln(src, p["ln1_scale"], p["ln1_bias"], eps)
+    kept, stats = {}, {}
+    if layer.mixer == "mamba":
+        y, memory = _mamba_mixer(layer, p, h)
+        stats["ssm_layer_steps"] = jnp.asarray(1, jnp.int32)
+        stats["ssm_positions"] = jnp.asarray(h.shape[0] * h.shape[1], jnp.int32)
+        if layer.keeps == "memory":
+            kept["memory"] = memory
+    elif layer.mixer == "attention":
+        y, kv = _diff_attention(layer, p, h, last_only=last_only)
+        if layer.keeps == "kv":
+            kept["kv"] = kv
+    elif layer.mixer == "cross_attention":
+        y, _ = _diff_attention(layer, p, h, kv=shared["kv"], last_only=last_only)
+        stats["shared_kv_reads"] = jnp.asarray(1, jnp.int32)
+    else:
+        memory = shared["memory"]
+        y = _gmu(p, h, memory[:, -1:] if position_wise else memory)
+        stats["shared_memory_reads"] = jnp.asarray(1, jnp.int32)
+    if last_only and not position_wise:
+        x, y = x[:, -1:], y[:, -1:]
+    x = src + y if position_wise else x + y
+    return x + _swiglu(p, _ln(x, p["ln2_scale"], p["ln2_bias"], eps)), kept, stats
+
+
 def _apply_stream_layer(layer: StreamLayer, x):
     if layer.mode == "expand":
         return jnp.broadcast_to(x[None], (layer.streams,) + x.shape)
@@ -1190,25 +1482,27 @@ def _apply_pool(layer: PoolLayer, x):
 
 def _last_only_block(spec: ModelSpec):
     """The index of the layer that runs ``last_only``, or None: the spec's
-    last hybrid or latent block, where every layer after it up to a
+    last hybrid, latent or SambaY block, where every layer after it up to a
     ``PoolLayer(mode="last")`` is position-wise (``RMSNormLayer``,
-    ``StreamLayer(mode="collapse")``). Nothing reads that block's other
-    positions: no later block asks for their keys and values, and the pool
-    keeps one. A ``DenseLayer`` in between does not qualify (its
-    ``l1_activity`` sums over positions), nor a mean or max pool.
-    ``TransformerBlock``, ``MoEBlock``, ``TCNBlock`` and ``LSTMLayer`` specs
-    keep every position: no benchmark cell runs them, and the
-    pipeline-parallel path consumes ``TransformerBlock``s whole."""
+    ``LayerNormLayer``, ``StreamLayer(mode="collapse")``). Nothing reads that
+    block's other positions: no later block asks for their keys and values
+    or their memory, and the pool keeps one. (A SambaY cross-attention
+    block's keys and values are an earlier block's, which runs whole.) A
+    ``DenseLayer`` in between does not qualify (its ``l1_activity`` sums over
+    positions), nor a mean or max pool. ``TransformerBlock``, ``MoEBlock``,
+    ``TCNBlock`` and ``LSTMLayer`` specs keep every position: no benchmark
+    cell runs them, and the pipeline-parallel path consumes
+    ``TransformerBlock``s whole."""
     blocks = [
         i for i, layer in enumerate(spec.layers)
-        if isinstance(layer, (HybridBlock, LatentBlock))
+        if isinstance(layer, (HybridBlock, LatentBlock, SambaYBlock))
     ]
     if not blocks:
         return None
     for layer in spec.layers[blocks[-1] + 1 :]:
         if isinstance(layer, PoolLayer):
             return blocks[-1] if layer.mode == "last" else None
-        position_wise = isinstance(layer, RMSNormLayer) or (
+        position_wise = isinstance(layer, (RMSNormLayer, LayerNormLayer)) or (
             isinstance(layer, StreamLayer) and layer.mode == "collapse"
         )
         if not position_wise:
@@ -1230,9 +1524,10 @@ def apply_model(spec: ModelSpec, params: Params, x: jnp.ndarray):
 
 
 def apply_model_stats(spec: ModelSpec, params: Params, x: jnp.ndarray):
-    """:func:`apply_model` and, third, what the layers counted on the way: a
-    dict of int32 scalars summed over the spec's routed layers
-    (:func:`routed_ffn`), empty for a spec that has none."""
+    """:func:`apply_model` and, third, what the layers counted on the way
+    (:func:`zero_stats` names them), empty for a spec that counts nothing.
+    A SambaY block's keys and values or memory go from the block that keeps
+    them to the later blocks that read them (:func:`_apply_sambay_block`)."""
     compute_dtype = jnp.dtype(getattr(spec, "compute_dtype", "float32"))
     batch = x.shape[0]
     out = x
@@ -1255,7 +1550,9 @@ def apply_model_stats(spec: ModelSpec, params: Params, x: jnp.ndarray):
         params = [
             {
                 k: (v if _stays_float32(k)
-                    and isinstance(layer, (MoEBlock, HybridBlock, LatentBlock))
+                    and isinstance(
+                        layer, (MoEBlock, HybridBlock, LatentBlock, SambaYBlock)
+                    )
                     else jax.tree_util.tree_map(_cast, v))
                 for k, v in p.items()
             }
@@ -1296,6 +1593,7 @@ def apply_model_stats(spec: ModelSpec, params: Params, x: jnp.ndarray):
     trimmed = _last_only_block(spec)
     penalty = jnp.asarray(0.0, jnp.float32)
     stats: Dict[str, jnp.ndarray] = {}
+    shared: Dict[str, Any] = {}
     for i, (layer, p) in enumerate(zip(spec.layers, params)):
         if pp_blocks and i in pp_blocks:
             if i != pp_blocks[0]:
@@ -1356,10 +1654,27 @@ def apply_model_stats(spec: ModelSpec, params: Params, x: jnp.ndarray):
             stats.update(
                 {key: stats.get(key, 0) + value for key, value in counted.items()}
             )
+        elif isinstance(layer, SambaYBlock):
+            if layer.reads and layer.reads not in shared:
+                check_shared_state(spec.layers)
+            block = functools.partial(
+                _apply_sambay_block, layer, last_only=i == trimmed
+            )
+            if remat:
+                block = jax.checkpoint(block)
+            out, kept, counted = block(p, out, shared)
+            shared.update(kept)
+            if i == trimmed:
+                counted = dict(counted, last_position_steps=jnp.asarray(1, jnp.int32))
+            stats.update(
+                {key: stats.get(key, 0) + value for key, value in counted.items()}
+            )
         elif isinstance(layer, StreamLayer):
             out = _apply_stream_layer(layer, out)
         elif isinstance(layer, RMSNormLayer):
             out = _rms_norm(out, p["scale"], layer.eps)
+        elif isinstance(layer, LayerNormLayer):
+            out = _ln(out, p["scale"], p["bias"], layer.eps)
         elif isinstance(layer, TCNBlock):
             out = _seq_layer(_apply_tcn_block, layer, p, out)
         elif isinstance(layer, PoolLayer):

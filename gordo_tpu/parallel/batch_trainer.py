@@ -406,9 +406,10 @@ def _predict_windows(spec: ModelSpec, params, X):
 
 def _note_layer_counts(counted: Dict[str, np.ndarray], rows, n_live: int) -> None:
     """Add a chunk's layer counts (a value a lane; padding lanes left out) to
-    the build's counters: the routed layers', a latent block's mixing, and
-    the blocks run at the last position alone. Nothing to add for a spec that
-    has none of them."""
+    the build's counters: the routed layers', a latent block's mixing, the
+    selective scans' and the reads of another block's state, and the blocks
+    run at the last position alone. Nothing to add for a spec that has none
+    of them."""
     if not counted:
         return
     live = np.asarray(rows) < n_live
@@ -423,6 +424,14 @@ def _note_layer_counts(counted: Dict[str, np.ndarray], rows, n_live: int) -> Non
         metric_catalog.HC_SUBLAYER_STEPS.inc(int(total["hc_sublayer_steps"]))
         metric_catalog.HC_STOCHASTIC_GAP.inc(float(total["hc_stochastic_gap"]))
         metric_catalog.HC_WRITE_KERNEL_STEPS.inc(int(total["hc_write_kernel_steps"]))
+    if "ssm_layer_steps" in total:
+        metric_catalog.SSM_LAYER_STEPS.inc(int(total["ssm_layer_steps"]))
+        metric_catalog.SSM_POSITIONS.inc(int(total["ssm_positions"]))
+    for kind in ("kv", "memory"):
+        if f"shared_{kind}_reads" in total:
+            metric_catalog.SHARED_STATE_READS.labels(kind=kind).inc(
+                int(total[f"shared_{kind}_reads"])
+            )
     if "last_position_steps" in total:
         metric_catalog.LAST_POSITION_BLOCK_STEPS.inc(int(total["last_position_steps"]))
 

@@ -9,8 +9,10 @@ profiler, and prints one JSON line a build: its wall, every
 ``model.pkl`` it wrote, the blocks it ran at the last position alone (a
 live training step each), the bytes it moved each way across the host link
 (with the pull's rate over the ``d2h`` stage), its chunks that starved
-for their fetch and its writes of the residual streams that ran the Pallas
-kernel pair, each where the program counts it. Each build's artifacts
+for their fetch, its writes of the residual streams that ran the Pallas
+kernel pair, and its selective scans' layer-steps and positions and its
+reads of another block's keys and values or memory, each where the program
+counts it. Each build's artifacts
 are removed as soon as it is read; with ``--keep`` they stay until the end, as
 they do in the benchmark's window (and in a deployment), so that a build's
 write meets the write-back of the artifacts before it.
@@ -39,6 +41,10 @@ COUNTERS = {
     "d2h_bytes": "gordo_build_transfer_bytes_total{direction=d2h}",
     "fetch_starved_chunks": "gordo_build_fetch_starved_chunks_total",
     "hc_write_kernel_steps": "gordo_build_hc_write_kernel_steps_total",
+    "ssm_layer_steps": "gordo_build_ssm_layer_steps_total",
+    "ssm_positions": "gordo_build_ssm_positions_total",
+    "shared_kv_reads": "gordo_build_shared_state_reads_total{kind=kv}",
+    "shared_memory_reads": "gordo_build_shared_state_reads_total{kind=memory}",
 }
 
 
